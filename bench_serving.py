@@ -130,6 +130,11 @@ from kubeflow_tpu.serving.scenarios import (  # noqa: F401
     percentile,
     run_trial,
 )
+from kubeflow_tpu.utils.jaxenv import (
+    device_summary,
+    place_compile_cache,
+    require_tpu,
+)
 
 
 def _bench_predict(args, model) -> dict:
@@ -514,7 +519,7 @@ def _bench_tp_sweep(args, model) -> dict:
     from kubeflow_tpu.serving import handoff as handoff_mod
     from kubeflow_tpu.serving.continuous import ContinuousDecoder
 
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = args.device["platform"] == "tpu"
     # f32 compute: under tp the row-parallel projections psum per-shard
     # partials, and bf16 rounds them before the reduce — f32 keeps the
     # reorder ~1e-6, which is what lets greedy stay bitwise across mesh
@@ -2009,7 +2014,12 @@ def _bench_flash_crowd_sweep(args, model) -> dict:
     spec = get_model(model)
     tmp = tempfile.mkdtemp(prefix="flash_crowd_")
     ckpt_dir = os.path.join(tmp, "ckpt")
-    cache_dir = os.path.join(tmp, "compile-cache")
+    # The dispatch-key manifest sits beside XLA's persistent cache (which
+    # main() placed: the environment's directory, or the fixed one in
+    # the checkout — never a path that moves). It starts empty so the
+    # baseline leg is born against no recorded coverage.
+    cache_dir = os.path.join(place_compile_cache(), "flash-crowd-manifest")
+    shutil.rmtree(cache_dir, ignore_errors=True)
     gen_n, slots, block = 8, 4, 8
 
     def eng_cfg(**kw):
@@ -2310,9 +2320,13 @@ def main() -> int:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8").strip()
-    on_tpu = jax.default_backend() == "tpu"
+    # The one rule for every mode: --quick is the tiny presets on
+    # whatever backend JAX finds (a smoke of the plumbing); anything else
+    # is a measurement and needs the TPU — it never substitutes the CPU.
+    place_compile_cache()
+    args.device = device_summary() if args.quick else require_tpu()
+    model = "lm-test-tiny" if args.quick else "llama-1b"
     if args.scenario:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         sc = get_scenario(args.scenario)
         assignments = json.loads(args.assignments) if args.assignments \
             else {}
@@ -2322,50 +2336,37 @@ def main() -> int:
             result = run_trial(args.scenario, assignments, seed=args.seed,
                                model=model, quick=args.quick)
     elif args.flash_crowd_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_flash_crowd_sweep(args, model)
     elif args.long_context_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_long_context_sweep(args, model)
     elif args.rollout_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_rollout_sweep(args, model)
     elif args.weight_push_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_weight_push_sweep(args, model)
     elif args.qos_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_qos_sweep(args, model)
     elif args.tp_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_tp_sweep(args, model)
     elif args.disagg_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_disagg_sweep(args, model)
     elif args.fleet_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_fleet_sweep(args, model)
     elif args.kv_economy_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_kv_economy_sweep(args, model)
     elif args.kv_dtype_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_kv_dtype_sweep(args, model)
     elif args.concurrency_sweep:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_concurrency_sweep(args, model)
     elif args.speculative:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_speculative(args, model)
     elif args.prefix_reuse:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_prefix_reuse(args, model)
     elif args.generate:
-        model = "llama-1b" if on_tpu and not args.quick else "lm-test-tiny"
         result = _bench_generate(args, model)
     else:
-        model = "bert-base" if on_tpu and not args.quick else "bert-test-tiny"
-        result = _bench_predict(args, model)
+        result = _bench_predict(
+            args, "bert-test-tiny" if args.quick else "bert-base")
+    result["device"] = args.device
     print(json.dumps(result))
     return 0
 

@@ -227,7 +227,7 @@ def scheduling_policy_schema() -> dict:
                     },
                     "profiles": {
                         # profile -> accelerator -> measured throughput
-                        # (tokens/s/chip, BENCH_*.json numbers): the
+                        # (tokens/s/chip, as bench.py prints them): the
                         # Gavel-style heterogeneity signal.
                         "type": "object",
                         "x-kubernetes-preserve-unknown-fields": True,

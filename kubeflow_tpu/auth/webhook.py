@@ -84,7 +84,10 @@ def mutate_pod(pod: dict) -> list[dict]:
         limits = c.get("resources", {}).get("limits", {})
         if TPU_RESOURCE in limits:
             patches.extend(_env_patch(c, i, "TPU_MIN_LOG_LEVEL", "1"))
-            patches.extend(_env_patch(c, i, "JAX_PLATFORMS", "tpu,cpu"))
+            # A pod that asked for chips runs on them or fails at
+            # start-up; "tpu,cpu" would let it train on the host CPU and
+            # look healthy.
+            patches.extend(_env_patch(c, i, "JAX_PLATFORMS", "tpu"))
     return patches
 
 

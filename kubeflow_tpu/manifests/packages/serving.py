@@ -221,11 +221,16 @@ def tpu_serving(
         args.append("--enable-prometheus")
     # The compile cache is node-shared state, not pod state: every
     # replica scheduled on the node mounts the same hostPath, so the
-    # first compile on the node is the LAST one any sibling pays.
-    volumes = mounts = None
+    # first compile on the node is the LAST one any sibling pays. The
+    # flag places the dispatch-key manifest; XLA's executables follow
+    # JAX's own variable (utils/jaxenv.place_compile_cache), which must
+    # name the same volume or a manifest hit is booked for an executable
+    # this replica compiles again.
+    volumes = mounts = env = None
     if compile_cache_dir:
         volumes = [k8s.host_path_volume("compile-cache", compile_cache_dir)]
         mounts = [k8s.volume_mount("compile-cache", compile_cache_dir)]
+        env = {"JAX_COMPILATION_CACHE_DIR": f"{compile_cache_dir}/xla"}
     pod_annotations = (
         {
             "prometheus.io/scrape": "true",
@@ -245,6 +250,7 @@ def tpu_serving(
                     image,
                     command=["python", "-m", "kubeflow_tpu.serving"],
                     args=args,
+                    env=env,
                     ports={"grpc": GRPC_PORT, "rest": REST_PORT},
                     resources=resources,
                     liveness_probe=k8s.tcp_probe(GRPC_PORT, initial_delay=30),

@@ -127,13 +127,11 @@ PRESETS: dict[str, TransformerConfig] = {
     # Single-chip flagship bench config: llama-style blocks at d=4096 with
     # a 5×d FFN and llama-3.2-style GQA (32 query / 4 kv heads), 3 layers /
     # 32k vocab — 1.13B params, the widest matmuls that fit 16GB HBM with
-    # adafactor. MXU efficiency rises with contraction width (measured
-    # v5e: 72 TF/s at K=2048, 107 at K=4096, 162 at K=8192), and at 3
+    # adafactor. MXU efficiency rises with contraction width, and at 3
     # layers the activations fit without remat while the unrolled layer
-    # loop avoids the scan's saved-activation stacking (~27% of step
-    # time). Ladder measured: L4/ff14336/kv8 scan+remat 53.4% MFU →
-    # L3/ff20480/kv4 60.4% → unrolled no-remat 69.9% → splash attention
-    # kernel (r4) 77.7% (BENCH_r04).
+    # loop avoids the scan's saved-activation stacking. An invented
+    # shape, chosen for MFU: ROADMAP Queue 2 replaces it in the
+    # benchmark; what it measures today is in PERF.md.
     "flagship-1b": TransformerConfig(
         vocab_size=32_000, d_model=4096, n_layers=3, n_heads=32,
         n_kv_heads=4, d_ff=20_480, max_seq_len=2048, remat=False,
@@ -142,18 +140,15 @@ PRESETS: dict[str, TransformerConfig] = {
     # Realistic-depth flagship: 16 llama-style layers (VERDICT r2 #1 —
     # the depth class of BERT/Llama users actually bring), 1.53B params,
     # the widest 16-layer geometry that keeps ~2GB HBM headroom on a
-    # 16GB v5e (configs within ~300MB of the HBM limit measurably thrash:
-    # same geometry drops from 46% to 32-38% MFU). The deep recipe vs the
-    # shallow flagship: unrolled layers + the "llm" named-save remat
-    # policy (save gate/up/attn-context, recompute the cheap rest) and
-    # bf16 gradients (OptimizerConfig.grad_dtype) — each buys HBM that
-    # goes straight into width. Round 4: the GQA-native splash attention
-    # kernel (fused bwd + causal block skipping) replaced the single-block
-    # XLA path and the unchunked LM loss replaced loss_chunks=8 (the
-    # splash memory savings make the full logits fit; the chunked head's
-    # extra forward cost ~1.2 MFU pts). Measured ladder at 16L, 8192
-    # tok/step: r3 XLA 61.3/57.2/48.0/38.1 at seq256/512/1024/2048 →
-    # splash 62.6/62.5/60.5/57.6 (BENCH_r04).
+    # 16GB v5e (configs close to the HBM limit thrash). The deep recipe
+    # vs the shallow flagship: unrolled layers + the "llm" named-save
+    # remat policy (save gate/up/attn-context, recompute the cheap rest)
+    # and bf16 gradients (OptimizerConfig.grad_dtype) — each buys HBM
+    # that goes straight into width. The GQA-native splash attention
+    # kernel (fused bwd + causal block skipping) replaced the
+    # single-block XLA path, and the unchunked LM loss replaced
+    # loss_chunks=8 (the splash memory savings make the full logits
+    # fit). What it measures today is in PERF.md.
     "flagship-deep": TransformerConfig(
         vocab_size=32_000, d_model=3072, n_layers=16, n_heads=24,
         n_kv_heads=4, d_ff=6656, max_seq_len=2048, remat=True,
@@ -317,6 +312,7 @@ def _attention(x, layer, cfg: TransformerConfig, rope, mesh):
             q, k, v, causal=True,
             implementation=cfg.attn_impl,
             block_k=cfg.attn_block_k,
+            mesh=mesh,
         )
     out = out.reshape(b, t, cfg.n_heads * hd)
     # Inert without the "llm" policy: wo's backward reuses its input, so

@@ -33,6 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from kubeflow_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_TENSOR
+from kubeflow_tpu.utils.jaxenv import not_tpu
+
 _NEG_INF = -1e30
 # Default kv block widths when the caller leaves block_k=None: the XLA
 # blockwise path takes DEFAULT_BLOCK_K (callers with known-static sequence
@@ -155,18 +158,48 @@ def _flash_bwd_xla(q, k, v, kvm, out, lse, g_out, *, causal, scale, block_k):
 # ---------------------------------------------------------------------------
 
 
-def _pallas_supported(q, k, kv_mask) -> bool:
-    """The tiled kernel wants TPU, lane-width head_dim, and MXU-aligned
-    sequence tiles; anything else routes to the XLA path."""
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except RuntimeError:
-        return False
-    _b, t, _hq, d = q.shape
-    s_len = k.shape[1]
-    return (kv_mask is None and d % 128 == 0
-            and t % 128 == 0 and t >= 128 and s_len % 128 == 0)
+def _kernel_unsupported(q, k, kv_mask, mesh=None) -> str | None:
+    """Why the tiled TPU kernels cannot take this call (None = they can):
+    they want a TPU, no padding mask, lane-width head_dim, MXU-aligned
+    sequence tiles and, on a mesh, batch and KV heads that divide over
+    the axes :func:`_shard_kernel` splits them on. Inside another
+    ``shard_map`` (a pipeline stage) they are fenced off: the nested
+    wrap that would take the axes still automatic has never run on a
+    chip."""
+    if why := not_tpu():
+        return why
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return ("inside a shard_map (a pipeline stage) the TPU kernels "
+                "are not wired up; the XLA path runs there")
+    if kv_mask is not None:
+        return "the TPU kernels take no kv_mask"
+    b, t, _hq, d = q.shape
+    s_len, hkv = k.shape[1], k.shape[2]
+    if d % 128 or t % 128 or s_len % 128:
+        return (f"head_dim {d}, T {t} and S {s_len} must be multiples "
+                "of 128")
+    if mesh is not None and mesh.size > 1:
+        batch_ways = mesh.shape[AXIS_DATA] * mesh.shape[AXIS_FSDP]
+        head_ways = mesh.shape[AXIS_TENSOR]
+        if b % batch_ways or hkv % head_ways:
+            return (f"batch {b} / kv heads {hkv} do not divide over "
+                    f"data*fsdp={batch_ways} / tensor={head_ways}")
+    return None
+
+
+def _shard_kernel(kernel, mesh):
+    """Mosaic kernels cannot be partitioned by GSPMD: on a multi-device
+    mesh run ``kernel(q, k, v)`` per shard under ``shard_map`` — batch
+    over data*fsdp, heads over tensor (attention is independent per
+    batch row and per KV-head group, so no collective is needed), every
+    other axis replicated."""
+    from jax.sharding import PartitionSpec as P
+
+    from kubeflow_tpu.parallel.collectives import shard_map
+
+    spec = P((AXIS_DATA, AXIS_FSDP), None, AXIS_TENSOR, None)
+    return shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                     out_specs=spec)
 
 
 def _pallas_flash(q, k, v, *, causal, scale, block):
@@ -230,10 +263,17 @@ def _splash_kernel(group: int, t: int, s_len: int, causal: bool,
         block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
         block_q_dq=blk, block_kv_dq=blk,
     )
-    return sk.make_splash_mqa_single_device(
-        mask=ml.MultiHeadMask(heads), block_sizes=sizes,
-        residual_checkpoint_name="attn_res",
-    )
+    # The kernel object carries its mask tables as arrays. This cache
+    # outlives the trace that first asks for it (jit, scan, shard_map —
+    # where jnp.array yields that trace's tracers), so build them as
+    # concrete values; the next trace would otherwise be handed tracers
+    # of a finished one (seen on the chip: UnexpectedTracerError on the
+    # second mesh a process trained on).
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mqa_single_device(
+            mask=ml.MultiHeadMask(heads), block_sizes=sizes,
+            residual_checkpoint_name="attn_res",
+        )
 
 
 def _splash_flash(q, k, v, *, causal, scale, block):
@@ -495,17 +535,17 @@ def _paged_decode_pallas(qg, k_pool, v_pool, table, pos, sm_scale,
     )(table.astype(jnp.int32), pos.astype(jnp.int32), qg, *operands)
 
 
-def _paged_kernel_supported(k_pool) -> bool:
-    """The real (non-interpret) kernel wants a TPU and lane-aligned
-    tiles; everything else rides the XLA walk."""
-    try:
-        if jax.devices()[0].platform != "tpu":
-            return False
-    except RuntimeError:
-        return False
-    payload = _kv_payload(k_pool)
-    _n, bs, _hkv, hd = payload.shape
-    return hd % 128 == 0 and bs % 8 == 0
+def _paged_kernel_unsupported(k_pool) -> str | None:
+    """Why the compiled (non-interpret) paged kernel cannot take this
+    pool (None = it can): it wants a TPU and lane/sublane-aligned
+    tiles."""
+    if why := not_tpu():
+        return why
+    _n, bs, _hkv, hd = _kv_payload(k_pool).shape
+    if hd % 128 or bs % 8:
+        return (f"head_dim {hd} must be a multiple of 128 and the block "
+                f"size {bs} a multiple of 8")
+    return None
 
 
 def _paged_decode_local(qg, k_pool, v_pool, table, pos, sm_scale,
@@ -514,8 +554,13 @@ def _paged_decode_local(qg, k_pool, v_pool, table, pos, sm_scale,
     of the mesh twin): qg [B, Hkv, G, hd] against [N, Bs, Hkv, hd]
     pools."""
     if implementation is None:
-        implementation = ("pallas" if _paged_kernel_supported(k_pool)
-                          else "xla")
+        implementation = ("xla" if _paged_kernel_unsupported(k_pool)
+                          else "pallas")
+    elif implementation == "pallas" and not interpret:
+        why = _paged_kernel_unsupported(k_pool)
+        if why:
+            raise ValueError(
+                f"paged_decode_attention(implementation='pallas'): {why}")
     if implementation == "pallas":
         return _paged_decode_pallas(qg, k_pool, v_pool, table, pos,
                                     sm_scale, interpret=interpret)
@@ -564,7 +609,9 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *,
     attends virtual positions ``<= pos[b]``. Returns [B, Hq, hd] f32.
 
     ``implementation``: None (auto: pallas on TPU for supported shapes,
-    else xla), "pallas", or "xla". Both walk the block table with an
+    else xla), "pallas", or "xla". An explicit "pallas" that cannot be
+    compiled for this backend or shape raises (``interpret=True`` runs
+    it in the interpreter anywhere). Both walk the block table with an
     online softmax — the gathered ``[B, MB*Bs, Hkv, hd]`` view is never
     materialized, which is the point.
 
@@ -812,6 +859,7 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int | None = None,
     implementation: str | None = None,
+    mesh=None,
 ):
     """Multi-head / grouped-query flash attention.
 
@@ -830,8 +878,12 @@ def flash_attention(
     - "xla" — blockwise online-softmax scan (any backend, any shape).
     - "plain" — materialized scores.
 
-    TPU-kernel picks fall back to the XLA path off-TPU or for
-    masked/unaligned shapes, so one model definition runs everywhere.
+    Auto falls to the XLA path off-TPU or for masked/unaligned shapes,
+    so one model definition runs everywhere; an EXPLICIT "splash" or
+    "pallas" that cannot be honoured raises instead of quietly running
+    something else. ``mesh``: the mesh the caller's jit is partitioned
+    over; with more than one device the kernel runs under ``shard_map``
+    (batch over data*fsdp, heads over tensor).
     """
     b, t, hq, d = q.shape
     s_len, hkv = k.shape[1], k.shape[2]
@@ -840,19 +892,25 @@ def flash_attention(
     group = hq // hkv
     scale = (d**-0.5) if scale is None else scale
 
-    pallas_ok = _pallas_supported(q, k, kv_mask)
-    if implementation is None and t >= 512 and pallas_ok:
-        implementation = "splash"
-    if implementation in ("splash", "pallas") and pallas_ok:
+    if implementation in (None, "splash", "pallas"):
+        why_not = _kernel_unsupported(q, k, kv_mask, mesh)
+        if implementation is None and t >= 512 and not why_not:
+            implementation = "splash"
+        elif implementation is not None and why_not:
+            raise ValueError(
+                f"flash_attention(implementation={implementation!r}): "
+                f"{why_not}")
+    if implementation in ("splash", "pallas"):
         # block_k=None → per-path measured-best default: 1024-wide tiles
         # here (2048 is slower at seq≥2048 and a VMEM risk), 2048 on the
-        # XLA fallback below. An explicit block_k is honored as given.
-        kernel_block = DEFAULT_KERNEL_BLOCK_K if block_k is None else block_k
-        if implementation == "pallas":
-            return _pallas_flash(q, k, v, causal=causal, scale=scale,
-                                 block=kernel_block)
-        return _splash_flash(q, k, v, causal=causal, scale=scale,
-                             block=kernel_block)
+        # XLA path below. An explicit block_k is honored as given.
+        kernel = functools.partial(
+            _pallas_flash if implementation == "pallas" else _splash_flash,
+            causal=causal, scale=scale,
+            block=DEFAULT_KERNEL_BLOCK_K if block_k is None else block_k)
+        if mesh is not None and mesh.size > 1:
+            kernel = _shard_kernel(kernel, mesh)
+        return kernel(q, k, v)
     if block_k is None:
         block_k = DEFAULT_BLOCK_K
 

@@ -15,6 +15,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from kubeflow_tpu.utils.jaxenv import not_tpu
+
 
 def rms_norm(x, weight, *, eps: float = 1e-6, implementation: str | None = None):
     """y = x / rms(x) * weight over the last dim. x: [..., D], weight: [D].
@@ -23,8 +25,15 @@ def rms_norm(x, weight, *, eps: float = 1e-6, implementation: str | None = None)
     on v5e, XLA's fused norm edges out the pallas kernel (27.5k vs 27.0k
     tok/s end-to-end) — XLA already fuses the norm into its neighbors, and
     the kernel boundary blocks that. The kernel stays opt-in
-    (``implementation="pallas"``) for standalone-norm workloads."""
+    (``implementation="pallas"``, TPU only — elsewhere it raises) for
+    standalone-norm workloads."""
     if implementation == "pallas":
+        if why := not_tpu():
+            # The kernel is compiled, never quietly interpreted: an
+            # interpreted run would pass for a kernel run in a benchmark.
+            raise ValueError(
+                f"rms_norm(implementation='pallas') compiles a TPU kernel: "
+                f"{why}")
         return _rms_norm_fused(x, weight, eps)
     return _rms_norm_xla(x, weight, eps)
 
@@ -33,8 +42,7 @@ def rms_norm(x, weight, *, eps: float = 1e-6, implementation: str | None = None)
 def _rms_norm_fused(x, weight, eps):
     # Autodiff must not see the pallas_call (no reverse-mode rule); the
     # backward is the closed-form VJP below.
-    return _rms_norm_pallas(x, weight, eps=eps,
-                            interpret=jax.default_backend() != "tpu")
+    return _rms_norm_pallas(x, weight, eps=eps, interpret=False)
 
 
 def _rms_norm_fused_fwd(x, weight, eps):

@@ -22,26 +22,13 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = False,
               axis_names=None):
-    """Version-portable ``jax.shard_map``: newer jax exposes it at the top
-    level with ``check_vma``/``axis_names``; older releases spell it
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` and the
-    COMPLEMENT set ``auto`` (axes left automatic rather than axes made
-    manual). Every shard_map in this repo goes through here so kernels
-    run on both."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    kwargs = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return _legacy(fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=check_vma, **kwargs)
+    """``jax.shard_map`` with this repo's default: ``check_vma=False``,
+    because collective-heavy kernels (and pallas calls) routinely mix
+    replicated and sharded values. ``axis_names`` limits the manual axes;
+    None makes every mesh axis manual."""
+    kwargs = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def psum(x, axis: str | Sequence[str]):
@@ -74,11 +61,8 @@ def axis_index(axis: str):
 
 
 def axis_size(axis: str) -> int:
-    """Static mesh-axis size inside a shard_map region, version-portable:
-    newer jax has lax.axis_size; older releases constant-fold psum(1)."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    """Static mesh-axis size inside a shard_map region."""
+    return lax.axis_size(axis)
 
 
 def shard_map_over(mesh: Mesh, in_specs, out_specs, *, check_vma: bool = False):

@@ -166,15 +166,13 @@ def _not_ready(node: Mapping) -> bool:
 # Throughput profiles (the heterogeneity signal)
 # ---------------------------------------------------------------------------
 
-# Default normalized throughput book, seeded from the repo's BENCH_*.json
-# measurements (tokens/s/chip on the flagship train config) scaled by the
-# pools' relative peak: jobs without a measured profile fall back to
-# "default". A SchedulingPolicy's spec.profiles overrides/extends this.
+# Default normalized throughput book: the pools' documented bf16 peak
+# ratio (v5e 197 TFLOP/s, v5p 459 TFLOP/s per chip), which is all a job
+# without a measured profile can be ranked by. Measured profiles come
+# from a SchedulingPolicy's spec.profiles or from bench output
+# (:meth:`ThroughputBook.from_bench_files`); none ships in the code.
 DEFAULT_PROFILES: dict[str, dict[str, float]] = {
     "default": {"v5e": 1.0, "v5p": 2.3},
-    # BENCH_r05: flagship-1b 22325 tok/s/chip on the v5e-class config;
-    # v5p-class peak ratio from the accelerator peak-flops ratio.
-    "flagship-1b": {"v5e": 22325.0, "v5p": 51348.0},
 }
 
 
@@ -197,7 +195,8 @@ class ThroughputBook:
     def from_bench_files(cls, files: Mapping[str, str],
                          extra: Mapping[str, Mapping[str, float]]
                          | None = None) -> "ThroughputBook":
-        """Build profiles from the repo's BENCH_*.json measurement files:
+        """Build profiles from bench output files (one ``bench.py`` JSON
+        line, bare or under a ``"parsed"`` key):
         ``files`` maps accelerator type -> path measured on it. Each file
         contributes its config's leading token (e.g. ``flagship-1b``) as
         the profile name with ``tokens_per_sec_per_chip`` as the

@@ -6,9 +6,12 @@ The container entrypoint the tpu-serving manifest runs
 from __future__ import annotations
 
 import argparse
+import signal
+import threading
 
 from kubeflow_tpu.serving.engine import EngineConfig
 from kubeflow_tpu.serving.server import ModelServer
+from kubeflow_tpu.utils.jaxenv import device_line, place_compile_cache
 
 
 def main(argv=None) -> int:
@@ -36,8 +39,9 @@ def main(argv=None) -> int:
                         "streaming; lockstep: one compiled call per batch")
     p.add_argument("--decode-chunk", type=int, default=1,
                    help="decode steps fused per device dispatch in "
-                        "continuous mode; set ~max-new-tokens on "
-                        "high-RTT links")
+                        "continuous mode: K>1 makes K times fewer "
+                        "dispatches, and a new request or a streamed "
+                        "token waits up to K steps")
     p.add_argument("--prefix-cache-slots", type=int, default=0,
                    help="device prefix-KV pool slots for reuse of shared "
                         "prompt prefixes (0 disables); matching prompts "
@@ -307,6 +311,10 @@ def main(argv=None) -> int:
             p.error(f"--kv-block-size {args.kv_block_size} must divide "
                     f"max-prompt-len + max-new-tokens = {total}")
 
+    # Before the first compile: where XLA keeps executables, and which
+    # device this process is really on.
+    print(f"compile cache: {place_compile_cache()}")
+    print(device_line(), flush=True)
     server = ModelServer(
         EngineConfig(
             model=args.model_name,
@@ -351,8 +359,22 @@ def main(argv=None) -> int:
         batch_timeout_ms=args.batch_timeout_ms,
     )
     print(f"serving {args.model_name} on REST :{args.rest_port} "
-          f"gRPC :{args.grpc_port}")
+          f"gRPC :{args.grpc_port}", flush=True)
+
+    # stop() waits for the accept loop this (main) thread is blocked in,
+    # so the handler hands it to another thread; main joins that thread
+    # so the decoder and batcher are drained before the process exits.
+    stopper = threading.Thread(target=server.stop, daemon=False)
+
+    def _on_signal(_signum, _frame):
+        if stopper.ident is None:  # not started yet
+            stopper.start()
+
+    signal.signal(signal.SIGTERM, _on_signal)
+    signal.signal(signal.SIGINT, _on_signal)
     server.serve_forever()
+    if stopper.ident is not None:
+        stopper.join(timeout=30)
     return 0
 
 

@@ -8,16 +8,16 @@ replica finished the exact same compiles seconds earlier. This module
 keys that work by an **engine fingerprint** — a digest of everything
 that selects a compiled executable: model config, mesh shape
 (tp/cp/pp), KV layout/dtype, the bucket set, and the decode knobs —
-and wires two layers of reuse under one directory
-(``--compile-cache-dir``, a volume shared across a pool's replicas):
+and keeps two layers of reuse apart:
 
-- **XLA's persistent compilation cache** (``jax_compilation_cache_dir``)
-  holds the serialized executables themselves. Where the installed jax
-  supports it, pointing it at the shared directory means the second-ever
-  replica of a config deserializes instead of compiling. Wired
-  best-effort: an older jax without the knob degrades to warm-by-
-  dispatch, never to a crash.
-- A **fingerprint-checked manifest** (this module's own store) records
+- **XLA's persistent compilation cache** holds the serialized
+  executables themselves. Its directory is placed from OUTSIDE this
+  module (``utils/jaxenv.place_compile_cache``, called by the entry
+  points): ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it,
+  else one fixed path in the checkout. A pool that wants its replicas to
+  share executables points that variable at the shared volume.
+- A **fingerprint-checked manifest** (this module's own store, under
+  ``--compile-cache-dir``) records
   which dispatch keys a prior replica of the SAME fingerprint already
   compiled. It is the hit/miss accounting surface
   (``serving_compile_cache_{hits,misses}_total``) and the invalidation
@@ -98,37 +98,15 @@ def dispatch_keys(*, slots: int, prefill_len: int, prefill_len_buckets: int,
     return keys
 
 
-def configure_jax_cache(cache_dir: str) -> bool:
-    """Point jax's persistent compilation cache at ``cache_dir``.
-    Best-effort: returns False (and changes nothing) on a jax build
-    without the knob — the manifest store still works, the newborn just
-    pays real compiles on this host."""
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # Serialize every executable, even fast-compiling ones: the
-        # cold-start budget cares about dispatch-set *coverage*, not
-        # per-executable amortization.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except (AttributeError, ValueError, TypeError):
-        return False
-    return True
-
-
 class CompileCache:
     """Fingerprint-keyed manifest of warmed dispatch keys under a
-    shared directory, plus (best-effort) the XLA persistent cache
-    wiring. One instance per decoder; hit/miss counts accumulate on the
-    instance and surface through the decoder's metrics."""
+    shared directory. One instance per decoder; hit/miss counts
+    accumulate on the instance and surface through the decoder's
+    metrics. Where XLA keeps the executables is not decided here."""
 
     def __init__(self, cache_dir: str):
         self.cache_dir = str(cache_dir)
         os.makedirs(self.cache_dir, exist_ok=True)
-        # XLA's serialized executables live next to the manifests; a
-        # failure to wire it leaves warm-by-dispatch as the whole story.
-        self.xla_cache_wired = configure_jax_cache(
-            os.path.join(self.cache_dir, "xla"))
         self.hits = 0
         self.misses = 0
 

@@ -119,8 +119,11 @@ class ModelServer:
         # Readiness ramp: True from construction until warm() covers
         # the boot path — /healthz answers {"status": "warming"} so the
         # gateway route-excludes this replica without failure-counter
-        # penalty while it compiles.
+        # penalty while it compiles. A warm that fails leaves its error
+        # text here: /healthz then answers 500 {"status": "failed"} and
+        # serve_forever ends the process.
         self.warming = True
+        self.warm_error: str | None = None
 
     @property
     def decoder(self):
@@ -507,8 +510,12 @@ class ModelServer:
                     # "warming" is alive-but-not-serving: the gateway
                     # route-excludes without a failure-counter penalty
                     # (a newborn mid-compile is not a dead upstream).
-                    status = "warming" if server.warming else "ok"
-                    self._send(200, {"status": status})
+                    if server.warm_error is not None:
+                        self._send(500, {"status": "failed",
+                                         "error": server.warm_error})
+                    else:
+                        status = "warming" if server.warming else "ok"
+                        self._send(200, {"status": status})
                 elif self.path == "/readyz":
                     code = 200 if server.engine.ready else 503
                     self._send(code, {"ready": server.engine.ready})
@@ -664,6 +671,8 @@ class ModelServer:
                                 d["compile_cache_hits"],
                             "serving_compile_cache_misses_total":
                                 d["compile_cache_misses"],
+                            "serving_warm_failed_shapes":
+                                d["warm_failed_shapes"],
                             "serving_warming": int(d["warming"]),
                             "serving_in_flight": d["in_flight"],
                             "serving_queued": d["queued"],
@@ -869,14 +878,22 @@ class ModelServer:
         configured (``compile_cache_dir``/``weight_peers``) — an eager
         decoder build + dispatch-set warm so the replica joins the
         fleet with nothing left to compile. Publishes the per-phase
-        cold-start breakdown and flips ``warming`` off."""
+        cold-start breakdown and flips ``warming`` off. Raises when the
+        predict compile fails or the decoder reports a dispatch shape
+        that did not warm: a replica that cannot compile its own
+        dispatch set must not join the fleet looking healthy."""
         t0 = time.perf_counter()
         self.engine.warmup()
         if self.engine.cfg.compile_cache_dir or self.engine.cfg.weight_peers:
             decoder = self.decoder
             if decoder is not None:
                 decoder.warming = True
-                decoder.warm()
+                report = decoder.warm()
+                if report["failed"]:
+                    raise RuntimeError(
+                        f"decoder warm failed for {report['failed']} "
+                        f"dispatch shape(s) {report['failed_shapes']}: "
+                        f"{report['first_error']}")
         self.engine.cold_start["compile"] = time.perf_counter() - t0
         self.engine.cold_start["first_token"] = (time.perf_counter()
                                                  - self._t_boot)
@@ -901,9 +918,24 @@ class ModelServer:
             ("0.0.0.0", self.port), self._make_handler()
         )
         # Warm on a side thread: the accept loop must answer health
-        # probes (as "warming") while the dispatch set compiles.
-        threading.Thread(target=self.warm, daemon=True).start()
+        # probes (as "warming") while the dispatch set compiles. A warm
+        # that raises is recorded for /healthz and stops the accept
+        # loop, so the failure ends the process instead of leaving a
+        # server that answers "warming" forever.
+        def _warm():
+            try:
+                self.warm()
+            except Exception as e:  # boundary: reported below, re-raised
+                import traceback
+
+                traceback.print_exc()
+                self.warm_error = f"{type(e).__name__}: {e}"
+                self._httpd.shutdown()
+
+        threading.Thread(target=_warm, daemon=True).start()
         self._httpd.serve_forever()
+        if self.warm_error is not None:
+            raise RuntimeError(f"server warm failed: {self.warm_error}")
 
     def stop(self) -> None:
         if self._httpd:

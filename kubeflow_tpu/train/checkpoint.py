@@ -8,7 +8,13 @@ training while orbax commits in the background, so checkpoint cadence
 doesn't trade against MFU) and a final synchronous save on preemption, and
 resumes from the latest step found. Multi-host safe — every process
 participates in the save (orbax handles the per-shard writes + atomic
-commit)."""
+commit).
+
+No file of a checkpoint is large: orbax's default packs arrays into data
+files of 2 GiB and more, which a per-process file-size limit
+(``RLIMIT_FSIZE``) refuses with ``EFBIG`` — the llama-1b state lost its
+save that way on a TPU host. ``DATA_FILE_BYTES`` caps them instead; an
+array larger than that is stored in chunks."""
 
 from __future__ import annotations
 
@@ -16,6 +22,10 @@ import os
 from typing import Any
 
 import orbax.checkpoint as ocp
+
+# A data file is closed once it reaches this size, and no chunk of an
+# array is larger, so a file stays under twice this (32 MiB).
+DATA_FILE_BYTES = 16 << 20
 
 
 def _manager(ckpt_dir: str, max_to_keep: int = 3, *,
@@ -27,6 +37,18 @@ def _manager(ckpt_dir: str, max_to_keep: int = 3, *,
             enable_async_checkpointing=async_saves,
         ),
     )
+
+
+def _save_args(state: Any) -> ocp.args.PyTreeSave:
+    return ocp.args.PyTreeSave(
+        state, ocdbt_target_data_file_size=DATA_FILE_BYTES)
+
+
+def _restore_args(abstract_state: Any) -> ocp.args.PyTreeRestore:
+    return ocp.args.PyTreeRestore(
+        abstract_state,
+        restore_args=ocp.checkpoint_utils.construct_restore_args(
+            abstract_state))
 
 
 class Checkpointer:
@@ -46,8 +68,7 @@ class Checkpointer:
                              async_saves=async_saves)
 
     def save(self, step: int, state: Any, *, force: bool = False) -> None:
-        self._mgr.save(step, args=ocp.args.StandardSave(state),
-                       force=force)
+        self._mgr.save(step, args=_save_args(state), force=force)
 
     def wait(self) -> None:
         self._mgr.wait_until_finished()
@@ -60,8 +81,7 @@ class Checkpointer:
         step = self._mgr.latest_step()
         if step is None:
             return None
-        state = self._mgr.restore(
-            step, args=ocp.args.StandardRestore(abstract_state))
+        state = self._mgr.restore(step, args=_restore_args(abstract_state))
         return state, step
 
     def close(self) -> None:
@@ -71,7 +91,7 @@ class Checkpointer:
 
 def save(ckpt_dir: str, step: int, state: Any, *, force: bool = False) -> None:
     mgr = _manager(ckpt_dir)
-    mgr.save(step, args=ocp.args.StandardSave(state), force=force)
+    mgr.save(step, args=_save_args(state), force=force)
     mgr.wait_until_finished()
     mgr.close()
 
@@ -90,7 +110,7 @@ def restore(ckpt_dir: str, step: int, abstract_state: Any) -> Any:
     with jax.eval_shape + shardings so restoring places shards directly on
     device)."""
     mgr = _manager(ckpt_dir)
-    state = mgr.restore(step, args=ocp.args.StandardRestore(abstract_state))
+    state = mgr.restore(step, args=_restore_args(abstract_state))
     mgr.close()
     return state
 

@@ -33,6 +33,7 @@ from kubeflow_tpu.train.trainer import (
     init_state,
     state_shardings,
 )
+from kubeflow_tpu.utils.jaxenv import device_line, place_compile_cache
 
 
 @dataclass
@@ -122,6 +123,10 @@ def run(cfg: RunConfig, *, log=print, mesh_source=None) -> dict:
             num_slices=info.num_slices if info.is_multislice else None,
         )
     opt_cfg = cfg.optimizer
+    # Which device this run is really on (after the rendezvous: asking
+    # earlier would initialise the backend before the gang has joined).
+    split_axes = {a: n for a, n in mesh.shape.items() if n > 1}
+    log(f"{device_line()} mesh={split_axes}")
 
     state = init_state(jax.random.PRNGKey(cfg.seed), model, opt_cfg, mesh)
     start_step = 0
@@ -224,6 +229,10 @@ def _train(cfg, info, model, mesh, opt_cfg, state, start_step, ckpt,
         # Stateless in (seed, step): restarting at start_step replays the
         # exact stream position — checkpoint resume is data-exact.
         store = TokenStore(cfg.data_path)
+        # The numpy reader is a silent ~100x slower stand-in when g++ is
+        # missing; name whichever one is feeding this run.
+        log(f"token store: backend={'native' if store.native else 'numpy'} "
+            f"tokens={store.n_tokens} path={cfg.data_path}")
     batches, prefetcher = _make_batches(
         cfg, info, model, mesh, start_step * cfg.accum_steps, store)
     poll_steps = (cfg.elastic_poll_steps
@@ -463,6 +472,7 @@ def main(argv=None) -> int:
         if overrides.get(key):
             overrides[key] = os.path.expandvars(overrides[key])
     cfg = RunConfig(mesh=mesh_cfg, optimizer=opt_cfg, **overrides)
+    print(f"compile cache: {place_compile_cache()}", flush=True)
     result = run(cfg)
     print(json.dumps(result))
     return 0

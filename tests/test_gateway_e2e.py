@@ -207,6 +207,8 @@ def test_admission_webhook_mutates_labeled_pods():
     names = {e["name"] for e in flat}
     assert {"GOOGLE_APPLICATION_CREDENTIALS", "JAX_PLATFORMS",
             "TPU_MIN_LOG_LEVEL"} <= names
+    # A pod that asked for chips runs on them or fails: no CPU fallback.
+    assert {e["name"]: e["value"] for e in flat}["JAX_PLATFORMS"] == "tpu"
 
     assert mutate_pod({"kind": "Pod", "metadata": {},
                        "spec": {"containers": [{"name": "c"}]}}) == []

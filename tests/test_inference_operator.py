@@ -595,3 +595,9 @@ def test_warmup_spec_renders_cache_volume_and_peer_chain(env):
         vols = {v["name"]: v for v in pod.get("volumes", [])}
         assert vols["compile-cache"]["hostPath"]["path"] == \
             "/var/cache/kubeflow-tpu/compile"
+        # XLA's executables go to the same volume as the manifest that
+        # books them: the entry point takes the directory from JAX's own
+        # variable and sets no other.
+        assert {"name": "JAX_COMPILATION_CACHE_DIR",
+                "value": "/var/cache/kubeflow-tpu/compile/xla"} in \
+            pod["containers"][0]["env"]

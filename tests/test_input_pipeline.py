@@ -169,7 +169,8 @@ def test_loop_exception_closes_prefetcher():
 
     def exploding_log(msg):
         calls.append(msg)
-        raise RuntimeError("log sink died")
+        if str(msg).startswith("step="):  # a log boundary INSIDE the loop
+            raise RuntimeError("log sink died")
 
     with pytest.raises(RuntimeError, match="log sink died"):
         run(_cfg(prefetch=2), log=exploding_log)

@@ -196,16 +196,92 @@ def test_flash_attention_all_masked_rows_are_zero(impl):
 
 
 @pytest.mark.parametrize("impl", ["pallas", "splash"])
-def test_tpu_kernel_impls_fall_back_off_tpu(impl):
-    """The TPU-kernel implementations route to the XLA path on CPU (and
-    for unaligned shapes), so one model definition runs everywhere; the
-    on-TPU numerical parity of all three paths is checked by the bench
-    harness (values agree to bf16 noise, scratch/deepbench history)."""
+def test_explicit_tpu_kernel_request_raises_off_tpu(impl):
+    """An EXPLICIT kernel request that cannot be honoured raises — it
+    never quietly runs the XLA scan under the kernel's name. Auto
+    (implementation=None) is what routes to the XLA path off-TPU, so one
+    model definition still runs everywhere; on-TPU parity of the kernels
+    with the XLA path is chip_smoke.py's kernels phase."""
     key = jax.random.PRNGKey(3)
     kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (2, 128, 8, 128))
-    k = jax.random.normal(kk, (2, 128, 2, 128))
-    v = jax.random.normal(kv, (2, 128, 2, 128))
-    out = flash_attention(q, k, v, causal=True, implementation=impl)
+    q = jax.random.normal(kq, (2, 512, 8, 128))
+    k = jax.random.normal(kk, (2, 512, 2, 128))
+    v = jax.random.normal(kv, (2, 512, 2, 128))
+    with pytest.raises(ValueError, match="backend is 'cpu'"):
+        flash_attention(q, k, v, causal=True, implementation=impl)
+    # Same kernel-eligible shape, auto: the XLA path, and it is right.
+    out = flash_attention(q, k, v, causal=True)
     ref = dense_attention(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_splash_kernel_cache_holds_no_tracers():
+    """The cached splash kernel object outlives the trace that first
+    builds it; its mask tables must be concrete arrays, or the next
+    trace is handed tracers of a finished one (seen on the chip as
+    UnexpectedTracerError on the second mesh a process trained on)."""
+    from kubeflow_tpu.ops.attention import _splash_kernel
+
+    _splash_kernel.cache_clear()
+
+    def traced(x):
+        kernel = _splash_kernel(2, 256, 256, True, 128)
+        leaves = jax.tree.leaves(kernel)
+        assert leaves and not any(
+            isinstance(leaf, jax.core.Tracer) for leaf in leaves)
+        return x
+
+    jax.jit(traced)(1.0)
+    _splash_kernel.cache_clear()
+
+
+def test_explicit_paged_and_norm_kernels_raise_off_tpu():
+    """Same rule for the other two kernels: compiled on request or an
+    error, interpreted only when the caller says interpret=True."""
+    from kubeflow_tpu.ops.attention import paged_decode_attention
+
+    q = jnp.ones((2, 4, 128))
+    pool = jnp.ones((4, 8, 2, 128))
+    table = jnp.zeros((2, 2), jnp.int32)
+    pos = jnp.array([3, 9], jnp.int32)
+    with pytest.raises(ValueError, match="backend is 'cpu'"):
+        paged_decode_attention(q, pool, pool, table, pos, n_kv_heads=2,
+                               implementation="pallas")
+    out = paged_decode_attention(q, pool, pool, table, pos, n_kv_heads=2,
+                                 implementation="pallas", interpret=True)
+    np.testing.assert_allclose(np.asarray(out), 1.0, atol=1e-6)
+    with pytest.raises(ValueError, match="backend is 'cpu'"):
+        rms_norm(jnp.ones((8, 128)), jnp.ones((128,)),
+                 implementation="pallas")
+
+
+def test_tpu_kernels_are_fenced_off_inside_a_shard_map(monkeypatch):
+    """A pipeline stage is already a ``shard_map`` over `pipeline`; the
+    kernels would need a second, nested wrap there that has never run on
+    a chip. So the predicate says no inside one: auto takes the XLA path
+    and an explicit request raises (checked with the backend test out of
+    the way — on the CPU it would answer first)."""
+    from jax.sharding import PartitionSpec as P
+
+    from kubeflow_tpu.ops import attention
+    from kubeflow_tpu.parallel.collectives import shard_map
+    from kubeflow_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    monkeypatch.setattr(attention, "not_tpu", lambda: None)
+    mesh = build_mesh(MeshConfig(data=1, pipeline=2, tensor=2),
+                      devices=jax.devices()[:4])
+    q = jnp.ones((2, 512, 8, 128))
+    kv = jnp.ones((2, 512, 2, 128))
+    assert attention._kernel_unsupported(q, kv, None, mesh) is None
+    seen = []
+
+    def stage(x):
+        seen.append(attention._kernel_unsupported(q, kv, None))
+        with pytest.raises(ValueError, match="inside a shard_map"):
+            flash_attention(q, kv, kv, implementation="splash")
+        return x
+
+    jax.jit(shard_map(stage, mesh=mesh, in_specs=P("pipeline"),
+                      out_specs=P("pipeline"),
+                      axis_names=frozenset({"pipeline"})))(jnp.ones((2,)))
+    assert seen and "inside a shard_map" in seen[0]

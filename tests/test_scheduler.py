@@ -169,16 +169,23 @@ def test_throughput_book_prefers_measured_faster_pool():
     assert book.score(None, "v5p") == 1.0
 
 
-def test_throughput_book_from_bench_files():
-    """Profiles load from the repo's real BENCH_*.json measurements: the
-    config's leading token names the profile, tokens/s/chip is the
-    throughput the Gavel scoring normalizes."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def test_throughput_book_from_bench_files(tmp_path):
+    """Profiles load from bench-format files: the config's leading token
+    names the profile, tokens/s/chip is the throughput the Gavel scoring
+    normalizes. The file is SYNTHETIC — round numbers in bench.py's
+    output shape, measured nowhere."""
+    bench_file = tmp_path / "synthetic_bench.json"
+    bench_file.write_text(json.dumps({"parsed": {
+        "config": "flagship-1b bs4 seq2048 adafactor bf16 x1chip",
+        "tokens_per_sec_per_chip": 20000.0,
+        "deep_config": "flagship-deep bs32 seq256 adafactor bf16 x1chip",
+        "deep_tokens_per_sec_per_chip": 10000.0,
+    }}))
     book = ThroughputBook.from_bench_files(
-        {"v5e": os.path.join(repo, "BENCH_r05.json")},
+        {"v5e": str(bench_file)},
         extra={"flagship-1b": {"v5p": 1e6}})
     tput = book.throughput("flagship-1b", "v5e")
-    assert tput > 1000  # a real measured number, not the 1.0 fallback
+    assert tput == 20000.0  # the file's number, not the 1.0 fallback
     assert book.score("flagship-1b", "v5p") == 1.0  # extra table merged
     assert book.score("flagship-1b", "v5e") == pytest.approx(
         tput / 1e6)
