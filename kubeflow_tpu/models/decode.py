@@ -21,6 +21,16 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from kubeflow_tpu.observability.tracing import (
+    SCOPE_ATTN,
+    SCOPE_DECODE,
+    SCOPE_EMBED,
+    SCOPE_HEAD,
+    SCOPE_MLP,
+    SCOPE_PREFILL,
+    SCOPE_SAMPLE,
+    scope,
+)
 from kubeflow_tpu.ops import rms_norm
 from kubeflow_tpu.ops.attention import (
     paged_decode_attention,
@@ -28,7 +38,12 @@ from kubeflow_tpu.ops.attention import (
     ring_span_attention,
 )
 from kubeflow_tpu.ops.rotary import rotary_frequencies
-from kubeflow_tpu.models.transformer import TransformerConfig, moe_ffn
+from kubeflow_tpu.models.transformer import (
+    TransformerConfig,
+    cast_param,
+    head_kernel,
+    moe_ffn,
+)
 
 _NEG_INF = -1e30
 
@@ -59,15 +74,16 @@ def _gqa_attention(q, k_cache, v_cache, mask, cfg):
         b, s, cfg.n_heads * hd)
 
 
+@scope(SCOPE_ATTN)
 def _cached_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos, valid):
     """x: [B, S, D] at cache slots pos..pos+S; attends over the full cache
     masked by ``valid`` [B, total]. Returns (out, k_cache, v_cache)."""
     b, s, _d = x.shape
     hd = cfg.head_dim
     cos, sin = rope_bt  # [B, S, hd//2] gathered per row by the caller
-    q = (x @ layer["wq"].astype(cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ layer["wk"].astype(cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ layer["wv"].astype(cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
+    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
     q = _rope(q, cos, sin)
     k = _rope(k, cos, sin)
     k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
@@ -81,7 +97,7 @@ def _cached_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos, valid):
     i_idx = pos + jnp.arange(s)[None, :, None]
     mask = (j_idx <= i_idx) & valid[:, None, :]
     out = _gqa_attention(q, k_cache, v_cache, mask[:, None, None], cfg)
-    return out @ layer["wo"].astype(cfg.dtype), k_cache, v_cache
+    return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
 
 
 def _rope(x, cos, sin):
@@ -90,6 +106,28 @@ def _rope(x, cos, sin):
     return jnp.concatenate(
         [x1 * c - x2 * s, x1 * s + x2 * c], axis=-1
     ).astype(x.dtype)
+
+
+def _embed(params, tokens, cfg):
+    with scope(SCOPE_EMBED):
+        return cast_param(params["embed"]["kernel"], cfg.dtype)[tokens]
+
+
+def _ffn(h, mlp, cfg, token_valid):
+    """A layer's feed-forward half on normed ``h``: MoE (pad tokens claim
+    no expert capacity) or the dense SwiGLU."""
+    if cfg.n_experts:
+        return moe_ffn(h, mlp, cfg, token_valid=token_valid)[0]
+    with scope(SCOPE_MLP):
+        gate = h @ cast_param(mlp["gate"], cfg.dtype)
+        up = h @ cast_param(mlp["up"], cfg.dtype)
+        return (jax.nn.silu(gate) * up) @ cast_param(mlp["down"], cfg.dtype)
+
+
+def _head(params, x, cfg):
+    """Final-norm hidden ``x`` → float32 logits."""
+    with scope(SCOPE_HEAD):
+        return (x @ head_kernel(params, cfg)).astype(jnp.float32)
 
 
 def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
@@ -101,7 +139,7 @@ def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
     cos_t, sin_t = rotary_frequencies(cfg.head_dim, cache["k"].shape[2],
                                       theta=cfg.rope_theta)
     rope_bt = (cos_t[positions], sin_t[positions])
-    x = params["embed"]["kernel"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
 
     def layer_fn(x, layer_and_cache):
         layer, k_cache, v_cache = layer_and_cache
@@ -111,25 +149,14 @@ def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
         )
         x = x + attn
         h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _aux = moe_ffn(h, layer["mlp"], cfg, token_valid=token_valid)
-            x = x + y
-        else:
-            gate = h @ layer["mlp"]["gate"].astype(cfg.dtype)
-            up = h @ layer["mlp"]["up"].astype(cfg.dtype)
-            x = x + (jax.nn.silu(gate) * up) @ layer["mlp"]["down"].astype(
-                cfg.dtype
-            )
+        x = x + _ffn(h, layer["mlp"], cfg, token_valid)
         return x, (k_cache, v_cache)
 
     x, (k_new, v_new) = lax.scan(
         layer_fn, x, (params["layers"], cache["k"], cache["v"])
     )
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    head = (params["embed"]["kernel"].T if cfg.tie_embeddings
-            else params["lm_head"]["kernel"])
-    logits = x @ head.astype(cfg.dtype)
-    return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+    return _head(params, x, cfg), {"k": k_new, "v": v_new}
 
 
 def _top_k_mask(logits, top_k: int):
@@ -140,6 +167,7 @@ def _top_k_mask(logits, top_k: int):
     return logits
 
 
+@scope(SCOPE_SAMPLE)
 def sample_token(logits, key, temperature, top_k: int = 0):
     """logits [B, V], temperature [B] (<=0 → greedy), static top_k."""
     greedy = jnp.argmax(logits, axis=-1)
@@ -282,6 +310,7 @@ def _pool_write(pool, table, cols, vals):
     return pool.at[blk, cols % bs].set(vals)
 
 
+@scope(SCOPE_ATTN)
 def _ragged_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b, valid,
                       table=None, fused=False, mesh=None):
     """Single-token attention where row ``b`` writes cache slot ``pos_b[b]``
@@ -303,9 +332,9 @@ def _ragged_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b, valid,
     b, s, _d = x.shape
     hd = cfg.head_dim
     cos, sin = rope_bt
-    q = (x @ layer["wq"].astype(cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ layer["wk"].astype(cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ layer["wv"].astype(cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
+    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
     q = _rope(q, cos, sin)
     k = _rope(k, cos, sin)
     rows = jnp.arange(b)
@@ -326,18 +355,19 @@ def _ragged_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b, valid,
                 q[:, 0], k_cache, v_cache, table, pos_b,
                 n_kv_heads=cfg.n_kv_heads, mesh=mesh,
             ).reshape(b, s, cfg.n_heads * hd).astype(cfg.dtype)
-            return out @ layer["wo"].astype(cfg.dtype), k_cache, v_cache
+            return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
         k_read = _pool_gather(k_cache, table)
         v_read = _pool_gather(v_cache, table)
     out = _gqa_attention(q, k_read, v_read,
                          valid[:, None, None, None, :], cfg)
     # Quantized pools dequantize to f32; fold back to the compute dtype
     # (identity for fp pools) so the residual stream's dtype is stable.
-    return (out.astype(cfg.dtype) @ layer["wo"].astype(cfg.dtype),
+    return (out.astype(cfg.dtype) @ cast_param(layer["wo"], cfg.dtype),
             k_cache, v_cache)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "total_len"))
+@scope(SCOPE_PREFILL)
 def prefill(params, prompt_tokens, prompt_lengths, cfg: TransformerConfig, *,
             total_len: int):
     """One request's prompt pass: tokens [B, T0] right-padded → (cache with
@@ -398,6 +428,7 @@ def insert_row(state, slot, row_cache, last_logits, length, remaining,
     }
 
 
+@scope(SCOPE_PREFILL)
 def _admit_rows_body(state, params, cfg: TransformerConfig, slots,
                      prompt_tokens, prompt_lengths, remaining, temperature):
     total_len = state["cache"]["k"].shape[2]
@@ -502,6 +533,7 @@ def store_prefix_cache(pool, pool_slot, cache):
     }
 
 
+@scope(SCOPE_PREFILL)
 def _admit_prefix_body(state, params, cfg: TransformerConfig, slot, pool,
                        pool_slot, prefix_len, suffix_tokens, prompt_len,
                        remaining, temperature):
@@ -613,7 +645,7 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
     cos_t, sin_t = rotary_frequencies(cfg.head_dim, total,
                                       theta=cfg.rope_theta)
     rope_bt = (cos_t[pos_b[:, None]], sin_t[pos_b[:, None]])
-    x = params["embed"]["kernel"].astype(cfg.dtype)[tok][:, None]
+    x = _embed(params, tok, cfg)[:, None]
     valid = jnp.arange(total)[None, :] <= pos_b[:, None]
 
     def layer_fn(x, layer_and_cache):
@@ -625,26 +657,14 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
         )
         x = x + attn
         h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _aux = moe_ffn(h, layer["mlp"], cfg,
-                              token_valid=token_valid[:, None])
-            x = x + y
-        else:
-            gate = h @ layer["mlp"]["gate"].astype(cfg.dtype)
-            up = h @ layer["mlp"]["up"].astype(cfg.dtype)
-            x = x + (jax.nn.silu(gate) * up) @ layer["mlp"]["down"].astype(
-                cfg.dtype
-            )
+        x = x + _ffn(h, layer["mlp"], cfg, token_valid[:, None])
         return x, (k_cache, v_cache)
 
     x, (k_new, v_new) = lax.scan(
         layer_fn, x, (params["layers"], k_cache0, v_cache0)
     )
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    head = (params["embed"]["kernel"].T if cfg.tie_embeddings
-            else params["lm_head"]["kernel"])
-    logits = (x @ head.astype(cfg.dtype)).astype(jnp.float32)[:, 0]
-    return logits, k_new, v_new
+    return _head(params, x, cfg)[:, 0], k_new, v_new
 
 
 def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
@@ -661,29 +681,30 @@ def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
     key, sub = jax.random.split(state["key"])
     tok = sample_token(state["last_logits"], sub, state["temperature"], top_k)
     p_b = state["length"]
-    logits, k_new, v_new = _single_token_forward(
-        params, cfg, k0, v0, tok, p_b, emit, table=table, fused=fused,
-        mesh=mesh,
-    )
-    step_inc = emit.astype(jnp.int32)
-    length = p_b + step_inc
-    remaining = state["remaining"] - step_inc
-    active = emit & (remaining > 0) & (length < total)
-    if eos_id is not None:
-        hit_eos = emit & (tok == eos_id)
-        active = active & ~hit_eos
-        # Park like retire_row: an out-of-bounds write position drops the
-        # row's cache scatter on subsequent fused steps.
-        length = jnp.where(hit_eos, total, length)
-    new_state = {
-        **state,
-        "length": length,
-        "remaining": remaining,
-        "active": active,
-        "last_logits": jnp.where(emit[:, None], logits,
-                                 state["last_logits"]),
-        "key": key,
-    }
+    with scope(SCOPE_DECODE):
+        logits, k_new, v_new = _single_token_forward(
+            params, cfg, k0, v0, tok, p_b, emit, table=table, fused=fused,
+            mesh=mesh,
+        )
+        step_inc = emit.astype(jnp.int32)
+        length = p_b + step_inc
+        remaining = state["remaining"] - step_inc
+        active = emit & (remaining > 0) & (length < total)
+        if eos_id is not None:
+            hit_eos = emit & (tok == eos_id)
+            active = active & ~hit_eos
+            # Park like retire_row: an out-of-bounds write position drops
+            # the row's cache scatter on subsequent fused steps.
+            length = jnp.where(hit_eos, total, length)
+        new_state = {
+            **state,
+            "length": length,
+            "remaining": remaining,
+            "active": active,
+            "last_logits": jnp.where(emit[:, None], logits,
+                                     state["last_logits"]),
+            "key": key,
+        }
     return _with_kv(new_state, k_new, v_new), tok, emit
 
 
@@ -753,6 +774,7 @@ def decode_chunk(state, params, cfg: TransformerConfig, steps: int,
 # step, so not advancing past the accepted region IS the rollback.
 
 
+@scope(SCOPE_ATTN)
 def _span_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b,
                     table=None, fused=False, mesh=None, ring=None):
     """Block attention where row ``b``'s ``S`` tokens occupy cache slots
@@ -774,9 +796,9 @@ def _span_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b,
     b, s, _d = x.shape
     hd = cfg.head_dim
     cos, sin = rope_bt
-    q = (x @ layer["wq"].astype(cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ layer["wk"].astype(cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ layer["wv"].astype(cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
+    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
     q = _rope(q, cos, sin)
     k = _rope(k, cos, sin)
     cols = pos_b[:, None] + jnp.arange(s)[None, :]
@@ -797,7 +819,7 @@ def _span_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b,
                 q, k_cache, v_cache, table, pos_b,
                 n_kv_heads=cfg.n_kv_heads, mesh=mesh,
             ).reshape(b, s, cfg.n_heads * hd).astype(cfg.dtype)
-            return out @ layer["wo"].astype(cfg.dtype), k_cache, v_cache
+            return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
         k_read = _pool_gather(k_cache, table)
         v_read = _pool_gather(v_cache, table)
         total = table.shape[1] * _kv_arr(k_cache).shape[1]
@@ -807,10 +829,10 @@ def _span_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b,
                 mesh=ring,
             ).astype(cfg.dtype)
             return (out.reshape(b, s, cfg.n_heads * hd)
-                    @ layer["wo"].astype(cfg.dtype), k_cache, v_cache)
+                    @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache)
     mask = jnp.arange(total)[None, None, :] <= cols[:, :, None]
     out = _gqa_attention(q, k_read, v_read, mask[:, None, None], cfg)
-    return (out.astype(cfg.dtype) @ layer["wo"].astype(cfg.dtype),
+    return (out.astype(cfg.dtype) @ cast_param(layer["wo"], cfg.dtype),
             k_cache, v_cache)
 
 
@@ -829,7 +851,7 @@ def _block_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
                                       theta=cfg.rope_theta)
     pos = pos_b[:, None] + jnp.arange(s)[None, :]
     rope_bt = (cos_t[pos], sin_t[pos])
-    x = params["embed"]["kernel"].astype(cfg.dtype)[tokens]
+    x = _embed(params, tokens, cfg)
 
     def layer_fn(x, layer_and_cache):
         layer, k_cache, v_cache = layer_and_cache
@@ -840,24 +862,14 @@ def _block_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
         )
         x = x + attn
         h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _aux = moe_ffn(h, layer["mlp"], cfg, token_valid=token_valid)
-            x = x + y
-        else:
-            gate = h @ layer["mlp"]["gate"].astype(cfg.dtype)
-            up = h @ layer["mlp"]["up"].astype(cfg.dtype)
-            x = x + (jax.nn.silu(gate) * up) @ layer["mlp"]["down"].astype(
-                cfg.dtype
-            )
+        x = x + _ffn(h, layer["mlp"], cfg, token_valid)
         return x, (k_cache, v_cache)
 
     x, (k_new, v_new) = lax.scan(
         layer_fn, x, (params["layers"], k_cache0, v_cache0)
     )
     x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    head = (params["embed"]["kernel"].T if cfg.tie_embeddings
-            else params["lm_head"]["kernel"])
-    return (x @ head.astype(cfg.dtype)).astype(jnp.float32), k_new, v_new
+    return _head(params, x, cfg), k_new, v_new
 
 
 def _target_probs(logits, temperature, top_k: int):
@@ -871,6 +883,7 @@ def _target_probs(logits, temperature, top_k: int):
     return jax.nn.softmax(logits / temp, axis=-1)
 
 
+@scope(SCOPE_DECODE)
 def _verify_step_body(state, params, cfg: TransformerConfig, draft,
                       draft_len, top_k: int, eos_id: int | None,
                       fused: bool = False, mesh=None):
@@ -1135,6 +1148,7 @@ def init_paged_state(cfg: TransformerConfig, slots: int, num_blocks: int,
     }
 
 
+@scope(SCOPE_PREFILL)
 def _paged_admit_rows_body(state, params, cfg: TransformerConfig, slots,
                            prompt_tokens, prompt_lengths, remaining,
                            temperature):
@@ -1207,6 +1221,7 @@ def paged_admit_rows_and_step(state, params, cfg: TransformerConfig, slots,
     return state, last, tok, emit
 
 
+@scope(SCOPE_PREFILL)
 def _paged_admit_prefix_body(state, params, cfg: TransformerConfig, slot,
                              prefix_len, suffix_tokens, prompt_len,
                              remaining, temperature, fused=False,
@@ -1272,6 +1287,7 @@ def paged_admit_prefix_and_step(state, params, cfg: TransformerConfig, slot,
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "kv_fused", "mesh", "ring"),
                    donate_argnames=("state",))
+@scope(SCOPE_PREFILL)
 def paged_prefill_chunk(state, params, cfg: TransformerConfig, slot, pos,
                         chunk_tokens, chunk_len, kv_fused: bool = False,
                         mesh=None, ring=None):

@@ -28,6 +28,15 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
+from kubeflow_tpu.observability.tracing import (
+    SCOPE_ATTN,
+    SCOPE_CAST_WEIGHTS,
+    SCOPE_EMBED,
+    SCOPE_HEAD,
+    SCOPE_HEAD_LOSS,
+    SCOPE_MLP,
+    scope,
+)
 from kubeflow_tpu.ops import flash_attention, rms_norm
 from kubeflow_tpu.ops.rotary import apply_rotary, rotary_frequencies
 from kubeflow_tpu.parallel.mesh import (
@@ -275,19 +284,28 @@ def batch_partition_spec(cfg: TransformerConfig) -> P:
 # ---------------------------------------------------------------------------
 
 
+def cast_param(w, dtype):
+    """A parameter leaf at the compute dtype. Every such cast goes through
+    here (this module and models/decode.py), so the convert carries the
+    ``cast_weights`` scope wherever XLA hoists or fuses it."""
+    with scope(SCOPE_CAST_WEIGHTS):
+        return w.astype(dtype)
+
+
 def _constrain(x, mesh, spec):
     if mesh is not None:
         x = lax.with_sharding_constraint(x, jax.NamedSharding(mesh, spec))
     return x
 
 
+@scope(SCOPE_ATTN)
 def _attention(x, layer, cfg: TransformerConfig, rope, mesh):
     b, t, d = x.shape
     hd = cfg.head_dim
     cos, sin = rope
-    q = (x @ layer["wq"].astype(cfg.dtype)).reshape(b, t, cfg.n_heads, hd)
-    k = (x @ layer["wk"].astype(cfg.dtype)).reshape(b, t, cfg.n_kv_heads, hd)
-    v = (x @ layer["wv"].astype(cfg.dtype)).reshape(b, t, cfg.n_kv_heads, hd)
+    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, t, cfg.n_heads, hd)
+    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, t, cfg.n_kv_heads, hd)
+    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, t, cfg.n_kv_heads, hd)
     # Inert unless the policy names them ("llm_qkv"): saving post-rope
     # q/k/v spares the backward from re-running rms_norm + the three
     # projections + rope just to rebuild the flash kernel's residuals.
@@ -318,15 +336,18 @@ def _attention(x, layer, cfg: TransformerConfig, rope, mesh):
     # Inert without the "llm" policy: wo's backward reuses its input, so
     # saving it here spares recomputing the whole attention block.
     out = checkpoint_name(out, "attn_ctx")
-    return out @ layer["wo"].astype(cfg.dtype)
+    return out @ cast_param(layer["wo"], cfg.dtype)
 
 
+@scope(SCOPE_MLP)
 def _mlp(x, layer, cfg: TransformerConfig):
-    gate = checkpoint_name(x @ layer["gate"].astype(cfg.dtype), "mlp_gate")
-    up = checkpoint_name(x @ layer["up"].astype(cfg.dtype), "mlp_up")
-    return (jax.nn.silu(gate) * up) @ layer["down"].astype(cfg.dtype)
+    gate = checkpoint_name(x @ cast_param(layer["gate"], cfg.dtype),
+                           "mlp_gate")
+    up = checkpoint_name(x @ cast_param(layer["up"], cfg.dtype), "mlp_up")
+    return (jax.nn.silu(gate) * up) @ cast_param(layer["down"], cfg.dtype)
 
 
+@scope(SCOPE_MLP)
 def moe_ffn(x, mlp, cfg: TransformerConfig, token_valid=None):
     """GShard-style MoE FFN: top-k routing with static per-expert capacity.
 
@@ -353,7 +374,7 @@ def moe_ffn(x, mlp, cfg: TransformerConfig, token_valid=None):
     xf = x.reshape(n, d)
 
     logits = (xf.astype(jnp.float32)
-              @ mlp["router"].astype(jnp.float32))  # router in fp32
+              @ cast_param(mlp["router"], jnp.float32))  # router in fp32
     probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
     gate_vals, expert_idx = lax.top_k(probs, k)  # [n, k]
     gate_vals = gate_vals / jnp.maximum(
@@ -386,10 +407,13 @@ def moe_ffn(x, mlp, cfg: TransformerConfig, token_valid=None):
     expert_in = jnp.einsum(
         "nd,nec->ecd", xf, dispatch.astype(cfg.dtype)
     )  # [e, c, d]
-    g = jnp.einsum("ecd,edf->ecf", expert_in, mlp["gate"].astype(cfg.dtype))
-    u = jnp.einsum("ecd,edf->ecf", expert_in, mlp["up"].astype(cfg.dtype))
+    g = jnp.einsum("ecd,edf->ecf", expert_in,
+                   cast_param(mlp["gate"], cfg.dtype))
+    u = jnp.einsum("ecd,edf->ecf", expert_in,
+                   cast_param(mlp["up"], cfg.dtype))
     out = jnp.einsum(
-        "ecf,efd->ecd", jax.nn.silu(g) * u, mlp["down"].astype(cfg.dtype)
+        "ecf,efd->ecd", jax.nn.silu(g) * u,
+        cast_param(mlp["down"], cfg.dtype)
     )
     y = jnp.einsum("ecd,nec->nd", out, combine)
 
@@ -449,14 +473,16 @@ def _layer_fn_attn_saved(cfg: TransformerConfig, mesh, rope, mlp_policy,
     return (x, aux), None
 
 
+@scope(SCOPE_EMBED)
 def _embed_lookup(kernel, tokens, cfg: TransformerConfig, mesh):
-    """Token embedding. Under a tensor-parallel mesh the lookup runs as a
-    one-hot matmul: GSPMD partitions matmuls cleanly (contraction over the
+    """Token embedding (``kernel`` arrives uncast). Under a tensor-parallel
+    mesh the lookup runs as a one-hot matmul: GSPMD partitions matmuls cleanly (contraction over the
     tensor-sharded vocab dim → one reduce), where a gather from a sharded
     table triggers involuntary full rematerialization (spmd_partitioner
     replicate-then-reshard, observed on the dryrun tp path); the backward
     scatter-add becomes a matmul too. Plain gather elsewhere — one-hot costs
     O(B·T·V) flops it only earns back when it buys clean partitioning."""
+    kernel = cast_param(kernel, cfg.dtype)
     if mesh is not None and mesh.shape.get(AXIS_TENSOR, 1) > 1:
         one_hot = jax.nn.one_hot(tokens, cfg.vocab_size, dtype=kernel.dtype)
         return one_hot @ kernel
@@ -469,9 +495,7 @@ def hidden_states(params, tokens, cfg: TransformerConfig, *, mesh=None):
     training-loss path applies the head inside the loss instead."""
     t = tokens.shape[1]
     rope = rotary_frequencies(cfg.head_dim, t, theta=cfg.rope_theta)
-    x = _embed_lookup(
-        params["embed"]["kernel"].astype(cfg.dtype), tokens, cfg, mesh
-    )
+    x = _embed_lookup(params["embed"]["kernel"], tokens, cfg, mesh)
     x = _constrain(x, mesh, P(*(batch_partition_spec(cfg) + (None,))))
 
     policy = {
@@ -601,10 +625,11 @@ def hidden_states(params, tokens, cfg: TransformerConfig, *, mesh=None):
     return x, aux
 
 
-def _head_kernel(params, cfg: TransformerConfig):
+def head_kernel(params, cfg: TransformerConfig):
+    """The LM head matrix [D, V] at the compute dtype."""
     if cfg.tie_embeddings:
-        return params["embed"]["kernel"].T
-    return params["lm_head"]["kernel"]
+        return cast_param(params["embed"]["kernel"], cfg.dtype).T
+    return cast_param(params["lm_head"]["kernel"], cfg.dtype)
 
 
 def apply(params, tokens, cfg: TransformerConfig, *, mesh=None,
@@ -614,7 +639,8 @@ def apply(params, tokens, cfg: TransformerConfig, *, mesh=None,
     ``return_aux=True`` additionally returns the summed MoE router
     load-balance loss (0.0 for dense models)."""
     x, aux = hidden_states(params, tokens, cfg, mesh=mesh)
-    logits = x @ _head_kernel(params, cfg).astype(cfg.dtype)
+    with scope(SCOPE_HEAD):
+        logits = x @ head_kernel(params, cfg)
     if return_aux:
         return logits, aux
     return logits
@@ -630,18 +656,18 @@ def loss_fn(params, batch, cfg: TransformerConfig, *, mesh=None):
         inputs, targets = batch["inputs"], batch["targets"]
     else:
         inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
-    if cfg.loss_chunks:
-        x, aux = hidden_states(params, inputs, cfg, mesh=mesh)
-        b, t, d = x.shape
-        loss, metrics = chunked_lm_head_loss(
-            x.reshape(b * t, d),
-            _head_kernel(params, cfg).astype(cfg.dtype),
-            targets.reshape(b * t),
-            z_loss=1e-4, n_chunks=cfg.loss_chunks,
-        )
-    else:
-        logits, aux = apply(params, inputs, cfg, mesh=mesh, return_aux=True)
-        loss, metrics = softmax_cross_entropy(logits, targets, z_loss=1e-4)
+    x, aux = hidden_states(params, inputs, cfg, mesh=mesh)
+    with scope(SCOPE_HEAD_LOSS):
+        head = head_kernel(params, cfg)
+        if cfg.loss_chunks:
+            b, t, d = x.shape
+            loss, metrics = chunked_lm_head_loss(
+                x.reshape(b * t, d), head, targets.reshape(b * t),
+                z_loss=1e-4, n_chunks=cfg.loss_chunks,
+            )
+        else:
+            loss, metrics = softmax_cross_entropy(x @ head, targets,
+                                                  z_loss=1e-4)
     if cfg.n_experts and cfg.router_aux_loss:
         aux_loss = cfg.router_aux_loss * aux
         metrics["router_aux_loss"] = aux_loss

@@ -14,6 +14,17 @@ chrome://tracing / Perfetto - loadable trace-event file), so a slow
 request's breakdown is one curl away. Spans are derived from consecutive
 events, which makes the invariant the E2E test pins: the span durations
 of a closed timeline sum to exactly its submit→finish wall time.
+
+The second half of the module is the program's phase names on the
+PROFILER's clock: device work is named with :func:`scope`
+(``jax.named_scope`` — HLO ``op_name`` metadata, nothing at run time) and
+the scheduler thread's work with :func:`host_span`
+(``jax.profiler.TraceAnnotation`` — a no-op unless a profiler session is
+open), so both land in the one xplane a ``jax.profiler`` capture writes.
+Every name is a constant here; the program, the benchmark's readers, the
+tests and the docs import or quote these and nobody spells one twice. A
+``sched.*`` span carries ``round=<n>``, the number a request's
+``admitted`` and ``first_token`` timeline events carry too.
 """
 
 from __future__ import annotations
@@ -24,6 +35,52 @@ import uuid
 from collections import deque
 
 REQUEST_ID_HEADER = "X-Request-ID"
+
+# Device scopes. Outer: which dispatch phase an op belongs to.
+SCOPE_PREFILL = "prefill"
+SCOPE_DECODE = "decode"
+SCOPE_SAMPLE = "sample"
+# Inner: which part of the model. ``cast_weights`` wraps every cast of a
+# parameter leaf to the compute dtype (models/transformer.py:cast_param).
+SCOPE_EMBED = "embed"
+SCOPE_ATTN = "attn"
+SCOPE_MLP = "mlp"
+SCOPE_HEAD = "head"
+SCOPE_CAST_WEIGHTS = "cast_weights"
+# Train step only. Backward ops keep JAX's own ``transpose(jvp(<scope>))``
+# marker in the same path, so forward and backward share a scope.
+SCOPE_HEAD_LOSS = "head_loss"
+SCOPE_OPTIMIZER = "optimizer"
+DEVICE_SCOPES = (SCOPE_PREFILL, SCOPE_DECODE, SCOPE_SAMPLE, SCOPE_EMBED,
+                 SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD, SCOPE_CAST_WEIGHTS,
+                 SCOPE_HEAD_LOSS, SCOPE_OPTIMIZER)
+
+# Host spans of the scheduler thread (serving/continuous.py:_run): one
+# ``sched.round`` per pass of the loop, its children named by phase.
+SPAN_PREFIX = "sched."
+SPAN_ROUND = SPAN_PREFIX + "round"
+SCHED_PHASES = ("idle", "plan", "build", "dispatch", "fetch", "route")
+PHASE_COUNTER = "serving_scheduler_phase_seconds_total"
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``: context manager or decorator (it runs
+    while tracing, never per step). Setting one also puts op metadata into
+    the persistent compile cache's key: by default JAX leaves it out, and
+    an executable cached by a build that set other names (or none) would be
+    loaded as it is, its stale names in every profile."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    return jax.named_scope(name)
+
+
+def host_span(name: str, **args):
+    """``jax.profiler.TraceAnnotation(name, **args)``: ``args`` become the
+    event's stats in the xplane."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def gen_request_id() -> str:
@@ -41,7 +98,9 @@ class Timeline:
     def __init__(self, request_id: str, *, max_events: int = 96,
                  on_close=None) -> None:
         self.request_id = request_id
-        self.start_wall = time.time()
+        # Back to back, so that ``start_wall`` places the timeline on the
+        # profiler's (wall) clock to within the two calls' distance.
+        self.start_wall = time.time_ns() * 1e-9
         self.start = time.perf_counter()
         self.status: str | None = None  # None = still open
         self.error: str | None = None
@@ -67,12 +126,13 @@ class Timeline:
             self._events.append((name, t, attrs))
 
     def close(self, status: str = "ok",
-              error: BaseException | str | None = None) -> None:
+              error: BaseException | str | None = None, **attrs) -> None:
         t = time.perf_counter() - self.start
         with self._lock:
             if self.status is not None:
                 return
-            attrs = {"error": str(error)} if error is not None else {}
+            if error is not None:
+                attrs["error"] = str(error)
             self._events.append(
                 ("error" if error is not None else "finish", t, attrs))
             self.status = "error" if error is not None else status

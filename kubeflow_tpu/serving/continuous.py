@@ -57,6 +57,7 @@ with the serving loop exposed.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -91,7 +92,14 @@ from kubeflow_tpu.models.decode import (
     verify_chunk,
 )
 from kubeflow_tpu.observability.metrics import MetricRegistry
-from kubeflow_tpu.observability.tracing import TraceStore
+from kubeflow_tpu.observability.tracing import (
+    PHASE_COUNTER,
+    SCHED_PHASES,
+    SPAN_PREFIX,
+    SPAN_ROUND,
+    TraceStore,
+    host_span,
+)
 from kubeflow_tpu.serving.affinity import (
     DEFAULT_AFFINITY_TOKENS,
     prefix_affinity_key,
@@ -156,6 +164,9 @@ class _Request:
     request_id: str = ""
     timeline: object | None = None
     last_emit_t: float | None = None
+    # Scheduler round that first admitted this request (0 = never): the
+    # terminal timeline event counts the rounds the stream lived.
+    admit_round: int = 0
     # QoS: owning tenant, base priority (tenant default unless the
     # request carried its own), and an absolute shed deadline (None =
     # never shed). ``defer_rounds`` counts rounds this request sat at
@@ -643,7 +654,6 @@ class ContinuousDecoder:
         self.prefix_tokens_reused = 0   # prompt tokens served from the pool
         self.prefix_suffix_tokens = 0   # suffix tokens prefilled on hits
         self.prefix_inserts = 0         # prefixes published to the pool
-        self.ramp_rounds = 0         # admission-only (no-chunk) rounds
         # Speculative-decoding counters (zero when speculation is off).
         self.spec_drafted_tokens = 0    # draft tokens submitted to verify
         self.spec_accepted_tokens = 0   # draft tokens the target kept
@@ -769,6 +779,18 @@ class ContinuousDecoder:
         # Per-stream lifecycle timelines, bounded ring, served at the
         # model server's /debug/requests (JSON + chrome-trace export).
         self.trace = TraceStore()
+        # Scheduler rounds begun (one per pass of _run's loop): the number
+        # every sched.* span carries, and a request's ``admitted`` and
+        # ``first_token`` timeline events with it.
+        self._round = 0
+        phase_seconds = self.registry.counter(
+            PHASE_COUNTER,
+            "Scheduler-thread seconds by phase of a round: idle (waiting "
+            "for work), plan (under the queue lock), build (host arrays "
+            "for a dispatch), dispatch (enqueue of the jitted call), fetch "
+            "(device_get), route (tokens to streams, finishes)",
+            labels=("phase",))
+        self._c_phase = {p: phase_seconds.labels(p) for p in SCHED_PHASES}
         self._ramp_streak = 0  # consecutive admission-only rounds
         if self.prefix_cache is not None and self._alloc is not None:
             # Trie evictions must return the entry's refcounted blocks
@@ -1040,8 +1062,12 @@ class ContinuousDecoder:
         if req.timeline is not None:
             # Every finish path funnels here, so a closed request can
             # never leak an open timeline — the invariant the chaos
-            # (_fail_all) test pins.
-            req.timeline.close(req.finish_reason, error=error)
+            # (_fail_all) test pins. The decode phase is the one span
+            # first_token→finish; its size rides the terminal event.
+            rounds = self._round - req.admit_round + 1 \
+                if req.admit_round else 0
+            req.timeline.close(req.finish_reason, error=error,
+                               tokens=len(req.out), rounds=rounds)
         req.stream.put(_DONE)
         req.done.set()
 
@@ -1143,27 +1169,28 @@ class ContinuousDecoder:
         full-``prefill_len`` prefill compute.
         """
         k = len(pending)
-        bucket = pow2_bucket(k)
-        t = self._seq_bucket(max(len(req.tokens) for req, _ in pending))
-        toks = np.zeros((bucket, t), np.int32)
-        lengths = np.ones((bucket,), np.int32)
-        slots = np.zeros((bucket,), np.int32)
-        temps = np.zeros((bucket,), np.float32)
-        wants = np.zeros((bucket,), np.int32)
-        for i in range(bucket):
-            req, slot = pending[min(i, k - 1)]  # pad = repeat last real
-            toks[i, : len(req.tokens)] = req.tokens
-            lengths[i] = max(len(req.tokens), 1)
-            slots[i] = slot
-            temps[i] = req.temperature
-            wants[i] = req.want_left
+        with self._phase("build", "admit"):
+            bucket = pow2_bucket(k)
+            t = self._seq_bucket(max(len(req.tokens) for req, _ in pending))
+            toks = np.zeros((bucket, t), np.int32)
+            lengths = np.ones((bucket,), np.int32)
+            slots = np.zeros((bucket,), np.int32)
+            temps = np.zeros((bucket,), np.float32)
+            wants = np.zeros((bucket,), np.int32)
+            for i in range(bucket):
+                req, slot = pending[min(i, k - 1)]  # pad = repeat last real
+                toks[i, : len(req.tokens)] = req.tokens
+                lengths[i] = max(len(req.tokens), 1)
+                slots[i] = slot
+                temps[i] = req.temperature
+                wants[i] = req.want_left
         # ONE admission executable per (batch, length) bucket: always the
         # fused variant (the extra decode step is ~free on device, and a
         # second plain-admit executable would surprise-compile
         # mid-traffic). The paged twin reads each slot's block-table row
         # (allocated at pop time) instead of scattering into dense rows.
         t_disp = time.perf_counter()
-        with self._state_lock:
+        with self._phase("dispatch", "admit"), self._state_lock:
             # The weights epoch this admission's prefill runs under —
             # read inside the same lock scope that passes self.params
             # to the dispatch, so it can never stamp the wrong epoch.
@@ -1197,22 +1224,29 @@ class ContinuousDecoder:
         # Fetch ONLY the fused step's tokens (one small transfer);
         # vocab-wide prefill logits stay on device behind a lazy
         # per-request resolver — an eager [K, V] fetch each admission
-        # round is a copy most requests never read.
-        tok_np, emit_np = jax.device_get((tok, emit))
-        self._h_dispatch.labels("admit").observe(
-            time.perf_counter() - t_disp)
-        for i, (req, slot) in enumerate(pending):
-            req.prefill_src = (last, i)
-            if req.timeline is not None:
-                req.timeline.event("prefill", tokens=len(req.tokens),
-                                   bucket=t)
-            self._post_admit(req, slot)
-        # The fused decode step's tokens (new rows' first token AND
-        # every peer row's next token) — routed after _post_admit so
-        # the new rows are registered.
-        with self._mlock:
-            self.steps += 1
-        self._dispatch(tok_np, emit_np)
+        # round is a copy most requests never read. The names are
+        # rebound so the two device arrays are freed here, inside the
+        # fetch span, as the decode round frees its own: held to the end
+        # of this function they were freed after route had woken every
+        # stream's reader, and the free lets go of the GIL — a
+        # millisecond of this thread under no sched.* span.
+        with self._phase("fetch", "admit"):
+            tok, emit = jax.device_get((tok, emit))
+            self._h_dispatch.labels("admit").observe(
+                time.perf_counter() - t_disp)
+        with self._phase("route", "admit"):
+            for i, (req, slot) in enumerate(pending):
+                req.prefill_src = (last, i)
+                if req.timeline is not None:
+                    req.timeline.event("prefill", tokens=len(req.tokens),
+                                       bucket=t)
+                self._post_admit(req, slot)
+            # The fused decode step's tokens (new rows' first token AND
+            # every peer row's next token) — routed after _post_admit so
+            # the new rows are registered.
+            with self._mlock:
+                self.steps += 1
+            self._dispatch(tok, emit)
 
     def _seq_bucket(self, n: int) -> int:
         """Compiled prefill length for an ``n``-token prompt."""
@@ -1294,10 +1328,40 @@ class ContinuousDecoder:
         suffix-sized prefill compute. ``entry`` arrives pinned
         (match() refcounted it) and stays pinned until the request
         finishes."""
-        suffix = req.tokens[prefix_len:]
-        toks = np.zeros((1, s), np.int32)
-        toks[0, : len(suffix)] = suffix
+        with self._phase("build", "admit"):
+            suffix = req.tokens[prefix_len:]
+            toks = np.zeros((1, s), np.int32)
+            toks[0, : len(suffix)] = suffix
         t_disp = time.perf_counter()
+        with self._phase("dispatch", "admit"):
+            last, tok, emit = self._dispatch_prefix(
+                req, slot, entry, prefix_len, toks)
+            req.pinned_prefix = entry
+            with self._mlock:
+                self.prefill_dispatches += 1
+                self.admitted += 1
+                self.prefix_hits += 1
+                self.prefix_tokens_reused += prefix_len
+                self.prefix_suffix_tokens += len(suffix)
+                self.prefill_tokens += len(suffix)
+        with self._phase("fetch", "admit"):
+            tok, emit = jax.device_get((tok, emit))
+            self._h_dispatch.labels("admit").observe(
+                time.perf_counter() - t_disp)
+        with self._phase("route", "admit"):
+            req.prefill_src = (last, 0)
+            if req.timeline is not None:
+                req.timeline.event("prefill", tokens=len(suffix),
+                                   prefix_reused=prefix_len, bucket=s)
+            self._post_admit(req, slot)
+            with self._mlock:
+                self.steps += 1
+            self._dispatch(tok, emit)
+
+    def _dispatch_prefix(self, req: _Request, slot: int, entry,
+                         prefix_len: int, toks: np.ndarray):
+        """Enqueue a prefix-hit admission (either KV layout). Returns
+        (prefill last-logits, sampled token, emitted mask), on device."""
         if self._alloc is not None:
             # The pop-time reservation already mapped the donor's FULL
             # prefix blocks into this slot by refcount — zero device
@@ -1343,25 +1407,7 @@ class ContinuousDecoder:
                     jnp.int32(req.want_left),
                     jnp.float32(req.temperature),
                     self.top_k, self.eos_id)
-        req.pinned_prefix = entry
-        with self._mlock:
-            self.prefill_dispatches += 1
-            self.admitted += 1
-            self.prefix_hits += 1
-            self.prefix_tokens_reused += prefix_len
-            self.prefix_suffix_tokens += len(suffix)
-            self.prefill_tokens += len(suffix)
-        tok_np, emit_np = jax.device_get((tok, emit))
-        self._h_dispatch.labels("admit").observe(
-            time.perf_counter() - t_disp)
-        req.prefill_src = (last, 0)
-        if req.timeline is not None:
-            req.timeline.event("prefill", tokens=len(suffix),
-                               prefix_reused=prefix_len, bucket=s)
-        self._post_admit(req, slot)
-        with self._mlock:
-            self.steps += 1
-        self._dispatch(tok_np, emit_np)
+        return last, tok, emit
 
     def _begin_chunked(self, req: _Request, slot: int) -> None:
         """Register a long admission as a chunk job. The slot and its
@@ -1394,8 +1440,9 @@ class ContinuousDecoder:
                                prefix_reused=plen,
                                chunk_tokens=self.prefill_chunk_tokens)
 
-    def _advance_chunked(self) -> None:
-        """Run AT MOST ONE chunk dispatch — the oldest job's next chunk.
+    def _advance_chunked(self) -> bool:
+        """Run AT MOST ONE chunk dispatch — the oldest job's next chunk;
+        returns whether one ran.
         One chunk per round is the interleave that bounds a live
         stream's inter-token gap at one chunk of prefill compute.
 
@@ -1409,22 +1456,23 @@ class ContinuousDecoder:
         exactly the pinned prefix-hit path, so the chain ends in the
         same dispatch shape a cache hit uses."""
         if not self._chunk_jobs:
-            return
+            return False
         req, slot = self._chunk_jobs[0]
-        n = len(req.tokens)
-        pos = req.chunk_pos
-        remaining = n - pos
-        final = remaining <= self.prefill_chunk_tokens
-        take = remaining if final else self.prefill_chunk_tokens
-        s = self._suffix_bucket(take)
-        toks = np.zeros((1, s), np.int32)
-        toks[0, :take] = req.tokens[pos: pos + take]
-        first = not req.chunk_started
-        plan = req.admit_plan
-        bs = self.kv_block_size
-        restart = False
+        with self._phase("build", "chunk"):
+            n = len(req.tokens)
+            pos = req.chunk_pos
+            remaining = n - pos
+            final = remaining <= self.prefill_chunk_tokens
+            take = remaining if final else self.prefill_chunk_tokens
+            s = self._suffix_bucket(take)
+            toks = np.zeros((1, s), np.int32)
+            toks[0, :take] = req.tokens[pos: pos + take]
+            first = not req.chunk_started
+            plan = req.admit_plan
+            bs = self.kv_block_size
+            restart = False
         t_disp = time.perf_counter()
-        with self._state_lock:
+        with self._phase("dispatch", "chunk"), self._state_lock:
             if first:
                 # First chunk: stamp the weights epoch, CoW the plan's
                 # partially-shared tail block, and map the table row —
@@ -1466,7 +1514,7 @@ class ContinuousDecoder:
                         self.kv_fused, self._kmesh, ring=self._ring)
         if restart:
             self._restart_chunked(req, slot)
-            return
+            return False
         if first and plan is not None and plan[1] % bs:
             with self._mlock:
                 self.kv_cow_copies += 1
@@ -1482,7 +1530,7 @@ class ContinuousDecoder:
             if req.timeline is not None:
                 req.timeline.event("prefill_chunk", pos=pos, tokens=take,
                                    bucket=s)
-            return
+            return True
         # Final chunk: the chain is done — promote to an ordinary
         # admitted stream (the fused step's token dispatches below).
         self._chunk_jobs.pop(0)
@@ -1490,17 +1538,21 @@ class ContinuousDecoder:
             self.prefill_dispatches += 1
             self.admitted += 1
             self.prefill_tokens += take
-        tok_np, emit_np = jax.device_get((tok, emit))
+        with self._phase("fetch", "chunk"):
+            tok, emit = jax.device_get((tok, emit))
         self._h_dispatch.labels("admit").observe(dt)
-        req.prefill_src = (last, 0)
-        if req.timeline is not None:
-            req.timeline.event("prefill", tokens=take, prefix_reused=pos,
-                               bucket=s, chunked=True)
-        req.chunk_pos = -1
-        self._post_admit(req, slot)
-        with self._mlock:
-            self.steps += 1
-        self._dispatch(tok_np, emit_np)
+        with self._phase("route", "chunk"):
+            req.prefill_src = (last, 0)
+            if req.timeline is not None:
+                req.timeline.event("prefill", tokens=take,
+                                   prefix_reused=pos, bucket=s,
+                                   chunked=True)
+            req.chunk_pos = -1
+            self._post_admit(req, slot)
+            with self._mlock:
+                self.steps += 1
+            self._dispatch(tok, emit)
+        return True
 
     def _restart_chunked(self, req: _Request, slot: int) -> None:
         """Abort a mid-chain chunked admission and replay it from the
@@ -2562,8 +2614,9 @@ class ContinuousDecoder:
             if req.timeline is not None:
                 req.timeline.event("resume", emitted=len(req.out),
                                    want_left=req.want_left)
+        req.admit_round = req.admit_round or self._round
         if req.timeline is not None:
-            req.timeline.event("admitted", slot=slot,
+            req.timeline.event("admitted", slot=slot, round=self._round,
                                wait_ms=round(1e3 * wait, 3))
 
     def _post_admit(self, req: _Request, slot: int) -> None:
@@ -2605,12 +2658,9 @@ class ContinuousDecoder:
                 ttft_n += 1
                 self._h_ttft.observe(req.ttft_s)
                 if req.timeline is not None:
-                    req.timeline.event("first_token")
-            else:
-                if req.last_emit_t is not None:
-                    self._h_itl.observe(now - req.last_emit_t)
-                if req.timeline is not None:
-                    req.timeline.event("dispatch", tokens=1)
+                    req.timeline.event("first_token", round=self._round)
+            elif req.last_emit_t is not None:
+                self._h_itl.observe(now - req.last_emit_t)
             req.last_emit_t = now
             req.stream.put(tok)
             emitted_n += 1
@@ -2645,7 +2695,6 @@ class ContinuousDecoder:
                 continue
             last_tok = None
             row_emitted = 0
-            first_here = req.ttft_s is None
             for j in range(toks.shape[1]):
                 if not emitted[slot, j]:
                     break
@@ -2657,7 +2706,8 @@ class ContinuousDecoder:
                     ttft_n += 1
                     self._h_ttft.observe(req.ttft_s)
                     if req.timeline is not None:
-                        req.timeline.event("first_token")
+                        req.timeline.event("first_token",
+                                           round=self._round)
                 req.stream.put(last_tok)
                 emitted_n += 1
                 row_emitted += 1
@@ -2667,8 +2717,6 @@ class ContinuousDecoder:
                 if req.last_emit_t is not None:
                     self._h_itl.observe(now - req.last_emit_t)
                 req.last_emit_t = now
-                if req.timeline is not None and not first_here:
-                    req.timeline.event("dispatch", tokens=row_emitted)
             hit_eos = self.eos_id is not None and last_tok == self.eos_id
             if hit_eos or len(req.out) >= req.want:
                 self._publish_prefix(req, slot)
@@ -2704,6 +2752,50 @@ class ContinuousDecoder:
         (a verify without drafts would pay two forwards for one token).
         """
         steps, k_w = self._verify_steps, self.speculative_k
+        with self._phase("build", "verify"):
+            drafts, dlens = self._collect_drafts(steps, k_w)
+        if not dlens.any():
+            return False
+        self._h_occupancy.observe(self._active_count)
+        t_disp = time.perf_counter()
+        with self._phase("dispatch", "verify"):
+            with self._state_lock:
+                self._state, outs, emits = verify_chunk(
+                    self._state, self.params, self.cfg, jnp.asarray(drafts),
+                    jnp.asarray(dlens), self.top_k, self.eos_id,
+                    self.kv_fused, self._kmesh)
+            with self._mlock:
+                self.dispatches += 1
+                self.spec_verify_dispatches += 1
+                self.steps += 2 * steps  # scoring + commit per verify
+        self._ramp_streak = 0
+        with self._phase("fetch", "verify"):
+            outs, emits = jax.device_get((outs, emits))
+            self._h_dispatch.labels("verify").observe(
+                time.perf_counter() - t_disp)
+        with self._phase("route", "verify"):
+            for s in range(steps):
+                # Accounting before routing: routing may free the slot.
+                drafted, accepted = 0, 0
+                for slot in range(self.slots):
+                    d = int(dlens[s, slot])
+                    if d == 0 or self._slot_req[slot] is None:
+                        continue
+                    m = int(emits[s, slot].sum())
+                    acc = min(max(m - 1, 0), d)
+                    drafted += d
+                    accepted += acc
+                    if m:
+                        self._tune_slot(slot, acc, d)
+                with self._mlock:
+                    self.spec_drafted_tokens += drafted
+                    self.spec_accepted_tokens += accepted
+                self._dispatch_block(outs[s], emits[s])
+        return True
+
+    def _collect_drafts(self, steps: int, k_w: int):
+        """Proposals for every live row as the verify dispatch takes
+        them: (drafts [steps, slots, k_w], their lengths [steps, slots])."""
         asks = []
         for slot in range(self.slots):
             req = self._slot_req[slot]
@@ -2738,41 +2830,7 @@ class ContinuousDecoder:
                     break
                 drafts[s, slot, : len(seg)] = seg
                 dlens[s, slot] = len(seg)
-        if not dlens.any():
-            return False
-        self._h_occupancy.observe(self._active_count)
-        t_disp = time.perf_counter()
-        with self._state_lock:
-            self._state, outs, emits = verify_chunk(
-                self._state, self.params, self.cfg, jnp.asarray(drafts),
-                jnp.asarray(dlens), self.top_k, self.eos_id,
-                self.kv_fused, self._kmesh)
-        with self._mlock:
-            self.dispatches += 1
-            self.spec_verify_dispatches += 1
-            self.steps += 2 * steps  # scoring + commit forward per verify
-        self._ramp_streak = 0
-        outs, emits = jax.device_get((outs, emits))
-        self._h_dispatch.labels("verify").observe(
-            time.perf_counter() - t_disp)
-        for s in range(steps):
-            # Accounting before routing: routing may free the slot.
-            drafted, accepted = 0, 0
-            for slot in range(self.slots):
-                d = int(dlens[s, slot])
-                if d == 0 or self._slot_req[slot] is None:
-                    continue
-                m = int(emits[s, slot].sum())
-                acc = min(max(m - 1, 0), d)
-                drafted += d
-                accepted += acc
-                if m:
-                    self._tune_slot(slot, acc, d)
-            with self._mlock:
-                self.spec_drafted_tokens += drafted
-                self.spec_accepted_tokens += accepted
-            self._dispatch_block(outs[s], emits[s])
-        return True
+        return drafts, dlens
 
     def _loop(self) -> None:
         """Scheduler-thread entry: run the loop, and on ANY exit — clean
@@ -2809,288 +2867,336 @@ class ContinuousDecoder:
         for req in queued:
             self._finish(req, error=err)
 
+    @contextlib.contextmanager
+    def _phase(self, phase: str, kind: str):
+        """One phase of the current scheduler round: a ``sched.<phase>``
+        span on the profiler's clock (a no-op unless a capture is open)
+        and, always, its seconds on the phase counter."""
+        t0 = time.perf_counter()
+        with host_span(SPAN_PREFIX + phase, round=self._round, kind=kind):
+            yield
+        self._c_phase[phase].inc(time.perf_counter() - t0)
+
     def _run(self) -> None:
         while True:
-            idled = False
-            with self._cv:
-                while (not self._stopped and not self._pending
-                       and self._active_count == 0
-                       and not self._chunk_jobs):
-                    idled = True
-                    self._cv.wait(timeout=0.5)
-                if self._stopped:
+            self._round += 1
+            with host_span(SPAN_ROUND, round=self._round,
+                           active=self._active_count) as span:
+                done = self._run_round()
+                if done is None:
                     return
-                now = time.perf_counter()
-                self._shed_expired_locked(now)
-                if self.qos is not None and len(self._pending) > 1:
-                    self._order_pending_locked(now)
-                pending = []
-                deferred = False
-                suspend_slot = -1
-                free_slots = [s for s in range(self.slots)
-                              if self._slot_req[s] is None]
-                if self._alloc is None:
-                    while free_slots and self._pending:
-                        req = self._pending.popleft()
-                        slot = free_slots.pop(0)
-                        self._mark_admitted(req, slot)
-                        pending.append((req, slot))
-                else:
-                    # Memory-aware admission: a request enters only when
-                    # its WORST-CASE block count fits the pool (so the
-                    # stream can never OOM mid-decode), reserving the
-                    # blocks here so prime_prefix can't race them away.
-                    # The prefix plan runs FIRST: a hit pins its entry
-                    # (reclaim then can't evict it underneath) and
-                    # shrinks the reservation to the non-shared blocks.
-                    # The low-watermark defers admission while other
-                    # work is in flight instead of draining the pool to
-                    # zero headroom. Three QoS/fairness extensions ride
-                    # on top: candidates arrive in fair-share/priority
-                    # order; a memory-blocked head may be BYPASSED by
-                    # up to hol_bypass_limit later candidates that fit
-                    # (defer_rounds aging shields it from starving);
-                    # and when the blocked candidate outranks a live
-                    # stream, that stream is SUSPENDED to the host tier
-                    # instead of the whole queue deferring.
-                    idx = 0
-                    bypassed = 0
-                    while free_slots and idx < len(self._pending):
-                        req = self._pending[idx]
-                        worst = self._alloc.blocks_for(
-                            max(len(req.tokens), 1) + req.want_left)
-                        # TERMINAL size rejections (vs. the silent defer
-                        # memory pressure takes): the request could
-                        # never be served no matter how long it waits —
-                        # either its worst-case block count exceeds the
-                        # whole pool, or its tokens + budget overflow
-                        # the virtual row. PromptTooLong -> HTTP 413.
-                        if (worst > self._alloc.num_blocks
-                                or len(req.tokens) + req.want_left
-                                > self.total_len):
-                            del self._pending[idx]
-                            with self._mlock:
-                                self.prompt_rejected_too_long += 1
-                            self._finish(req, error=PromptTooLong(
-                                f"request needs {worst} KV blocks "
-                                f"({len(req.tokens)} prompt + "
-                                f"{req.want_left} new tokens) but the "
-                                f"pool holds {self._alloc.num_blocks} "
-                                f"blocks / {self.total_len} tokens"))
-                            continue
-                        plan = (self._plan_prefix(req)
-                                if self.prefix_cache is not None else None)
-                        n_shared = (plan[1] // self.kv_block_size
-                                    if plan is not None else 0)
-                        need = worst - n_shared
-                        fits = True
-                        # A parked stream longer than the compiled
-                        # prompt shape can only resume through its
-                        # exported prefix — without a plan it waits for
-                        # the promote to find memory, never cold-
-                        # prefills a truncated sequence.
-                        # (Chunked prefill lifts the cold ceiling: any
-                        # in-row-bounds sequence can re-prefill as a
-                        # chain of chunks, plan or no plan.)
-                        resumable = (plan is not None
-                                     or self.prefill_chunk_tokens > 0
-                                     or len(req.tokens) <= self.prefill_len)
-                        with self._prefix_lock:
-                            self._reclaim_blocks(need, req.timeline)
-                            headroom = self._alloc.free_blocks - need
-                            busy = self._active_count > 0 or pending
-                            if (not resumable
-                                    or headroom < (self.kv_low_watermark
-                                                   if busy else 0)):
-                                fits = False
-                                if plan is not None:
-                                    self.prefix_cache.release(plan[0])
-                                if not deferred:
-                                    deferred = True
-                                    suspend_slot = \
-                                        self._pick_suspend_victim_locked(
-                                            req, need)
-                            else:
-                                own = self._alloc.alloc(need)
-                                shared = (list(plan[0].blocks[:n_shared])
-                                          if plan is not None else [])
-                                for b in shared:
-                                    self._alloc.share(b)
-                                self.kv_blocks_peak = max(
-                                    self.kv_blocks_peak,
-                                    self._alloc.blocks_in_use)
-                        if fits:
-                            req.admit_plan = plan
-                            req.defer_rounds = 0
-                            slot = free_slots.pop(0)
-                            self._slot_blocks[slot] = shared + own
-                            # The TABLE row stays sentinel until this
-                            # request's own admission dispatch uploads
-                            # it (_admit_prefix/_admit_batch). Pointing
-                            # it at the blocks now would arm a
-                            # stale-row write: an earlier admission's
-                            # fused decode step in the SAME round still
-                            # sees this slot's old device length, and
-                            # its unconditional K/V scatter would land
-                            # junk inside these blocks — including
-                            # refcount-SHARED prefix blocks other
-                            # streams read.
-                            del self._pending[idx]
-                            if bypassed:
-                                with self._mlock:
-                                    self.hol_bypasses += 1
-                            self._mark_admitted(req, slot)
-                            pending.append((req, slot))
-                            continue
-                        # Blocked: note the deferral, but keep scanning
-                        # for a smaller candidate that fits — unless
-                        # this head has aged past the bypass shield
-                        # (then nothing younger may jump it again).
-                        req.defer_rounds += 1
-                        if req.timeline is not None:
-                            req.timeline.event(
-                                "deferred", need=need,
-                                free=self._alloc.free_blocks)
-                        if req.defer_rounds >= self.hol_shield_rounds:
-                            break
-                        bypassed += 1
-                        if bypassed > self.hol_bypass_limit:
-                            break
-                        idx += 1
-                if deferred:
+                span.set_metadata(kind=done[0], admitted=done[1])
+
+    def _plan_round(self):
+        """Wait for work, then plan the round under the cv: shed, order,
+        pop, reserve blocks. Returns (popped ``(request, slot)`` pairs,
+        slot of a stream to suspend or -1, whether the loop idled), or
+        None once stopped."""
+        idled = False
+        with self._cv:
+            if (not self._stopped and not self._pending
+                    and self._active_count == 0 and not self._chunk_jobs):
+                idled = True
+                with self._phase("idle", "idle"):
+                    while (not self._stopped and not self._pending
+                           and self._active_count == 0
+                           and not self._chunk_jobs):
+                        self._cv.wait(timeout=0.5)
+        # The wait for the queue lock (submits hold it) is plan's too.
+        with self._phase("plan", "admit"), self._cv:
+            if self._stopped:
+                return None
+            pending, suspend_slot = self._plan_admissions_locked()
+        return pending, suspend_slot, idled
+
+    def _plan_admissions_locked(self):
+        """The admission plan of one round (called under the cv)."""
+        now = time.perf_counter()
+        self._shed_expired_locked(now)
+        if self.qos is not None and len(self._pending) > 1:
+            self._order_pending_locked(now)
+        pending = []
+        deferred = False
+        suspend_slot = -1
+        free_slots = [s for s in range(self.slots)
+                      if self._slot_req[s] is None]
+        if self._alloc is None:
+            while free_slots and self._pending:
+                req = self._pending.popleft()
+                slot = free_slots.pop(0)
+                self._mark_admitted(req, slot)
+                pending.append((req, slot))
+        else:
+            # Memory-aware admission: a request enters only when
+            # its WORST-CASE block count fits the pool (so the
+            # stream can never OOM mid-decode), reserving the
+            # blocks here so prime_prefix can't race them away.
+            # The prefix plan runs FIRST: a hit pins its entry
+            # (reclaim then can't evict it underneath) and
+            # shrinks the reservation to the non-shared blocks.
+            # The low-watermark defers admission while other
+            # work is in flight instead of draining the pool to
+            # zero headroom. Three QoS/fairness extensions ride
+            # on top: candidates arrive in fair-share/priority
+            # order; a memory-blocked head may be BYPASSED by
+            # up to hol_bypass_limit later candidates that fit
+            # (defer_rounds aging shields it from starving);
+            # and when the blocked candidate outranks a live
+            # stream, that stream is SUSPENDED to the host tier
+            # instead of the whole queue deferring.
+            idx = 0
+            bypassed = 0
+            while free_slots and idx < len(self._pending):
+                req = self._pending[idx]
+                worst = self._alloc.blocks_for(
+                    max(len(req.tokens), 1) + req.want_left)
+                # TERMINAL size rejections (vs. the silent defer
+                # memory pressure takes): the request could
+                # never be served no matter how long it waits —
+                # either its worst-case block count exceeds the
+                # whole pool, or its tokens + budget overflow
+                # the virtual row. PromptTooLong -> HTTP 413.
+                if (worst > self._alloc.num_blocks
+                        or len(req.tokens) + req.want_left
+                        > self.total_len):
+                    del self._pending[idx]
                     with self._mlock:
-                        self.kv_defer_admissions += 1
-            if idled:
-                # Coming out of idle: the streak cap must not outlive
-                # the burst that set it — the next admission deserves
-                # its ramp round. Reset OUTSIDE the cv so every
-                # _ramp_streak access stays scheduler-thread-plain
-                # (one site under the cv made the guard inconsistent).
-                self._ramp_streak = 0
-            try:
-                if suspend_slot >= 0:
-                    # Preempt-to-host: the victim was chosen under the
-                    # cv, but the export is a device round-trip submits
-                    # must not wait on — executed here, outside the cv.
-                    # Its freed blocks admit the blocked candidate on
-                    # the next round.
-                    self._suspend_stream(suspend_slot)
-                if pending:
-                    # Admission fuses prefill + insert + one decode step
-                    # into a single dispatch, so a new request's first
-                    # token ships on the admission dispatch itself
-                    # (prompt→token = one dispatch). Whether the round
-                    # ALSO runs its chunk is the TTFT-ramp streak cap:
-                    # normally an admission round ends here (fast first
-                    # token, next round chunks), but under sustained
-                    # arrivals (pending non-empty nearly every round) at
-                    # most one consecutive admission-only round is
-                    # allowed before a fused chunk runs in the same
-                    # round — decode throughput must not degrade toward
-                    # one dispatch per token. (want==0 admissions are
-                    # pure prefills answered in _post_admit.)
-                    #
-                    # With the prefix cache on, each request first probes
-                    # the trie: hits ride suffix-only admissions (one
-                    # dispatch each), misses batch as before.
-                    if self.prefill_chunk_tokens:
-                        # Long admissions (suffix wider than one chunk)
-                        # leave the one-dispatch paths: they register as
-                        # chunk jobs and the pop loop feeds them one
-                        # bounded chunk per round, interleaved with
-                        # decode — a 32k admission no longer stalls
-                        # every live stream for a monolithic prefill.
-                        short = []
-                        for req, slot in pending:
-                            plan = req.admit_plan
-                            plen = plan[1] if plan is not None else 0
-                            if (len(req.tokens) - plen
-                                    > self.prefill_chunk_tokens):
-                                self._begin_chunked(req, slot)
-                            else:
-                                short.append((req, slot))
-                        pending = short
-                    misses = pending
-                    if self.prefix_cache is not None:
-                        hits, misses = [], []
-                        for req, slot in pending:
-                            # Paged admissions planned at pop time (the
-                            # plan gates the block reservation); dense
-                            # ones probe the trie here.
-                            plan = (req.admit_plan
-                                    if self._alloc is not None
-                                    else self._plan_prefix(req))
-                            if plan is None:
-                                with self._mlock:
-                                    self.prefix_misses += 1
-                                misses.append((req, slot))
-                            else:
-                                hits.append((req, slot, plan))
-                        for req, slot, (entry, plen, s) in hits:
-                            self._admit_prefix(req, slot, entry, plen, s)
-                    if misses:
-                        self._admit_batch(misses)
-                    ramp = (any(req.want_left for req, _ in pending)
-                            and (self.chunk_size == 1
-                                 or self._ramp_streak < 1))
-                    if ramp:
-                        self.ramp_rounds += 1
-                        if self.chunk_size > 1:
-                            self._ramp_streak += 1
-                        # A ramp round still owes the oldest chunked
-                        # admission its chunk — TTFT ramping must not
-                        # starve a long prefill chain.
-                        self._advance_chunked()
-                        continue  # this round's step already ran
-                self._advance_chunked()
-                if self._active_count == 0:
+                        self.prompt_rejected_too_long += 1
+                    self._finish(req, error=PromptTooLong(
+                        f"request needs {worst} KV blocks "
+                        f"({len(req.tokens)} prompt + "
+                        f"{req.want_left} new tokens) but the "
+                        f"pool holds {self._alloc.num_blocks} "
+                        f"blocks / {self.total_len} tokens"))
                     continue
-                if self._spec is not None and self._spec_round():
+                plan = (self._plan_prefix(req)
+                        if self.prefix_cache is not None else None)
+                n_shared = (plan[1] // self.kv_block_size
+                            if plan is not None else 0)
+                need = worst - n_shared
+                fits = True
+                # A parked stream longer than the compiled
+                # prompt shape can only resume through its
+                # exported prefix — without a plan it waits for
+                # the promote to find memory, never cold-
+                # prefills a truncated sequence.
+                # (Chunked prefill lifts the cold ceiling: any
+                # in-row-bounds sequence can re-prefill as a
+                # chain of chunks, plan or no plan.)
+                resumable = (plan is not None
+                             or self.prefill_chunk_tokens > 0
+                             or len(req.tokens) <= self.prefill_len)
+                with self._prefix_lock:
+                    self._reclaim_blocks(need, req.timeline)
+                    headroom = self._alloc.free_blocks - need
+                    busy = self._active_count > 0 or pending
+                    if (not resumable
+                            or headroom < (self.kv_low_watermark
+                                           if busy else 0)):
+                        fits = False
+                        if plan is not None:
+                            self.prefix_cache.release(plan[0])
+                        if not deferred:
+                            deferred = True
+                            suspend_slot = \
+                                self._pick_suspend_victim_locked(
+                                    req, need)
+                    else:
+                        own = self._alloc.alloc(need)
+                        shared = (list(plan[0].blocks[:n_shared])
+                                  if plan is not None else [])
+                        for b in shared:
+                            self._alloc.share(b)
+                        self.kv_blocks_peak = max(
+                            self.kv_blocks_peak,
+                            self._alloc.blocks_in_use)
+                if fits:
+                    req.admit_plan = plan
+                    req.defer_rounds = 0
+                    slot = free_slots.pop(0)
+                    self._slot_blocks[slot] = shared + own
+                    # The TABLE row stays sentinel until this
+                    # request's own admission dispatch uploads
+                    # it (_admit_prefix/_admit_batch). Pointing
+                    # it at the blocks now would arm a
+                    # stale-row write: an earlier admission's
+                    # fused decode step in the SAME round still
+                    # sees this slot's old device length, and
+                    # its unconditional K/V scatter would land
+                    # junk inside these blocks — including
+                    # refcount-SHARED prefix blocks other
+                    # streams read.
+                    del self._pending[idx]
+                    if bypassed:
+                        with self._mlock:
+                            self.hol_bypasses += 1
+                    self._mark_admitted(req, slot)
+                    pending.append((req, slot))
                     continue
-                self._h_occupancy.observe(self._active_count)
-                t_disp = time.perf_counter()
+                # Blocked: note the deferral, but keep scanning
+                # for a smaller candidate that fits — unless
+                # this head has aged past the bypass shield
+                # (then nothing younger may jump it again).
+                req.defer_rounds += 1
+                if req.timeline is not None:
+                    req.timeline.event(
+                        "deferred", need=need,
+                        free=self._alloc.free_blocks)
+                if req.defer_rounds >= self.hol_shield_rounds:
+                    break
+                bypassed += 1
+                if bypassed > self.hol_bypass_limit:
+                    break
+                idx += 1
+        if deferred:
+            with self._mlock:
+                self.kv_defer_admissions += 1
+        return pending, suspend_slot
+
+    def _run_round(self):
+        """One pass of the scheduler loop. Returns (the kind of its last
+        dispatch — admit, chunk, verify or decode —, requests admitted),
+        or None once stopped."""
+        planned = self._plan_round()
+        if planned is None:
+            return None
+        pending, suspend_slot, idled = planned
+        if idled:
+            # Coming out of idle: the streak cap must not outlive
+            # the burst that set it — the next admission deserves
+            # its ramp round. Reset OUTSIDE the cv so every
+            # _ramp_streak access stays scheduler-thread-plain
+            # (one site under the cv made the guard inconsistent).
+            self._ramp_streak = 0
+        try:
+            return self._dispatch_round(pending, suspend_slot), len(pending)
+        except Exception as e:
+            # A failed prefill/decode/verify may have invalidated
+            # self._state (the jitted calls donate its buffers), so
+            # the decoder cannot safely take more work. Requests
+            # popped this round but not yet registered in a slot
+            # would be invisible to the loop-exit sweep — fail them
+            # here (returning any pop-time block reservation), then
+            # let _loop's wrapper fail everything else (in-flight
+            # and queued) with the same error.
+            for req, _slot in pending:
+                self._finish(req, error=e)
+                self._free_slot_blocks(_slot)
+            raise
+
+    def _dispatch_round(self, pending, suspend_slot) -> str:
+        """The device half of a round: admissions, one chunk of a long
+        admission, then a verify or decode step. Returns the kind of the
+        last dispatch."""
+        if suspend_slot >= 0:
+            # Preempt-to-host: the victim was chosen under the
+            # cv, but the export is a device round-trip submits
+            # must not wait on — executed here, outside the cv.
+            # Its freed blocks admit the blocked candidate on
+            # the next round.
+            self._suspend_stream(suspend_slot)
+        if pending:
+            # Admission fuses prefill + insert + one decode step
+            # into a single dispatch, so a new request's first
+            # token ships on the admission dispatch itself
+            # (prompt→token = one dispatch). Whether the round
+            # ALSO runs its chunk is the TTFT-ramp streak cap:
+            # normally an admission round ends here (fast first
+            # token, next round chunks), but under sustained
+            # arrivals (pending non-empty nearly every round) at
+            # most one consecutive admission-only round is
+            # allowed before a fused chunk runs in the same
+            # round — decode throughput must not degrade toward
+            # one dispatch per token. (want==0 admissions are
+            # pure prefills answered in _post_admit.)
+            #
+            # With the prefix cache on, each request first probes
+            # the trie: hits ride suffix-only admissions (one
+            # dispatch each), misses batch as before.
+            if self.prefill_chunk_tokens:
+                # Long admissions (suffix wider than one chunk)
+                # leave the one-dispatch paths: they register as
+                # chunk jobs and the pop loop feeds them one
+                # bounded chunk per round, interleaved with
+                # decode — a 32k admission no longer stalls
+                # every live stream for a monolithic prefill.
+                short = []
+                for req, slot in pending:
+                    plan = req.admit_plan
+                    plen = plan[1] if plan is not None else 0
+                    if (len(req.tokens) - plen
+                            > self.prefill_chunk_tokens):
+                        self._begin_chunked(req, slot)
+                    else:
+                        short.append((req, slot))
+                pending = short
+            misses = pending
+            if self.prefix_cache is not None:
+                hits, misses = [], []
+                for req, slot in pending:
+                    # Paged admissions planned at pop time (the
+                    # plan gates the block reservation); dense
+                    # ones probe the trie here.
+                    plan = (req.admit_plan
+                            if self._alloc is not None
+                            else self._plan_prefix(req))
+                    if plan is None:
+                        with self._mlock:
+                            self.prefix_misses += 1
+                        misses.append((req, slot))
+                    else:
+                        hits.append((req, slot, plan))
+                for req, slot, (entry, plen, s) in hits:
+                    self._admit_prefix(req, slot, entry, plen, s)
+            if misses:
+                self._admit_batch(misses)
+            ramp = (any(req.want_left for req, _ in pending)
+                    and (self.chunk_size == 1
+                         or self._ramp_streak < 1))
+            if ramp:
                 if self.chunk_size > 1:
-                    with self._state_lock:
-                        self._state, toks, emitted = decode_chunk(
-                            self._state, self.params, self.cfg,
-                            self.chunk_size, self.top_k, self.eos_id,
-                            self.kv_fused, self._kmesh,
-                        )
-                    with self._mlock:
-                        self.steps += self.chunk_size
-                        self.dispatches += 1
-                    self._ramp_streak = 0
-                    toks, emitted = jax.device_get((toks, emitted))
-                    self._h_dispatch.labels("decode").observe(
-                        time.perf_counter() - t_disp)
-                    for k in range(self.chunk_size):
-                        self._dispatch(toks[k], emitted[k])
+                    self._ramp_streak += 1
+                # A ramp round still owes the oldest chunked
+                # admission its chunk — TTFT ramping must not
+                # starve a long prefill chain.
+                self._advance_chunked()
+                return "admit"  # this round's step already ran
+        chunked = self._advance_chunked()
+        if self._active_count == 0:
+            return "chunk" if chunked else "admit"
+        if self._spec is not None and self._spec_round():
+            return "verify"
+        with self._phase("dispatch", "decode"):
+            self._h_occupancy.observe(self._active_count)
+            t_disp = time.perf_counter()
+            with self._state_lock:
+                if self.chunk_size > 1:
+                    self._state, toks, emitted = decode_chunk(
+                        self._state, self.params, self.cfg,
+                        self.chunk_size, self.top_k, self.eos_id,
+                        self.kv_fused, self._kmesh,
+                    )
                 else:
-                    with self._state_lock:
-                        self._state, toks, emitted = decode_step(
-                            self._state, self.params, self.cfg, self.top_k,
-                            self.eos_id, self.kv_fused, self._kmesh,
-                        )
-                    with self._mlock:
-                        self.steps += 1
-                        self.dispatches += 1
-                    toks, emitted = jax.device_get((toks, emitted))
-                    self._h_dispatch.labels("decode").observe(
-                        time.perf_counter() - t_disp)
-                    self._dispatch(toks, emitted)
-            except Exception as e:
-                # A failed prefill/decode/verify may have invalidated
-                # self._state (the jitted calls donate its buffers), so
-                # the decoder cannot safely take more work. Requests
-                # popped this round but not yet registered in a slot
-                # would be invisible to the loop-exit sweep — fail them
-                # here (returning any pop-time block reservation), then
-                # let _loop's wrapper fail everything else (in-flight
-                # and queued) with the same error.
-                for req, _slot in pending:
-                    self._finish(req, error=e)
-                    self._free_slot_blocks(_slot)
-                raise
+                    self._state, toks, emitted = decode_step(
+                        self._state, self.params, self.cfg,
+                        self.top_k, self.eos_id, self.kv_fused,
+                        self._kmesh,
+                    )
+            with self._mlock:
+                self.steps += self.chunk_size
+                self.dispatches += 1
+        with self._phase("fetch", "decode"):
+            toks, emitted = jax.device_get((toks, emitted))
+            self._h_dispatch.labels("decode").observe(
+                time.perf_counter() - t_disp)
+        with self._phase("route", "decode"):
+            if self.chunk_size > 1:
+                self._ramp_streak = 0
+                for k in range(self.chunk_size):
+                    self._dispatch(toks[k], emitted[k])
+            else:
+                self._dispatch(toks, emitted)
+        return "decode"
 
     # ------------------------------------------------------------------
 
@@ -3115,11 +3221,9 @@ class ContinuousDecoder:
                 "max_prompt_len": self.max_prompt_len,
                 "prefill_chunk_tokens": self.prefill_chunk_tokens,
                 "requests_admitted": self.admitted,
-                "ramp_rounds": self.ramp_rounds,
                 "tokens_emitted": self.tokens_emitted,
                 "ttft_avg_s": (self.ttft_sum / self.ttft_count
                                if self.ttft_count else 0.0),
-                "trace_open": self.trace.open_count,
                 "in_flight": self._active_count,
                 "peak_in_flight": self.peak_in_flight,
                 "queued": queued,

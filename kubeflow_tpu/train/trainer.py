@@ -19,6 +19,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kubeflow_tpu.models.registry import ModelSpec
+from kubeflow_tpu.observability.tracing import SCOPE_OPTIMIZER, scope
 from kubeflow_tpu.parallel.sharding import tree_shardings
 from kubeflow_tpu.train.optimizers import OptimizerConfig, build as build_opt
 
@@ -104,6 +105,7 @@ def build_train_step(model: ModelSpec, opt_cfg: OptimizerConfig,
         )
         return loss, dict(metrics), grads
 
+    @scope(SCOPE_OPTIMIZER)
     def apply_update(state, metrics, grads):
         updates, opt_state = opt.update(grads, state.opt_state, state.params)
         params = optax.apply_updates(state.params, updates)

@@ -320,32 +320,6 @@ def test_chunked_eos_parks_on_device(model):
         d.stop()
 
 
-def test_sustained_arrivals_keep_chunking_engaged(model):
-    """Under sustained arrivals (pending non-empty nearly every round) the
-    TTFT ramp must not degrade chunked dispatch back to one dispatch per
-    token (ADVICE r4): un-fused ramp rounds are never consecutive, so
-    u <= c + 1 where u/c are un-fused/chunked dispatch counts."""
-    spec, params = model
-    K = 4
-    d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
-                          max_new_tokens=8, chunk_size=K)
-    try:
-        long_req = d.submit([1, 2, 3], 8)
-        it = long_req.tokens(timeout=60)
-        next(it)  # long request admitted and past its ramp round
-        shorts = [d.submit([5 + i], 1) for i in range(6)]
-        for h in shorts:
-            assert len(h.result(timeout=60)["tokens"]) == 1
-        assert len(long_req.result(timeout=60)["tokens"]) == 8
-        # Ramp steps ride the admission dispatch; the streak cap bounds
-        # admission-ONLY rounds (no chunk) so chunking stays engaged:
-        # never two in a row => ramp_rounds <= chunk dispatches + 1.
-        m = d.metrics()
-        assert m["ramp_rounds"] <= m["decode_dispatches"] + 1, m
-    finally:
-        d.stop()
-
-
 def test_batched_admission_parity_and_dispatch_count(model):
     """A burst admitted together (one prefill + one insert dispatch)
     produces exactly the tokens sequential admission produces, and the

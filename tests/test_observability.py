@@ -308,7 +308,7 @@ def test_decoder_metrics_expose_histogram_quantiles(model):
                     "queue_wait_p50_s", "queue_wait_p99_s"):
             assert key in m
         assert 0 < m["ttft_p50_s"] <= m["ttft_p99_s"]
-        assert m["trace_open"] == 0
+        assert d.trace.open_count == 0
         text = d.registry.render()
         assert lint(text) == []
         assert type_line("serving_ttft_seconds", "histogram") in text
