@@ -20,6 +20,7 @@ query flash attention, SwiGLU MLP — written TPU-first:
 from __future__ import annotations
 
 import functools
+import re
 from dataclasses import dataclass, replace
 
 import jax
@@ -48,7 +49,7 @@ from kubeflow_tpu.parallel.mesh import (
     AXIS_TENSOR,
 )
 from kubeflow_tpu.parallel.ring_attention import ring_attention
-from kubeflow_tpu.parallel.sharding import PartitionRule
+from kubeflow_tpu.parallel.sharding import PartitionRule, path_str
 
 
 @dataclass(frozen=True)
@@ -187,7 +188,9 @@ def config(name: str, **overrides) -> TransformerConfig:
 
 
 def init(key, cfg: TransformerConfig):
-    """Parameter pytree; weights float32 (cast to cfg.dtype at apply time)."""
+    """Parameter pytree; weights float32. Training keeps them so (masters,
+    cast to cfg.dtype inside the step by :func:`cast_param`); serving casts
+    once, when a tree is installed (:func:`serving_params`)."""
     d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
     # NOTE: split count must stay 8 — changing it would silently reshuffle
@@ -290,6 +293,35 @@ def cast_param(w, dtype):
     ``cast_weights`` scope wherever XLA hoists or fuses it."""
     with scope(SCOPE_CAST_WEIGHTS):
         return w.astype(dtype)
+
+
+# The leaves cast_param is applied to at cfg.dtype. Norm gains are used
+# in float32 by rms_norm and the MoE router is cast to float32: not here.
+_COMPUTE_DTYPE_LEAF = re.compile(
+    r"^(embed/kernel|lm_head/kernel|layers/attn/w[qkvo]"
+    r"|layers/mlp/(gate|up|down))$")
+
+
+def serving_params(params, cfg: TransformerConfig):
+    """``params`` as a serving replica holds them: every leaf the forward
+    casts to ``cfg.dtype`` is at ``cfg.dtype`` already, so no dispatch
+    casts a weight again (cast_param on such a leaf emits no op). Same
+    rounding as inside the step, so tokens and logits are bit for bit
+    what the float32 tree gives. A leaf already at its dtype comes back
+    as it is. Cast leaf by leaf, each float32 leaf let go as soon as its
+    copy exists: a caller that hands over its only reference (the
+    argument built in the call) pays one leaf of transient, not a second
+    tree."""
+    dtype = jnp.dtype(cfg.dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    del params
+    paths = [path_str(kp) for kp, _ in flat]
+    leaves = [leaf for _, leaf in flat]
+    del flat
+    for i, path in enumerate(paths):
+        if _COMPUTE_DTYPE_LEAF.match(path) and leaves[i].dtype != dtype:
+            leaves[i] = jax.block_until_ready(cast_param(leaves[i], dtype))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def _constrain(x, mesh, spec):

@@ -91,6 +91,7 @@ from kubeflow_tpu.models.decode import (
     store_prefix_row,
     verify_chunk,
 )
+from kubeflow_tpu.models.transformer import serving_params
 from kubeflow_tpu.observability.metrics import MetricRegistry
 from kubeflow_tpu.observability.tracing import (
     PHASE_COUNTER,
@@ -272,6 +273,12 @@ class StreamHandle:
         return self._req.ttft_s
 
 
+def _weights_footprint(params) -> tuple[int, str]:
+    """(bytes of every leaf of a serving tree, dtype of its matrices)."""
+    nbytes = sum(int(leaf.nbytes) for leaf in jax.tree.leaves(params))
+    return nbytes, str(params["embed"]["kernel"].dtype)
+
+
 class ContinuousDecoder:
     """Owns the device decode state and the scheduler thread.
 
@@ -369,6 +376,11 @@ class ContinuousDecoder:
             stage_layer_ranges(cfg.n_layers, self.pp_stages)
             cfg = dataclasses.replace(cfg,
                                       pipeline_stages=self.pp_stages)
+        # The one cast of every weight the forward uses at cfg.dtype: a
+        # no-op behind the engine (its tree is a serving tree already),
+        # the cast for a caller that hands over float32. No dispatch
+        # converts a weight after this.
+        params = serving_params(params, cfg)
         if self.tp_shards > 1 or self.cp_shards > 1 or self.pp_stages > 1:
             from kubeflow_tpu.models.transformer import partition_rules
             from kubeflow_tpu.parallel.mesh import serving_mesh
@@ -769,6 +781,13 @@ class ContinuousDecoder:
             "Weights epoch installed by live pushes (0 = boot weights)")
         if self.weights_version:
             self._g_weights_version.set(self.weights_version)
+        # What the installed tree holds on the device: the witness that
+        # serving keeps one copy at the compute dtype (a float32 tree
+        # would read twice the bytes). Set here and at every swap.
+        self._g_weights_bytes = self.registry.gauge(
+            "serving_weights_bytes",
+            "Bytes of the installed serving parameter tree")
+        self._g_weights_bytes.set(_weights_footprint(self.params)[0])
         self._c_weight_pushes = self.registry.counter(
             "serving_weight_pushes_total",
             "Live weight swaps installed by update_weights")
@@ -2355,8 +2374,11 @@ class ContinuousDecoder:
         if version is not None and int(version) <= cur_version:
             return cur_version
         # Shape/dtype contract against the serving tree (tree.map
-        # raises on a structure mismatch); dtype casts on host so a
-        # f32 learner can push into a bf16 server.
+        # raises on a structure mismatch). A pushed leaf lands at the
+        # dtype the serving tree holds it in, so a float32 learner's
+        # push installs bf16 matrices: a host array is cast on the host
+        # (no float32 copy reaches the device), a device array on the
+        # device (the float32 one stays the caller's).
         def _fit(n, o):
             if tuple(getattr(n, "shape", ())) != tuple(o.shape):
                 raise ValueError(
@@ -2404,6 +2426,7 @@ class ContinuousDecoder:
         trie_flushed, tier_flushed = self._flush_stale_kv(new_version)
         total_s = time.perf_counter() - t0
         self._g_weights_version.set(new_version)
+        self._g_weights_bytes.set(_weights_footprint(new_params)[0])
         self._c_weight_pushes.inc()
         self._h_weight_push.observe(total_s)
         with self._mlock:
@@ -3284,6 +3307,8 @@ class ContinuousDecoder:
         # consistent without coupling the lock hierarchies.
         with self._state_lock:
             snap["weights_version"] = self.weights_version
+            snap["weights_bytes"], snap["weights_dtype"] = \
+                _weights_footprint(self.params)
         # Allocator / trie stats live under the prefix lock — taken in a
         # SEPARATE scope (never nested with the metrics lock) so the two
         # subsystems can't deadlock against each other.

@@ -305,8 +305,9 @@ class InferenceEngine:
         always comes up."""
         from kubeflow_tpu.serving import weights as weights_mod
 
-        reference = self.model.init(jax.random.PRNGKey(0),
-                                    self.model.config)
+        # Only the tree's structure is needed: no second set of weights.
+        reference = jax.eval_shape(lambda: self.model.init(
+            jax.random.PRNGKey(0), self.model.config))
         for donor in [p.strip() for p in self.cfg.weight_peers.split(",")
                       if p.strip()]:
             try:
@@ -342,29 +343,46 @@ class InferenceEngine:
         return jax.device_put(jax.tree.map(np.asarray, params))
 
     def _load_params(self):
+        """The tree this replica holds for the life of the process. The
+        transformer family holds it at the compute dtype
+        (``transformer.serving_params``): a float32 init or checkpoint
+        is cast here, once, before the tree moves through the host (half
+        the bytes), and no float32 copy is kept; a donor's ``:pull``
+        already carries the held dtype. Other families' trees pass as
+        they are."""
+        cfg = self.model.config
+        if self.model.family == "transformer":
+            from kubeflow_tpu.models.transformer import (
+                serving_params as held,
+            )
+        else:
+            def held(params, cfg):
+                return params
         if self.cfg.weight_peers:
             params = self._pull_params_from_peers()
             if params is not None:
-                return self._normalize_placement(params)
-        params = self.model.init(jax.random.PRNGKey(0), self.model.config)
-        if self.cfg.checkpoint_dir:
-            from kubeflow_tpu.train import checkpoint as ckpt_lib
-            from kubeflow_tpu.train.optimizers import OptimizerConfig
-            from kubeflow_tpu.train.trainer import init_state
+                return self._normalize_placement(held(params, cfg))
+        if not self.cfg.checkpoint_dir:
+            # Built in the call: the helper is the float32 tree's only
+            # holder and lets each leaf go as its copy exists.
+            return self._normalize_placement(
+                held(self.model.init(jax.random.PRNGKey(0), cfg), cfg))
+        from kubeflow_tpu.train import checkpoint as ckpt_lib
+        from kubeflow_tpu.train.optimizers import OptimizerConfig
+        from kubeflow_tpu.train.trainer import init_state
 
-            state = init_state(
-                jax.random.PRNGKey(0), self.model, OptimizerConfig()
+        state = init_state(
+            jax.random.PRNGKey(0), self.model, OptimizerConfig()
+        )
+        abstract = jax.eval_shape(lambda: state)
+        restored = ckpt_lib.restore_latest(self.cfg.checkpoint_dir,
+                                           abstract)
+        if restored is None:
+            raise FileNotFoundError(
+                f"no checkpoint under {self.cfg.checkpoint_dir}"
             )
-            abstract = jax.eval_shape(lambda: state)
-            restored = ckpt_lib.restore_latest(self.cfg.checkpoint_dir,
-                                               abstract)
-            if restored is None:
-                raise FileNotFoundError(
-                    f"no checkpoint under {self.cfg.checkpoint_dir}"
-                )
-            params = restored[0].params
-            self.weight_pull_source = "checkpoint"
-        return self._normalize_placement(params)
+        self.weight_pull_source = "checkpoint"
+        return self._normalize_placement(held(restored[0].params, cfg))
 
     # ------------------------------------------------------------------
 

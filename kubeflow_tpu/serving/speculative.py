@@ -32,6 +32,7 @@ import numpy as np
 
 from kubeflow_tpu.models.decode import extend_and_propose, init_decode_state
 from kubeflow_tpu.models.registry import get_model
+from kubeflow_tpu.models.transformer import serving_params
 from kubeflow_tpu.serving.engine import pow2_bucket
 
 
@@ -105,7 +106,8 @@ class DraftModelProposer:
                 f"{spec.config.vocab_size} != target vocab {target_vocab}"
             )
         self.cfg = spec.config
-        self.params = spec.init(jax.random.PRNGKey(seed), self.cfg)
+        self.params = serving_params(
+            spec.init(jax.random.PRNGKey(seed), self.cfg), self.cfg)
         self.slots = slots
         self.total_len = total_len
         self.propose_steps = max(1, int(propose_steps))

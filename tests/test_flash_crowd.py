@@ -44,6 +44,7 @@ import jax
 
 from kubeflow_tpu.gateway.resilience import UpstreamHealth
 from kubeflow_tpu.models.registry import get_model
+from kubeflow_tpu.models.transformer import serving_params
 from kubeflow_tpu.serving import weights as weights_mod
 from kubeflow_tpu.serving.compile_cache import (
     CompileCache,
@@ -61,6 +62,11 @@ P_DONOR = SPEC.init(jax.random.PRNGKey(1), SPEC.config)
 def _flat(params) -> dict:
     return {p: np.asarray(a)
             for p, a in weights_mod.flatten_params(params).items()}
+
+
+def _held(params):
+    """A float32 tree as a replica holds it (cast once, at install)."""
+    return serving_params(params, SPEC.config)
 
 
 def _trees_equal(a, b) -> bool:
@@ -300,8 +306,8 @@ def test_donor_death_mid_pull_falls_back_without_partial_install(
         # checkpoint fallback, no partial epoch.
         assert newborn.weight_pull_source == "peer"
         assert newborn.boot_weights_version == 3
-        assert _trees_equal(newborn.params, P_DONOR)
-        assert not _trees_equal(newborn.params, ckpt_params)
+        assert _trees_equal(newborn.params, _held(P_DONOR))
+        assert not _trees_equal(newborn.params, _held(ckpt_params))
     finally:
         if half_dead is not None:
             half_dead.stop()
@@ -318,7 +324,7 @@ def test_every_donor_dead_falls_back_to_checkpoint_byte_identical(
         weight_pull_timeout_s=5.0, checkpoint_dir=ckpt_dir))
     assert newborn.weight_pull_source == "checkpoint"
     assert newborn.boot_weights_version == 0
-    assert _trees_equal(newborn.params, ckpt_params)
+    assert _trees_equal(newborn.params, _held(ckpt_params))
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,7 @@ import pytest
 from benchmarks.harness import spans
 from kubeflow_tpu.models import decode
 from kubeflow_tpu.models.registry import get_model
+from kubeflow_tpu.models.transformer import serving_params
 from kubeflow_tpu.observability import tracing
 from kubeflow_tpu.observability.lint import lint
 from kubeflow_tpu.serving.continuous import ContinuousDecoder
@@ -68,6 +69,21 @@ def test_admission_carries_prefill_and_its_fused_step_decode(model):
                    for p in paths)
 
 
+def test_a_serving_tree_leaves_no_cast_in_the_decode_step(model):
+    """``weight_cast_share_pct`` reads 0.0 for this reason: on the tree a
+    replica holds, cast_param emits nothing, so no instruction of the step
+    carries the scope. It stays in the train step (below) and in a step
+    lowered on float32 (above)."""
+    spec, params = model
+    state = decode.init_decode_state(spec.config, 2, 24)
+    compiled = decode.decode_step.lower(
+        state, serving_params(params, spec.config), spec.config).compile()
+    assert tracing.SCOPE_CAST_WEIGHTS not in compiled.as_text()
+    assert _scopes(compiled) == MODEL_SCOPES - {
+        tracing.SCOPE_CAST_WEIGHTS} | {
+        tracing.SCOPE_DECODE, tracing.SCOPE_SAMPLE, tracing.SCOPE_HEAD}
+
+
 def test_train_step_carries_its_scopes(model):
     from kubeflow_tpu.parallel.mesh import single_device_mesh
     from kubeflow_tpu.train.optimizers import OptimizerConfig
@@ -79,6 +95,9 @@ def test_train_step_carries_its_scopes(model):
     state = init_state(jax.random.PRNGKey(0), spec, opt, mesh)
     compiled = build_train_step(spec, opt, mesh).lower(
         state, {"tokens": jnp.zeros((2, 17), jnp.int32)}).compile()
+    # Training keeps float32 masters and casts inside the step.
+    assert all(leaf.dtype == jnp.float32
+               for leaf in jax.tree.leaves(state.params))
     assert _scopes(compiled) == MODEL_SCOPES | {
         tracing.SCOPE_HEAD_LOSS, tracing.SCOPE_OPTIMIZER}
     # Backward ops stay in the forward's scope.
