@@ -29,6 +29,8 @@ from kubeflow_tpu.observability.tracing import (
     SCOPE_MLP,
     SCOPE_PREFILL,
     SCOPE_SAMPLE,
+    SCOPE_SPARSE_ATTN,
+    SCOPE_SPARSE_SELECT,
     scope,
 )
 from kubeflow_tpu.ops import rms_norm
@@ -38,11 +40,25 @@ from kubeflow_tpu.ops.attention import (
     ring_span_attention,
 )
 from kubeflow_tpu.ops.rotary import rotary_frequencies
+from kubeflow_tpu.ops.sparse_attention import (
+    attend_selected,
+    compress_keys,
+    compress_last,
+    select_blocks,
+)
 from kubeflow_tpu.models.transformer import (
+    MIXER_LIGHTNING,
+    MIXER_SPARSE,
     TransformerConfig,
     cast_param,
+    final_hidden,
     head_kernel,
+    lightning_span,
+    lightning_token,
+    mixer_out,
+    mixer_qkv,
     moe_ffn,
+    sparse_span,
 )
 
 _NEG_INF = -1e30
@@ -50,6 +66,11 @@ _NEG_INF = -1e30
 
 def init_cache(cfg: TransformerConfig, batch: int, total_len: int):
     """Per-layer K/V cache, stacked on a leading layer dim like the params."""
+    if cfg.mixer_types:
+        raise ValueError(
+            "mixer_types: the dense KV cache (and the lockstep generate "
+            "built on it) holds K and V for every layer and nothing else; "
+            "a model with recurrent state serves on kv_layout='paged'")
     shape = (cfg.n_layers, batch, total_len, cfg.n_kv_heads, cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -604,9 +625,20 @@ def retire_row(state, slot):
     :func:`_state_kv`; ``insert_row``/admission resets ``length`` on
     readmission."""
     total = _state_kv(state)[3]
-    return {**state,
-            "active": state["active"].at[slot].set(False),
-            "length": state["length"].at[slot].set(total)}
+    return _zero_row_state(
+        {**state,
+         "active": state["active"].at[slot].set(False),
+         "length": state["length"].at[slot].set(total)}, slot)
+
+
+def _zero_row_state(state, slot):
+    """Recurrent state has no validity mask to hide behind: a retired
+    row's is zeroed (and an admission at position 0 starts from zero
+    whatever it finds). A state that holds none comes back as it is."""
+    if "lin_state" not in state:
+        return state
+    return {**state, "lin_state": tuple(s.at[slot].set(0.0)
+                                        for s in state["lin_state"])}
 
 
 def _state_kv(state):
@@ -618,8 +650,10 @@ def _state_kv(state):
     if "pool" in state:
         k = state["pool"]["k"]
         table = state["block_table"]
-        return (k, state["pool"]["v"], table,
-                table.shape[1] * _kv_arr(k).shape[2])
+        # A mixer_types state keeps its pool head-major (see
+        # init_paged_state): the block's tokens are one axis further in.
+        bs = k.shape[3] if "lin_state" in state else _kv_arr(k).shape[2]
+        return k, state["pool"]["v"], table, table.shape[1] * bs
     k = state["cache"]["k"]
     return k, state["cache"]["v"], None, k.shape[2]
 
@@ -682,10 +716,16 @@ def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
     tok = sample_token(state["last_logits"], sub, state["temperature"], top_k)
     p_b = state["length"]
     with scope(SCOPE_DECODE):
-        logits, k_new, v_new = _single_token_forward(
-            params, cfg, k0, v0, tok, p_b, emit, table=table, fused=fused,
-            mesh=mesh,
-        )
+        if cfg.mixer_types:
+            logits, held = _hybrid_token_forward(params, cfg, state, tok,
+                                                 p_b, emit)
+            state = {**state, **held}
+            k_new, v_new = state["pool"]["k"], state["pool"]["v"]
+        else:
+            logits, k_new, v_new = _single_token_forward(
+                params, cfg, k0, v0, tok, p_b, emit, table=table,
+                fused=fused, mesh=mesh,
+            )
         step_inc = emit.astype(jnp.int32)
         length = p_b + step_inc
         remaining = state["remaining"] - step_inc
@@ -1123,6 +1163,9 @@ def init_paged_state(cfg: TransformerConfig, slots: int, num_blocks: int,
     share/refcount/CoW bookkeeping covers payload and scales in one
     move, and resident K/V costs ~``head_dim + 4`` bytes per head
     instead of ``head_dim * fp_bytes``."""
+    if cfg.mixer_types:
+        return _init_hybrid_state(cfg, slots, num_blocks, block_size,
+                                  max_blocks_per_seq, seed, kv_dtype)
     shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
     if kv_dtype == "int8":
@@ -1148,6 +1191,236 @@ def init_paged_state(cfg: TransformerConfig, slots: int, num_blocks: int,
     }
 
 
+# ---------------------------------------------------------------------------
+# Hybrid decoders (cfg.mixer_types): recurrent state and compressed keys
+# beside a K/V pool that only the sparse layers have
+# ---------------------------------------------------------------------------
+#
+# Paged layout only. The state holds, beside the scalars every state has:
+#   pool       {"k", "v"}: [La, N, Hkv, Bs, hd] for the La sparse layers,
+#              HEAD-MAJOR inside a block (the other models' pools are
+#              [L, N, Bs, Hkv, hd]): the selection is per KV head, so a
+#              (block, head) tile is what a read gathers, and here it is
+#              contiguous. Bs is the selection's block size.
+#   lin_state  one [slots, H, hd, hd] float32 array per lightning layer
+#   ckeys      one [slots, Hkv, W, hd] array per sparse layer: the mean key
+#              of every window of kernel tokens, stride apart (the
+#              selection's index). Window j is written when the row's
+#              token stride*j + kernel - 1 is, and read by position, so a
+#              reused slot needs no clearing.
+# The layer loop is unrolled (the kinds' trees differ); every pool access
+# names its layer in the index, so each is one gather or scatter on the
+# whole donated array and no layer is ever sliced out and put back.
+
+
+def _init_hybrid_state(cfg: TransformerConfig, slots: int, num_blocks: int,
+                       block_size: int, max_blocks_per_seq: int, seed: int,
+                       kv_dtype: str):
+    spec = cfg.sparse_spec
+    if kv_dtype not in ("", "fp"):
+        raise ValueError(
+            f"mixer_types: int8 KV (kv_dtype {kv_dtype!r}) is not "
+            "supported: the block selection reads keys at the model dtype")
+    if block_size != spec.block:
+        raise ValueError(
+            f"mixer_types: kv_block_size {block_size} must equal the "
+            f"sparse layers' block size {spec.block} (a selected block is "
+            "a pool block)")
+    n_sparse = len(cfg.layers_of(MIXER_SPARSE))
+    n_lightning = len(cfg.layers_of(MIXER_LIGHTNING))
+    hd = cfg.head_dim
+    shape = (n_sparse, num_blocks, cfg.n_kv_heads, block_size, hd)
+    windows = spec.n_windows(max_blocks_per_seq * block_size)
+    return {
+        "pool": {"k": jnp.zeros(shape, cfg.dtype),
+                 "v": jnp.zeros(shape, cfg.dtype)},
+        "lin_state": tuple(
+            jnp.zeros((slots, cfg.n_heads, hd, hd), jnp.float32)
+            for _ in range(n_lightning)),
+        "ckeys": tuple(
+            jnp.zeros((slots, cfg.n_kv_heads, windows, hd), cfg.dtype)
+            for _ in range(n_sparse)),
+        "block_table": jnp.full((slots, max_blocks_per_seq), num_blocks,
+                                jnp.int32),
+        "length": jnp.zeros((slots,), jnp.int32),
+        "remaining": jnp.zeros((slots,), jnp.int32),
+        "active": jnp.zeros((slots,), bool),
+        "temperature": jnp.zeros((slots,), jnp.float32),
+        "last_logits": jnp.zeros((slots, cfg.vocab_size), jnp.float32),
+        "key": jax.random.PRNGKey(seed),
+    }
+
+
+def _hm_blocks(pool, table, cols):
+    """Physical block of each virtual position ``cols`` [B, S] through
+    ``table`` [B, MB]; out-of-row positions and sentinel entries resolve
+    past the pool (a scatter there is dropped, a gather clamps)."""
+    n, bs = pool.shape[1], pool.shape[3]
+    mb = table.shape[1]
+    blk = jnp.take_along_axis(table, jnp.clip(cols // bs, 0, mb - 1), axis=1)
+    return jnp.where((cols >= 0) & (cols < mb * bs), blk, n)
+
+
+def _hm_write(pool, layer: int, table, cols, vals):
+    """Scatter ``vals`` [B, S, Hkv, hd] at virtual positions ``cols``
+    [B, S] of sparse layer ``layer``."""
+    heads = jnp.arange(pool.shape[2])[None, None, :]
+    return pool.at[layer, _hm_blocks(pool, table, cols)[:, :, None], heads,
+                   (cols % pool.shape[3])[:, :, None]].set(vals)
+
+
+def _hm_read(pool, layer: int, table, cols):
+    """Gather positions ``cols`` [B, S] → [B, Hkv, S, hd]."""
+    heads = jnp.arange(pool.shape[2])[None, :, None]
+    return pool[layer, _hm_blocks(pool, table, cols)[:, None, :], heads,
+                (cols % pool.shape[3])[:, None, :]]
+
+
+def _hm_row(pool, layer: int, table):
+    """A layer's whole virtual rows through ``table`` [B, MB] →
+    [B, Hkv, MB*Bs, hd]."""
+    b, mb = table.shape
+    _, _, h, bs, hd = pool.shape
+    return pool[layer, table].transpose(0, 2, 1, 3, 4).reshape(
+        b, h, mb * bs, hd)
+
+
+@scope(SCOPE_ATTN)
+def _sparse_token(x, mixer, cfg: TransformerConfig, pool_k, pool_v,
+                  layer: int, table, ckeys, pos_b, live):
+    """A sparse layer for one token a row at positions ``pos_b`` [B]:
+    write its K/V, complete the compressed key its position completes,
+    select, gather the selected blocks, attend. Returns (out [B, 1, D],
+    pool_k, pool_v, ckeys)."""
+    spec = cfg.sparse_spec
+    b = x.shape[0]
+    hkv, group = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+    q, k, v = mixer_qkv(x, mixer, cfg, hkv)
+    pool_k = _hm_write(pool_k, layer, table, pos_b[:, None], k)
+    pool_v = _hm_write(pool_v, layer, table, pos_b[:, None], v)
+    qg = q[:, 0].reshape(b, hkv, group, cfg.head_dim)
+    with scope(SCOPE_SPARSE_SELECT):
+        n = pos_b + 1
+        done = live & (n >= spec.kernel) & (
+            (n - spec.kernel) % spec.stride == 0)
+        window = jnp.where(done, (n - spec.kernel) // spec.stride,
+                           ckeys.shape[2])  # past the end: dropped
+        tail = pos_b[:, None] - (spec.kernel - 1) + jnp.arange(spec.kernel)
+        newest = compress_last(_hm_read(pool_k, layer, table, tail))
+        ckeys = ckeys.at[jnp.arange(b), :, window].set(newest)
+        idx, ok = select_blocks(qg[:, :, :, None], ckeys, pos_b[:, None],
+                                table.shape[1], spec)
+        idx, ok = idx[:, :, 0], ok[:, :, 0]
+    with scope(SCOPE_SPARSE_ATTN):
+        phys = jnp.take_along_axis(
+            jnp.broadcast_to(table[:, None, :], (b, hkv, table.shape[1])),
+            idx, axis=2)
+        heads = jnp.arange(hkv)[None, :, None]
+        o = attend_selected(qg, pool_k[layer, phys, heads],
+                            pool_v[layer, phys, heads], idx, ok, pos_b, spec)
+    return mixer_out(o.reshape(b, 1, -1), x, mixer, cfg), pool_k, pool_v, ckeys
+
+
+def _hybrid_token_forward(params, cfg: TransformerConfig, state, tok, pos_b,
+                          live):
+    """One [B, 1] forward of a ``mixer_types`` model at per-row positions
+    ``pos_b`` against the whole state. Rows that are not ``live`` (parked,
+    or mid-way through a chunked admission) change nothing they hold.
+    Returns (logits [B, V], the state entries it refreshed)."""
+    table = state["block_table"]
+    pool_k, pool_v = state["pool"]["k"], state["pool"]["v"]
+    total = table.shape[1] * pool_k.shape[3]
+    rope = rotary_frequencies(cfg.head_dim, total, theta=cfg.rope_theta)
+    positions = pos_b[:, None]
+    lin, ckeys = list(state["lin_state"]), list(state["ckeys"])
+    x = _embed(params, tok, cfg)[:, None] * cfg.embed_scale
+    li = si = 0
+    for layer, kind in zip(params["layers"], cfg.mixer_types):
+        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
+        if kind == MIXER_LIGHTNING:
+            mixed, lin[li] = lightning_token(h, layer["mixer"], cfg, rope,
+                                             positions, lin[li], live)
+            li += 1
+        else:
+            mixed, pool_k, pool_v, ckeys[si] = _sparse_token(
+                h, layer["mixer"], cfg, pool_k, pool_v, si, table,
+                ckeys[si], pos_b, live)
+            si += 1
+        x = x + cfg.residual_scale * mixed
+        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
+        x = x + cfg.residual_scale * _ffn(h, layer["mlp"], cfg, None)
+    logits = _head(params, final_hidden(x, params, cfg), cfg)[:, 0]
+    return logits, {"pool": {"k": pool_k, "v": pool_v},
+                    "lin_state": tuple(lin), "ckeys": tuple(ckeys)}
+
+
+def _hybrid_block_forward(params, cfg: TransformerConfig, state, slots,
+                          tokens, pos_b, n_tok):
+    """[B, S] forward of rows ``slots`` [B] whose tokens sit at positions
+    ``pos_b[b]..``, the first ``n_tok[b]`` of them real: every admission
+    of a ``mixer_types`` model, whole prompts (``pos_b`` 0) and the chunks
+    of a long one alike. A row at position 0 starts from a zero recurrent
+    state whatever its slot held; a later chunk carries on from what the
+    chunk before it left. The head runs on each row's last real position
+    only (and not at all where the caller drops the logits: an interior
+    chunk's module holds no head). Returns (logits [B, V], the state
+    entries it refreshed)."""
+    spec = cfg.sparse_spec
+    table = state["block_table"][slots]
+    pool_k, pool_v = state["pool"]["k"], state["pool"]["v"]
+    total = table.shape[1] * pool_k.shape[3]
+    _b, s = tokens.shape
+    rope = rotary_frequencies(cfg.head_dim, total, theta=cfg.rope_theta)
+    positions = pos_b[:, None] + jnp.arange(s)[None, :]
+    fresh = (pos_b == 0)[:, None, None, None]
+    lin, ckeys = list(state["lin_state"]), list(state["ckeys"])
+    x = _embed(params, tokens, cfg) * cfg.embed_scale
+    li = si = 0
+    for layer, kind in zip(params["layers"], cfg.mixer_types):
+        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
+        if kind == MIXER_LIGHTNING:
+            before = jnp.where(fresh, 0.0, lin[li][slots])
+            mixed, after = lightning_span(h, layer["mixer"], cfg, rope,
+                                          positions, before, n_tok)
+            lin[li] = lin[li].at[slots].set(after)
+            li += 1
+        else:
+            with scope(SCOPE_ATTN):
+                q, k, v = mixer_qkv(h, layer["mixer"], cfg, cfg.n_kv_heads)
+                pool_k = _hm_write(pool_k, si, table, positions, k)
+                pool_v = _hm_write(pool_v, si, table, positions, v)
+                k_row = _hm_row(pool_k, si, table)
+                with scope(SCOPE_SPARSE_SELECT):
+                    windows = compress_keys(k_row, spec)
+                    ckeys[si] = ckeys[si].at[slots].set(windows)
+                mixed = sparse_span(q, positions, k_row,
+                                    _hm_row(pool_v, si, table), windows, h,
+                                    layer["mixer"], cfg)
+            si += 1
+        x = x + cfg.residual_scale * mixed
+        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
+        x = x + cfg.residual_scale * _ffn(h, layer["mlp"], cfg, None)
+    last = jnp.take_along_axis(
+        x, (jnp.maximum(n_tok, 1) - 1)[:, None, None], axis=1)
+    return (_head(params, final_hidden(last, params, cfg), cfg)[:, 0],
+            {"pool": {"k": pool_k, "v": pool_v},
+             "lin_state": tuple(lin), "ckeys": tuple(ckeys)})
+
+
+def _admitted(state, held, slots, lengths, remaining, temperature, last):
+    """``state`` with the rows ``slots`` admitted: what their forward
+    refreshed (``held``: the pool, and whatever else a row holds) and the
+    rows' scalars."""
+    return {
+        **state, **held,
+        "length": state["length"].at[slots].set(lengths),
+        "remaining": state["remaining"].at[slots].set(remaining),
+        "active": state["active"].at[slots].set(remaining > 0),
+        "temperature": state["temperature"].at[slots].set(temperature),
+        "last_logits": state["last_logits"].at[slots].set(last),
+    }
+
+
 @scope(SCOPE_PREFILL)
 def _paged_admit_rows_body(state, params, cfg: TransformerConfig, slots,
                            prompt_tokens, prompt_lengths, remaining,
@@ -1157,6 +1430,13 @@ def _paged_admit_rows_body(state, params, cfg: TransformerConfig, slots,
     row's K/V into the pool blocks the host allocated for its slot
     (``state["block_table"][slots]``; sentinel entries drop their
     writes)."""
+    if cfg.mixer_types:
+        prompt_lengths = jnp.maximum(prompt_lengths, 1)
+        last, held = _hybrid_block_forward(
+            params, cfg, state, slots, prompt_tokens,
+            jnp.zeros_like(prompt_lengths), prompt_lengths)
+        return _admitted(state, held, slots, prompt_lengths, remaining,
+                         temperature, last), last
     pool_k, pool_v = state["pool"]["k"], state["pool"]["v"]
     bs = _kv_arr(pool_k).shape[2]
     mb = state["block_table"].shape[1]
@@ -1188,16 +1468,10 @@ def _paged_admit_rows_body(state, params, cfg: TransformerConfig, slots,
                     "scale": pool["scale"].at[:, rows_tbl].set(qd["scale"])}
         return pool.at[:, rows_tbl].set(upd)
 
-    return {
-        **state,
-        "pool": {"k": _scatter(pool_k, upd_k),
-                 "v": _scatter(pool_v, upd_v)},
-        "length": state["length"].at[slots].set(prompt_lengths),
-        "remaining": state["remaining"].at[slots].set(remaining),
-        "active": state["active"].at[slots].set(remaining > 0),
-        "temperature": state["temperature"].at[slots].set(temperature),
-        "last_logits": state["last_logits"].at[slots].set(last),
-    }, last
+    held = {"pool": {"k": _scatter(pool_k, upd_k),
+                     "v": _scatter(pool_v, upd_v)}}
+    return _admitted(state, held, slots, prompt_lengths, remaining,
+                     temperature, last), last
 
 
 @functools.partial(jax.jit,
@@ -1233,6 +1507,16 @@ def _paged_admit_prefix_body(state, params, cfg: TransformerConfig, slot,
     suffix K/V into the slot's owned blocks. ``fused`` block-walks the
     span read too, so a fused deployment never materializes the dense
     row even at admission."""
+    if cfg.mixer_types:
+        # The last chunk of a chunked admission (a prefix hit is refused
+        # at construction): the row's state and compressed keys carry on
+        # from the chunks before it.
+        last, held = _hybrid_block_forward(
+            params, cfg, state, jnp.reshape(slot, (1,)), suffix_tokens,
+            jnp.reshape(prefix_len, (1,)),
+            jnp.reshape(jnp.maximum(prompt_len - prefix_len, 1), (1,)))
+        return _admitted(state, held, slot, prompt_len, remaining,
+                         temperature, last[0]), last
     table_row = state["block_table"][slot][None]  # [1, mb]
     _b, s = suffix_tokens.shape
     suffix_len = jnp.maximum(prompt_len - prefix_len, 1)
@@ -1311,6 +1595,16 @@ def paged_prefill_chunk(state, params, cfg: TransformerConfig, slot, pos,
     admit paths' padded suffixes — the next chunk (or decode) overwrites
     them before any mask admits them. ``ring`` sequence-shards the span
     read (context-parallel chunk prefill)."""
+    if cfg.mixer_types:
+        _last, held = _hybrid_block_forward(
+            params, cfg, state, jnp.reshape(slot, (1,)), chunk_tokens,
+            jnp.reshape(pos, (1,)), jnp.reshape(chunk_len, (1,)))
+        total = _state_kv(state)[3]
+        return {
+            **state, **held,
+            "length": state["length"].at[slot].set(total),
+            "active": state["active"].at[slot].set(False),
+        }
     table_row = state["block_table"][slot][None]  # [1, mb]
     _b, s = chunk_tokens.shape
     _logits, pool_k, pool_v = _block_forward(
@@ -1404,6 +1698,14 @@ def copy_block(pool, dst, src):
         return kv.at[:, dst].set(kv[:, src])
 
     return {"k": _copy(pool["k"]), "v": _copy(pool["v"])}
+
+
+def max_admit_rows(cfg: TransformerConfig) -> int | None:
+    """The most rows one admission dispatch may hold (None: as many as
+    there are). A sparse layer scores a whole virtual row per block of
+    queries, so a batch of such rows is a batch of those score tensors:
+    one row a dispatch."""
+    return 1 if cfg.mixer_types and cfg.layers_of(MIXER_SPARSE) else None
 
 
 # ---------------------------------------------------------------------------

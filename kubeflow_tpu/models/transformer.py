@@ -35,10 +35,23 @@ from kubeflow_tpu.observability.tracing import (
     SCOPE_EMBED,
     SCOPE_HEAD,
     SCOPE_HEAD_LOSS,
+    SCOPE_LINEAR_ATTN,
     SCOPE_MLP,
+    SCOPE_SPARSE_ATTN,
+    SCOPE_SPARSE_SELECT,
     scope,
 )
 from kubeflow_tpu.ops import flash_attention, rms_norm
+from kubeflow_tpu.ops.linear_attention import (
+    lightning_chunked,
+    lightning_slopes,
+    lightning_step,
+)
+from kubeflow_tpu.ops.sparse_attention import (
+    SparseSpec,
+    attend_span,
+    compress_keys,
+)
 from kubeflow_tpu.ops.rotary import apply_rotary, rotary_frequencies
 from kubeflow_tpu.parallel.mesh import (
     AXIS_DATA,
@@ -50,6 +63,11 @@ from kubeflow_tpu.parallel.mesh import (
 )
 from kubeflow_tpu.parallel.ring_attention import ring_attention
 from kubeflow_tpu.parallel.sharding import PartitionRule, path_str
+
+
+MIXER_LIGHTNING = "lightning-attn"
+MIXER_SPARSE = "minicpm4"
+MIXER_KINDS = (MIXER_LIGHTNING, MIXER_SPARSE)
 
 
 @dataclass(frozen=True)
@@ -114,10 +132,68 @@ class TransformerConfig:
     # compile stays O(G) while the dynamic-update-slice stacking cost
     # drops by the group factor. 1 = plain per-layer scan.
     scan_group_size: int = 1
+    # Hybrid decoder: one mixer kind per layer, MIXER_LIGHTNING (decayed
+    # linear attention: a float32 state per row, no K/V) or MIXER_SPARSE
+    # (softmax attention without rotary over selected blocks of its K/V,
+    # by a score over compressed keys). Empty = every layer the rotary
+    # GQA attention above, stacked and scanned; set, the layers are a
+    # list of per-layer trees (the two kinds' shapes differ) and the loop
+    # over them is unrolled. Both kinds norm q and k per head and gate
+    # their output; ``rope_theta`` is the lightning layers'.
+    mixer_types: tuple[str, ...] = ()
+    # muP: the embedding times ``embed_scale``, each residual branch times
+    # ``residual_scale`` (scale_depth / sqrt(published depth): it does not
+    # follow a depth cut), the final hidden times ``head_scale`` before
+    # the head (dim_model_base / d_model).
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    head_scale: float = 1.0
+    # The sparse layers' selection (ops/sparse_attention.py:SparseSpec):
+    # compressed-key window and stride, block, blocks read per query,
+    # blocks always read from the start, local window, and the context
+    # length up to which a query reads everything.
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    # Tokens per chunk of the lightning layers' prefill form.
+    lightning_chunk: int = 128
+
+    def __post_init__(self):
+        if not self.mixer_types:
+            return
+        if len(self.mixer_types) != self.n_layers:
+            raise ValueError(
+                f"mixer_types names {len(self.mixer_types)} layers, "
+                f"n_layers is {self.n_layers}")
+        unknown = sorted(set(self.mixer_types) - set(MIXER_KINDS))
+        if unknown:
+            raise ValueError(f"unknown mixer kind(s) {unknown}; "
+                             f"known: {list(MIXER_KINDS)}")
+        if self.n_experts or self.context_parallel or self.pipeline_stages:
+            raise ValueError("mixer_types composes with the dense FFN on "
+                             "one chip: no MoE, context_parallel or "
+                             "pipeline_stages")
+        self.sparse_spec  # validates the selection's sizes
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def sparse_spec(self) -> SparseSpec:
+        return SparseSpec(
+            kernel=self.sparse_kernel_size, stride=self.sparse_kernel_stride,
+            block=self.sparse_block_size, topk=self.sparse_topk,
+            init_blocks=self.sparse_init_blocks, window=self.sparse_window,
+            dense_len=self.sparse_dense_len)
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        """Indices of the layers whose mixer is ``kind``."""
+        return tuple(i for i, m in enumerate(self.mixer_types) if m == kind)
 
 
 # Named presets; sizes per the public Llama-3/TinyLlama shapes.
@@ -175,6 +251,35 @@ PRESETS: dict[str, TransformerConfig] = {
         d_ff=128, max_seq_len=128, remat=False, n_experts=4,
         expert_top_k=2,
     ),
+    # MiniCPM-SALA as published (openbmb/MiniCPM-SALA config.json): 24
+    # lightning and 8 sparse layers, the sparse ones where its mixer_types
+    # has them; muP scale_emb 12, scale_depth 1.4, dim_model_base 256.
+    # Serving only, on the paged layout (docs/serving.md says what refuses
+    # it); at bf16 its 9 B parameters want more than one 16 GB chip.
+    "minicpm-sala-9b": TransformerConfig(
+        vocab_size=73_448, d_model=4096, n_layers=32, n_heads=32,
+        n_kv_heads=2, d_ff=16_384, max_seq_len=524_288, rope_theta=10_000.0,
+        norm_eps=1e-6, remat=False,
+        mixer_types=tuple(
+            MIXER_SPARSE if i in (0, 9, 16, 17, 22, 29, 30, 31)
+            else MIXER_LIGHTNING for i in range(32)),
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+        head_scale=256 / 4096,
+    ),
+    # The same two mixers at toy widths, every selection size shrunk with
+    # them (block 8, windows of 4 by 2, 6 blocks a query, local window 16,
+    # dense to 32 tokens): what the CPU tests serve.
+    "sala-test-tiny": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq_len=256, rope_theta=10_000.0, norm_eps=1e-6,
+        remat=False,
+        mixer_types=(MIXER_SPARSE, MIXER_LIGHTNING, MIXER_LIGHTNING,
+                     MIXER_LIGHTNING) * 2,
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5, head_scale=0.25,
+        sparse_kernel_size=4, sparse_kernel_stride=2, sparse_block_size=8,
+        sparse_topk=6, sparse_init_blocks=1, sparse_window=16,
+        sparse_dense_len=32, lightning_chunk=8,
+    ),
 }
 
 
@@ -190,7 +295,12 @@ def config(name: str, **overrides) -> TransformerConfig:
 def init(key, cfg: TransformerConfig):
     """Parameter pytree; weights float32. Training keeps them so (masters,
     cast to cfg.dtype inside the step by :func:`cast_param`); serving casts
-    once, when a tree is installed (:func:`serving_params`)."""
+    once, when a tree is installed (:func:`serving_params`). A
+    ``mixer_types`` model (served only; its float32 tree would not leave a
+    16 GB chip room) has each matrix at cfg.dtype as it is drawn: the tree
+    :func:`serving_params` would give, one leaf of float32 at a time."""
+    if cfg.mixer_types:
+        return _init_hybrid(key, cfg)
     d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
     # NOTE: split count must stay 8 — changing it would silently reshuffle
@@ -237,6 +347,51 @@ def init(key, cfg: TransformerConfig):
         params["lm_head"] = {
             "kernel": dense(jax.random.fold_in(key, 99), (d, cfg.vocab_size), d)
         }
+    return params
+
+
+def _init_hybrid(key, cfg: TransformerConfig):
+    """The tree of a ``mixer_types`` model: ``layers`` is a list with one
+    tree per layer, ``mixer`` shaped by the layer's kind. Both kinds hold
+    wq, wo and the gate wg at [d, d], a gain per head dim for q and k;
+    lightning k/v are as wide as q and its output has a norm gain, the
+    sparse k/v are ``n_kv_heads`` wide."""
+    d, f, hd = cfg.d_model, cfg.d_ff, cfg.head_dim
+    q_dim = cfg.n_heads * hd
+
+    def dense(k, shape, fan_in=None):
+        w = jax.random.normal(k, shape, jnp.float32) * (
+            fan_in or shape[0]) ** -0.5
+        return w.astype(cfg.dtype)
+
+    layers = []
+    for i, kind in enumerate(cfg.mixer_types):
+        ks = jax.random.split(jax.random.fold_in(key, 1000 + i), 8)
+        kv_dim = q_dim if kind == MIXER_LIGHTNING else cfg.n_kv_heads * hd
+        mixer = {
+            "wq": dense(ks[0], (d, q_dim)), "wk": dense(ks[1], (d, kv_dim)),
+            "wv": dense(ks[2], (d, kv_dim)), "wo": dense(ks[3], (q_dim, d)),
+            "wg": dense(ks[4], (d, q_dim)),
+            "q_norm": jnp.ones((hd,), jnp.float32),
+            "k_norm": jnp.ones((hd,), jnp.float32),
+        }
+        if kind == MIXER_LIGHTNING:
+            mixer["o_norm"] = jnp.ones((q_dim,), jnp.float32)
+        layers.append({
+            "mixer": mixer,
+            "mlp": {"gate": dense(ks[5], (d, f)), "up": dense(ks[6], (d, f)),
+                    "down": dense(ks[7], (f, d))},
+            "ln_attn": jnp.ones((d,), jnp.float32),
+            "ln_mlp": jnp.ones((d,), jnp.float32),
+        })
+    keys = jax.random.split(key, 2)
+    params = {
+        "embed": {"kernel": dense(keys[0], (cfg.vocab_size, d), d)},
+        "layers": layers,
+        "final_norm": jnp.ones((d,), jnp.float32),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"kernel": dense(keys[1], (d, cfg.vocab_size))}
     return params
 
 
@@ -299,7 +454,8 @@ def cast_param(w, dtype):
 # in float32 by rms_norm and the MoE router is cast to float32: not here.
 _COMPUTE_DTYPE_LEAF = re.compile(
     r"^(embed/kernel|lm_head/kernel|layers/attn/w[qkvo]"
-    r"|layers/mlp/(gate|up|down))$")
+    r"|layers/mlp/(gate|up|down)"
+    r"|layers/\d+/mixer/w[qkvog]|layers/\d+/mlp/(gate|up|down))$")
 
 
 def serving_params(params, cfg: TransformerConfig):
@@ -462,6 +618,131 @@ def moe_ffn(x, mlp, cfg: TransformerConfig, token_valid=None):
     return y.reshape(b, t, d).astype(x.dtype), aux
 
 
+# ---------------------------------------------------------------------------
+# Hybrid mixers (cfg.mixer_types)
+# ---------------------------------------------------------------------------
+#
+# Both kinds: q and k normed per head (RMSNorm over head_dim with a gain),
+# the output times sigmoid(x @ wg), then wo. models/decode.py calls the
+# same pieces against its caches; :func:`_hybrid_layers` below is the
+# cache-free forward.
+
+
+def mixer_qkv(x, mixer, cfg: TransformerConfig, n_kv: int):
+    """x [B, S, D] → q [B, S, H, hd], k, v [B, S, n_kv, hd], q and k
+    normed per head, no rotary yet."""
+    b, s, _ = x.shape
+    hd = cfg.head_dim
+    q = (x @ cast_param(mixer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
+    k = (x @ cast_param(mixer["wk"], cfg.dtype)).reshape(b, s, n_kv, hd)
+    v = (x @ cast_param(mixer["wv"], cfg.dtype)).reshape(b, s, n_kv, hd)
+    q = rms_norm(q, mixer["q_norm"], eps=cfg.norm_eps)
+    k = rms_norm(k, mixer["k_norm"], eps=cfg.norm_eps)
+    return q, k, v
+
+
+def mixer_out(o, x, mixer, cfg: TransformerConfig):
+    """Heads' output o [B, S, H*hd] → the mixer's: the lightning kind's
+    norm over the concatenated heads, the gate, wo."""
+    if "o_norm" in mixer:
+        o = rms_norm(o, mixer["o_norm"], eps=cfg.norm_eps)
+    gate = jax.nn.sigmoid(x @ cast_param(mixer["wg"], cfg.dtype))
+    return (o.astype(cfg.dtype) * gate) @ cast_param(mixer["wo"], cfg.dtype)
+
+
+def lightning_qkv(x, mixer, cfg: TransformerConfig, rope, positions):
+    """q, k (rotary at ``positions`` [B, S] over the whole head) and v of a
+    lightning layer, each [B, S, H, hd]."""
+    q, k, v = mixer_qkv(x, mixer, cfg, cfg.n_heads)
+    cos, sin = rope
+    return (apply_rotary(q, cos, sin, positions=positions),
+            apply_rotary(k, cos, sin, positions=positions), v)
+
+
+@scope(SCOPE_ATTN)
+def lightning_span(x, mixer, cfg: TransformerConfig, rope, positions, state,
+                   n_valid):
+    """A lightning layer over a span: x [B, S, D] at ``positions``, the
+    rows' state [B, H, hd, hd] as the span begins, ``n_valid`` [B] real
+    tokens. Returns (out [B, S, D], state after them)."""
+    b, s, _ = x.shape
+    q, k, v = lightning_qkv(x, mixer, cfg, rope, positions)
+    with scope(SCOPE_LINEAR_ATTN):
+        o, state = lightning_chunked(q, k, v, state,
+                                     lightning_slopes(cfg.n_heads), n_valid,
+                                     chunk=cfg.lightning_chunk)
+    return mixer_out(o.reshape(b, s, -1), x, mixer, cfg), state
+
+
+@scope(SCOPE_ATTN)
+def lightning_token(x, mixer, cfg: TransformerConfig, rope, positions, state,
+                    live):
+    """The same layer one token a row: x [B, 1, D]; rows that are not
+    ``live`` [B] keep their state."""
+    b = x.shape[0]
+    q, k, v = lightning_qkv(x, mixer, cfg, rope, positions)
+    with scope(SCOPE_LINEAR_ATTN):
+        o, new = lightning_step(q[:, 0], k[:, 0], v[:, 0], state,
+                                lightning_slopes(cfg.n_heads))
+        state = jnp.where(live[:, None, None, None], new, state)
+    return mixer_out(o.reshape(b, 1, -1), x, mixer, cfg), state
+
+
+def sparse_span(q, positions, k_row, v_row, ckeys, x, mixer,
+                cfg: TransformerConfig):
+    """The sparse layer's read for a span of queries q [B, S, H, hd] over
+    a row's keys and values [B, Hkv, T, hd] and its compressed keys."""
+    b, s, _, _ = q.shape
+    with scope(SCOPE_SPARSE_ATTN):
+        o = attend_span(q, positions, k_row, v_row, ckeys, cfg.sparse_spec)
+    return mixer_out(o.reshape(b, s, -1), x, mixer, cfg)
+
+
+@scope(SCOPE_ATTN)
+def _sparse_uncached(x, mixer, cfg: TransformerConfig):
+    """A sparse layer over whole sequences with no cache: the row is the
+    sequence's own keys, padded to whole blocks."""
+    b, s, _ = x.shape
+    spec = cfg.sparse_spec
+    q, k, v = mixer_qkv(x, mixer, cfg, cfg.n_kv_heads)
+    pad = -s % spec.block
+    k_row, v_row = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))
+                            ).transpose(0, 2, 1, 3) for a in (k, v))
+    with scope(SCOPE_SPARSE_SELECT):
+        ckeys = compress_keys(k_row, spec)
+    positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+    return sparse_span(q, positions, k_row, v_row, ckeys, x, mixer, cfg)
+
+
+def _hybrid_layers(params, x, cfg: TransformerConfig):
+    """The unrolled layer loop of a ``mixer_types`` model on x [B, T, D],
+    whole sequences from position 0."""
+    b, t, _ = x.shape
+    rope = rotary_frequencies(cfg.head_dim, t, theta=cfg.rope_theta)
+    positions = jnp.broadcast_to(jnp.arange(t)[None], (b, t))
+    n_valid = jnp.full((b,), t, jnp.int32)
+    for layer, kind in zip(params["layers"], cfg.mixer_types):
+        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
+        if kind == MIXER_LIGHTNING:
+            state = jnp.zeros((b, cfg.n_heads, cfg.head_dim, cfg.head_dim),
+                              jnp.float32)
+            mixed, _ = lightning_span(h, layer["mixer"], cfg, rope, positions,
+                                      state, n_valid)
+        else:
+            mixed = _sparse_uncached(h, layer["mixer"], cfg)
+        x = x + cfg.residual_scale * mixed
+        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
+        x = x + cfg.residual_scale * _mlp(h, layer["mlp"], cfg)
+    return x
+
+
+def final_hidden(x, params, cfg: TransformerConfig):
+    """The final norm, and a ``mixer_types`` model's scale before the
+    head."""
+    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+    return x * cfg.head_scale if cfg.head_scale != 1.0 else x
+
+
 def _layer_fn(cfg: TransformerConfig, mesh, rope, carry, layer):
     x, aux = carry
     act_spec = batch_partition_spec(cfg) + (None,)
@@ -525,6 +806,10 @@ def hidden_states(params, tokens, cfg: TransformerConfig, *, mesh=None):
     """tokens [B, T] → (final-norm hidden [B, T, D] in cfg.dtype, MoE aux
     loss). The trunk of :func:`apply` without the LM head — the chunked
     training-loss path applies the head inside the loss instead."""
+    if cfg.mixer_types:
+        x = _embed_lookup(params["embed"]["kernel"], tokens, cfg, mesh)
+        x = _hybrid_layers(params, x * cfg.embed_scale, cfg)
+        return final_hidden(x, params, cfg), jnp.zeros((), jnp.float32)
     t = tokens.shape[1]
     rope = rotary_frequencies(cfg.head_dim, t, theta=cfg.rope_theta)
     x = _embed_lookup(params["embed"]["kernel"], tokens, cfg, mesh)

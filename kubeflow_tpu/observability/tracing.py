@@ -54,6 +54,15 @@ SCOPE_OPTIMIZER = "optimizer"
 DEVICE_SCOPES = (SCOPE_PREFILL, SCOPE_DECODE, SCOPE_SAMPLE, SCOPE_EMBED,
                  SCOPE_ATTN, SCOPE_MLP, SCOPE_HEAD, SCOPE_CAST_WEIGHTS,
                  SCOPE_HEAD_LOSS, SCOPE_OPTIMIZER)
+# Kernels of the hybrid mixers (models/transformer.py:mixer_types), set
+# inside ``attn`` under ``decode`` or ``prefill``: the decayed linear
+# attention (state update and read), the block selection over compressed
+# keys (their upkeep included), and the attention over the selected blocks.
+# A tuple of their own: DEVICE_SCOPES is what every model's trace holds.
+SCOPE_LINEAR_ATTN = "linear_attn"
+SCOPE_SPARSE_SELECT = "sparse_select"
+SCOPE_SPARSE_ATTN = "sparse_attn"
+MIXER_SCOPES = (SCOPE_LINEAR_ATTN, SCOPE_SPARSE_SELECT, SCOPE_SPARSE_ATTN)
 
 # Host spans of the scheduler thread (serving/continuous.py:_run): one
 # ``sched.round`` per pass of the loop, its children named by phase.

@@ -80,6 +80,7 @@ from kubeflow_tpu.models.decode import (
     init_decode_state,
     init_paged_state,
     init_prefix_pool,
+    max_admit_rows,
     paged_admit_prefix_and_step,
     paged_admit_rows_and_step,
     paged_prefill_chunk,
@@ -91,7 +92,7 @@ from kubeflow_tpu.models.decode import (
     store_prefix_row,
     verify_chunk,
 )
-from kubeflow_tpu.models.transformer import serving_params
+from kubeflow_tpu.models.transformer import MIXER_SPARSE, serving_params
 from kubeflow_tpu.observability.metrics import MetricRegistry
 from kubeflow_tpu.observability.tracing import (
     PHASE_COUNTER,
@@ -273,6 +274,36 @@ class StreamHandle:
         return self._req.ttft_s
 
 
+def _check_hybrid(**using) -> None:
+    """A ``mixer_types`` model holds recurrent state and compressed keys
+    beside its K/V. Everything named here moves, shares or reads K/V
+    alone and would serve such a model wrongly, so each is refused by
+    name at construction (ROADMAP Queue 2, M4, is what would lift them)."""
+    refused = {
+        "kv_layout": "the dense KV layout (kv_layout='dense')",
+        "prefix_cache_slots": "the prefix cache (prefix_cache_slots): a "
+                              "hit would skip the prefix's recurrent state",
+        "speculative_k": "speculative decoding (speculative_k): "
+                         "verify_step cannot roll recurrent state back",
+        "kv_dtype": "int8 KV (kv_dtype='int8')",
+        "kv_fused": "the fused block-table kernel (kv_fused)",
+        "tp_shards": "tensor parallelism (tp_shards > 1)",
+        "cp_shards": "context parallelism (cp_shards > 1)",
+        "pp_stages": "pipeline parallelism (pp_stages > 1)",
+        "host_kv_bytes": "stream suspension to the host tier "
+                         "(_suspend_stream, host_kv_bytes)",
+        "role": "the prefill/decode handoff (role: export_blocks / "
+                "import_blocks carry K/V only)",
+        "kv_directory": "the fleet KV economy (kv_directory / cold_store: "
+                        "export_blocks carries K/V only)",
+    }
+    for option, on in using.items():
+        if on:
+            raise ValueError(
+                f"mixer_types: {refused[option]} is not supported for a "
+                "model with recurrent state and compressed keys")
+
+
 def _weights_footprint(params) -> tuple[int, str]:
     """(bytes of every leaf of a serving tree, dtype of its matrices)."""
     nbytes = sum(int(leaf.nbytes) for leaf in jax.tree.leaves(params))
@@ -334,6 +365,16 @@ class ContinuousDecoder:
         self.tp_shards = max(1, int(tp_shards))
         self.cp_shards = max(1, int(cp_shards))
         self.pp_stages = max(1, int(pp_stages))
+        if cfg.mixer_types:
+            _check_hybrid(
+                kv_layout=kv_layout != "paged",
+                prefix_cache_slots=prefix_cache_slots > 0,
+                speculative_k=speculative_k > 0, kv_dtype=kv_dtype == "int8",
+                kv_fused=kv_fused, tp_shards=self.tp_shards > 1,
+                cp_shards=self.cp_shards > 1, pp_stages=self.pp_stages > 1,
+                host_kv_bytes=host_kv_bytes > 0, role=bool(role),
+                kv_directory=(kv_directory is not None
+                              or cold_store is not None))
         if self.tp_shards > 1:
             if cfg.n_kv_heads % self.tp_shards:
                 raise ValueError(
@@ -541,10 +582,13 @@ class ContinuousDecoder:
             # Bytes are priced PER CHIP: a tp-sharded pool holds
             # Hkv / tp heads per position on each chip, and the fill
             # gauges must reflect the HBM a chip actually spends.
+            # Only the sparse layers of a mixer_types model hold K/V.
+            kv_layers = (len(cfg.layers_of(MIXER_SPARSE)) if cfg.mixer_types
+                         else cfg.n_layers)
             self._alloc = BlockAllocator(
                 num_blocks, self.kv_block_size,
                 bytes_per_token=kv_bytes_per_token(
-                    cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
+                    kv_layers, cfg.n_kv_heads, cfg.head_dim,
                     jnp.dtype(cfg.dtype).itemsize, kv_dtype,
                     tp_shards=self.tp_shards))
             self._max_blocks_per_seq = mb
@@ -560,6 +604,15 @@ class ContinuousDecoder:
             self.kv_block_size = int(kv_block_size)
             self._alloc = None
             self._state = init_decode_state(cfg, slots, self.total_len, seed)
+        # Per-row state that is not K/V (a mixer_types model's recurrent
+        # state and compressed keys), as allocated: the sizes never change.
+        self.state_bytes = sum(
+            int(leaf.nbytes) for name in ("lin_state", "ckeys")
+            for leaf in jax.tree.leaves(self._state.get(name, ())))
+        # The sparse layers' selection, for the host's count of what a
+        # decode step reads (None: every layer reads its whole context).
+        self._sparse = cfg.sparse_spec if cfg.mixer_types else None
+        self._admit_rows = max_admit_rows(cfg)
         if self.mesh is not None:
             # KV payload onto the mesh, head-sharded (and layer-sharded
             # over `pipeline` when pp > 1); scalars/tables/RNG
@@ -701,6 +754,12 @@ class ContinuousDecoder:
         # ordering's used-share input. Guarded by _mlock with the other
         # counters.
         self._tenant_served: dict[str, float] = {}
+        # Sparse-attention counters (zero without mixer_types), one count
+        # per row per decode step, from the lengths the host has.
+        self.sparse_tokens_attended = 0    # tokens the selection read
+        self.sparse_tokens_in_context = 0  # tokens the rows held
+        self.rows_dense = 0                # row-steps at or under dense_len
+        self.rows_sparse = 0               # row-steps over it
         self.kv_blocks_peak = 0      # high-water blocks_in_use
         self.peak_in_flight = 0      # high-water concurrent requests
         # Counter mutations and metrics() reads go through this lock so
@@ -811,6 +870,9 @@ class ContinuousDecoder:
             labels=("phase",))
         self._c_phase = {p: phase_seconds.labels(p) for p in SCHED_PHASES}
         self._ramp_streak = 0  # consecutive admission-only rounds
+        # The plain decode step whose tokens are still on the device:
+        # (tokens, emitted mask, time of its dispatch), or None.
+        self._inflight = None
         if self.prefix_cache is not None and self._alloc is not None:
             # Trie evictions must return the entry's refcounted blocks
             # to the pool; remove() fires this under the prefix lock.
@@ -1830,6 +1892,7 @@ class ContinuousDecoder:
         blocks and freed after the export."""
         if self._alloc is None:
             raise ValueError("prompt handoff requires kv_layout='paged'")
+        _check_hybrid(role=bool(self.cfg.mixer_types))
         toks = [int(t) for t in tokens][: self.prefill_len]
         if len(toks) < 2:
             raise ValueError("prompt handoff needs a >=2-token prompt")
@@ -1898,6 +1961,7 @@ class ContinuousDecoder:
         corrupt KV)."""
         if self._alloc is None:
             raise ValueError("prompt handoff requires kv_layout='paged'")
+        _check_hybrid(role=bool(self.cfg.mixer_types))
         if int(handoff["block_size"]) != self.kv_block_size:
             raise ValueError(
                 f"handoff block_size {handoff['block_size']} != "
@@ -2125,6 +2189,7 @@ class ContinuousDecoder:
         and falls through to the cold store or a plain prefill."""
         if self._alloc is None:
             raise ValueError("prefix export requires kv_layout='paged'")
+        _check_hybrid(kv_directory=bool(self.cfg.mixer_types))
         toks = [int(t) for t in tokens]
         cache = self.prefix_cache
         entry, depth, host = None, 0, None
@@ -2668,12 +2733,27 @@ class ContinuousDecoder:
         now = time.perf_counter()
         emitted_n, ttft_sum, ttft_n = 0, 0.0, 0
         tenant_tok: dict[str, int] = {}
+        spec = self._sparse
+        attended = in_context = dense = sparse = 0
         for slot in range(self.slots):
             req = self._slot_req[slot]
             if req is None or not emitted[slot]:
                 continue
             tok = int(toks[slot])
             req.out.append(tok)
+            if spec is not None:
+                # The step that emitted this token read the row with the
+                # token in it: n tokens of context.
+                n = len(req.tokens) + len(req.out)
+                in_context += n
+                if n <= spec.dense_len:
+                    dense += 1
+                    attended += n
+                else:
+                    sparse += 1
+                    blocks = min(spec.topk, (n - 1) // spec.block + 1)
+                    attended += ((blocks - 1) * spec.block
+                                 + (n - 1) % spec.block + 1)
             tenant_tok[req.tenant] = tenant_tok.get(req.tenant, 0) + 1
             if req.ttft_s is None:
                 req.ttft_s = now - req.submit_t
@@ -2699,6 +2779,10 @@ class ContinuousDecoder:
                 self._finish(req, reason="eos" if hit_eos else "length")
         with self._mlock:
             self.tokens_emitted += emitted_n
+            self.sparse_tokens_attended += attended
+            self.sparse_tokens_in_context += in_context
+            self.rows_dense += dense
+            self.rows_sparse += sparse
             self.ttft_sum += ttft_sum
             self.ttft_count += ttft_n
             for t, n in tenant_tok.items():
@@ -2874,6 +2958,7 @@ class ContinuousDecoder:
             queued = list(self._pending)
             self._pending.clear()
         self._chunk_jobs.clear()
+        self._inflight = None
         for slot in range(self.slots):
             req = self._slot_req[slot]
             if req is not None:
@@ -3111,6 +3196,11 @@ class ContinuousDecoder:
         """The device half of a round: admissions, one chunk of a long
         admission, then a verify or decode step. Returns the kind of the
         last dispatch."""
+        if self._inflight is not None and (
+                suspend_slot >= 0 or pending or self._chunk_jobs):
+            # Everything but a plain decode step routes tokens of its
+            # own: the step in flight delivers its tokens first.
+            self._deliver_inflight()
         if suspend_slot >= 0:
             # Preempt-to-host: the victim was chosen under the
             # cv, but the export is a device round-trip submits
@@ -3171,8 +3261,10 @@ class ContinuousDecoder:
                         hits.append((req, slot, plan))
                 for req, slot, (entry, plen, s) in hits:
                     self._admit_prefix(req, slot, entry, plen, s)
-            if misses:
-                self._admit_batch(misses)
+            # As many rows a dispatch as the decode layer takes.
+            rows = self._admit_rows or max(1, len(misses))
+            for i in range(0, len(misses), rows):
+                self._admit_batch(misses[i:i + rows])
             ramp = (any(req.want_left for req, _ in pending)
                     and (self.chunk_size == 1
                          or self._ramp_streak < 1))
@@ -3208,6 +3300,23 @@ class ContinuousDecoder:
             with self._mlock:
                 self.steps += self.chunk_size
                 self.dispatches += 1
+        # One step ahead. The device state holds all the next step needs
+        # (lengths, budgets, EOS parking), so this step was enqueued
+        # before the last one's tokens are fetched and routed: the host's
+        # part of a round runs beside the device's, not between two
+        # steps. A finish is seen a step late; that step emits nothing
+        # for the row, which the device has parked.
+        last, self._inflight = self._inflight, (toks, emitted, t_disp)
+        if last is not None:
+            self._deliver(*last)
+        if self._active_count == 0 or self._spec is not None:
+            # Nothing left to run ahead of (or the proposer reads every
+            # token a row has): take this step's tokens now.
+            self._deliver_inflight()
+        return "decode"
+
+    def _deliver(self, toks, emitted, t_disp: float) -> None:
+        """Fetch one plain decode dispatch's tokens and route them."""
         with self._phase("fetch", "decode"):
             toks, emitted = jax.device_get((toks, emitted))
             self._h_dispatch.labels("decode").observe(
@@ -3219,7 +3328,11 @@ class ContinuousDecoder:
                     self._dispatch(toks[k], emitted[k])
             else:
                 self._dispatch(toks, emitted)
-        return "decode"
+
+    def _deliver_inflight(self) -> None:
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._deliver(*step)
 
     # ------------------------------------------------------------------
 
@@ -3265,6 +3378,11 @@ class ContinuousDecoder:
                     if self.spec_drafted_tokens else 0.0),
                 "spec_draft_k": (sum(self._slot_k) / len(self._slot_k)
                                  if self._slot_k else 0.0),
+                "state_bytes": self.state_bytes,
+                "sparse_tokens_attended": self.sparse_tokens_attended,
+                "sparse_tokens_in_context": self.sparse_tokens_in_context,
+                "rows_dense": self.rows_dense,
+                "rows_sparse": self.rows_sparse,
                 "kv_cow_copies": self.kv_cow_copies,
                 "kv_shared_blocks": self.kv_shared_blocks,
                 "kv_defer_admissions": self.kv_defer_admissions,

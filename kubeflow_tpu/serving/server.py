@@ -563,6 +563,16 @@ class ModelServer:
                                 d["spec_draft_dispatches"],
                             "serving_spec_acceptance_rate":
                                 d["spec_acceptance_rate"],
+                            # Per-row state that is not K/V and what
+                            # the block selection read (presets with
+                            # mixer_types; 0 otherwise).
+                            "serving_state_bytes": d["state_bytes"],
+                            "serving_sparse_tokens_attended_total":
+                                d["sparse_tokens_attended"],
+                            "serving_sparse_tokens_in_context_total":
+                                d["sparse_tokens_in_context"],
+                            "serving_rows_dense_total": d["rows_dense"],
+                            "serving_rows_sparse_total": d["rows_sparse"],
                             "serving_kv_blocks_total": d["kv_blocks_total"],
                             "serving_kv_blocks_in_use":
                                 d["kv_blocks_in_use"],

@@ -6,3 +6,4 @@ the span readers)."""
 
 from benchmarks.tests.test_harness import *  # noqa: F401,F403
 from benchmarks.tests.test_spans import *  # noqa: F401,F403
+from benchmarks.tests.test_sessions import *  # noqa: F401,F403

@@ -8,6 +8,7 @@ correctness against the one-shot compiled path.
 
 import http.client
 import json
+import threading
 import time
 
 import jax
@@ -17,6 +18,7 @@ import pytest
 
 from kubeflow_tpu.models.decode import generate
 from kubeflow_tpu.models.registry import get_model
+from kubeflow_tpu.serving import continuous
 from kubeflow_tpu.serving.continuous import ContinuousDecoder
 from kubeflow_tpu.serving.engine import EngineConfig
 from kubeflow_tpu.serving.server import ModelServer
@@ -66,17 +68,25 @@ def test_greedy_parity_with_lockstep_generate(model, decoder):
         assert res["finish_reason"] == "length"
 
 
-def test_short_request_returns_before_long_peer(decoder):
+def test_short_request_returns_before_long_peer(model):
     """The decoupling the lockstep batch lacks: a 1-token request submitted
-    WITH a long one finishes as soon as its own token lands."""
-    long_h = decoder.submit([1, 2, 3], 8)
-    next(long_h.tokens(timeout=60))  # long is mid-flight
-    short_h = decoder.submit([4, 5], 1)
-    short_res = short_h.result(timeout=60)
-    long_running_at_short_done = not long_h._req.done.is_set()
-    long_res = long_h.result(timeout=60)
+    WITH a long one finishes as soon as its own token lands. (The peer is
+    64 tokens long so that a loaded machine's late submit still finds it
+    mid-flight: at 8 its seven rounds could pass first.)"""
+    spec, params = model
+    d = ContinuousDecoder(params, spec.config, slots=4, prefill_len=16,
+                          max_new_tokens=64)
+    try:
+        long_h = d.submit([1, 2, 3], 64)
+        next(long_h.tokens(timeout=60))  # long is mid-flight
+        short_h = d.submit([4, 5], 1)
+        short_res = short_h.result(timeout=60)
+        long_running_at_short_done = not long_h._req.done.is_set()
+        long_res = long_h.result(timeout=60)
+    finally:
+        d.stop()
     assert len(short_res["tokens"]) == 1
-    assert len(long_res["tokens"]) == 8
+    assert len(long_res["tokens"]) == 64
     assert long_running_at_short_done
 
 
@@ -384,18 +394,25 @@ def test_loop_crash_fails_inflight_and_queued_promptly(model, monkeypatch):
     still queued — must get the error immediately, not a 60s timeout."""
     spec, params = model
     d = ContinuousDecoder(params, spec.config, slots=1, prefill_len=16,
-                          max_new_tokens=8)
+                          max_new_tokens=64)
     try:
-        inflight = d.submit([1, 2, 3], 8)
+        inflight = d.submit([1, 2, 3], 64)
         next(inflight.tokens(timeout=60))  # decoding is underway
         boom = RuntimeError("injected decode failure")
+        real = continuous.decode_step
+        armed = threading.Event()
 
-        def explode(*_a, **_k):
-            raise boom
+        def explode(*a, **k):
+            # Armed only once the second request is queued: a loop that
+            # died first would refuse the submit itself.
+            if armed.is_set():
+                raise boom
+            return real(*a, **k)
 
         monkeypatch.setattr("kubeflow_tpu.serving.continuous.decode_step",
                             explode)
         queued = d.submit([4, 5], 4)  # slots=1: this one sits in _pending
+        armed.set()
         t0 = time.perf_counter()
         with pytest.raises(RuntimeError, match="injected decode failure"):
             inflight.result(timeout=10)
@@ -433,9 +450,9 @@ def test_stream_iteration_raises_loop_error(model, monkeypatch):
     as a raised error on the iterator, not a silent stall."""
     spec, params = model
     d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
-                          max_new_tokens=8)
+                          max_new_tokens=64)
     try:
-        h = d.submit([1, 2, 3], 8)
+        h = d.submit([1, 2, 3], 64)
         it = h.tokens(timeout=60)
         next(it)
         monkeypatch.setattr(
@@ -518,3 +535,54 @@ def test_stop_with_queued_requests_fails_them_cleanly(model):
     for h in handles:
         with pytest.raises((RuntimeError, TimeoutError)):
             h.result(timeout=5)
+
+
+def test_plain_decode_steps_run_one_ahead_of_their_tokens(model):
+    """While a row decodes, step n+1 is enqueued before step n's tokens
+    are fetched; the last step of a busy period is taken at once."""
+    spec, params = model
+    d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
+                          max_new_tokens=8)
+    ahead = []
+    deliver = d._deliver
+
+    def spy(toks, emitted, t_disp):
+        ahead.append((d.dispatches, d._inflight is not None))
+        deliver(toks, emitted, t_disp)
+
+    d._deliver = spy
+    try:
+        res = d.generate([1, 2, 3], 8, timeout=60)
+        assert len(res["tokens"]) == 8
+        deadline = time.time() + 10
+        while d._inflight is not None and time.time() < deadline:
+            time.sleep(0.01)
+        assert d._inflight is None
+    finally:
+        d.stop()
+    # The admission's fused step gave token 1; plain dispatch k (1-based)
+    # carries token k+1 and is fetched after dispatch k+1 went out.
+    assert ahead[:7] == [(k + 1, True) for k in range(1, 8)]
+    # Dispatch 8 ran ahead of the finish; it emits nothing and nothing is
+    # left in flight behind it.
+    assert ahead[7:] == [(8, False)]
+    assert d.metrics()["tokens_emitted"] == 8
+
+
+def test_admission_takes_the_step_in_flight_first(model):
+    """A request admitted while another decodes one step ahead: both get
+    the tokens they get alone, each in order."""
+    spec, params = model
+    d = ContinuousDecoder(params, spec.config, slots=4, prefill_len=16,
+                          max_new_tokens=24)
+    try:
+        alone = [d.generate(p, 24, timeout=60)["tokens"]
+                 for p in ([1, 2, 3], [7, 5])]
+        first = d.submit([1, 2, 3], 24)
+        stream = first.tokens(timeout=60)
+        got = [next(stream) for _ in range(5)]
+        second = d.submit([7, 5], 24)
+        assert second.result(timeout=60)["tokens"] == alone[1]
+        assert got + list(stream) == alone[0]
+    finally:
+        d.stop()

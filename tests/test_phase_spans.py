@@ -244,9 +244,10 @@ def test_a_256_token_request_drops_no_timeline_event(model):
                          "first_token", "finish"]
         finish = tl["events"][-1]
         assert finish["tokens"] == 256
-        # The admission's fused step gave the first token, one round each
-        # for the rest.
-        assert finish["rounds"] == 256
+        # The admission's fused step gave the first token; a round each
+        # for the rest, and the one that only enqueued the first plain
+        # step (tokens are routed a dispatch behind: one step ahead).
+        assert finish["rounds"] == 257
         # The decode phase is the one span first_token → finish.
         assert tl["spans"][-1]["name"] == "finish"
         assert sum(s["duration_ms"] for s in tl["spans"]) == pytest.approx(
