@@ -749,6 +749,45 @@ def child_kernels() -> int:
         check(f"paged_decode_attention[{label} pool, block {bs}]", 3e-2,
               lambda run=run: run("pallas"), lambda run=run: run("xla"))
 
+    # --- sparse decode attention at MiniCPM-SALA's widths (2 KV heads of
+    # 128, groups of 16, blocks of 64): rows on both sides of dense_len,
+    # one with nothing allocated, a shuffled pool; ONE selection, the
+    # program's own, read by the kernel and by the XLA gather.
+    from kubeflow_tpu.ops import sparse_attention as sa
+
+    spec = sa.SparseSpec(kernel=32, stride=16, block=64, topk=64,
+                         init_blocks=1, window=2048, dense_len=8192)
+    s_pos = np.array([0, 63, 64, 4095, 8191, 8192, 12000, 200], np.int32)
+    s_mb, s_pool = 192, 1024
+    order = rng.permutation(s_pool).tolist()
+    s_table = np.full((len(s_pos), s_mb), s_pool, np.int32)
+    for row, p in enumerate(s_pos[:-1]):  # the last row: sentinels only
+        s_table[row, :p // 64 + 1] = [order.pop() for _ in range(p // 64 + 1)]
+    sq = (4 * jax.random.normal(kq, (len(s_pos), 2, 16, 128))).astype(
+        jnp.bfloat16)
+    spk, spv = (jax.random.normal(k_, (2, s_pool, 2, 64, 128)).astype(
+        jnp.bfloat16) for k_ in (kk, kv))
+
+    s_table, s_pos = jnp.asarray(s_table), jnp.asarray(s_pos)
+
+    def select(q_, pk_, table_, pos_):
+        rows = pk_[1][jnp.minimum(table_, s_pool - 1)].transpose(
+            0, 2, 1, 3, 4).reshape(len(s_pos), 2, s_mb * 64, 128)
+        idx, ok = sa.select_blocks(
+            q_[:, :, :, None], sa.compress_keys(rows, spec), pos_[:, None],
+            s_mb, spec)
+        return idx[:, :, 0], ok[:, :, 0]
+    s_idx, s_ok = jax.jit(select)(sq, spk, s_table, s_pos)
+
+    def sparse(impl):
+        return jax.jit(
+            lambda q_, pk_, pv_, table_, idx_, ok_, pos_:
+            sa.sparse_decode_attention(q_, pk_, pv_, 1, table_, idx_, ok_,
+                                       pos_, spec, implementation=impl))(
+            sq, spk, spv, s_table, s_idx, s_ok, s_pos)
+    check("sparse_decode_attention[bf16 pool, block 64]", 3e-2,
+          lambda: sparse("pallas"), lambda: sparse("xla"))
+
     # --- rms_norm, forward and (custom-VJP) backward, training shape.
     x = jax.random.normal(kq, (8, SEQ_LEN, d)).astype(jnp.bfloat16)
     w = 1.0 + 0.1 * jax.random.normal(kk, (d,), jnp.float32)

@@ -41,10 +41,10 @@ from kubeflow_tpu.ops.attention import (
 )
 from kubeflow_tpu.ops.rotary import rotary_frequencies
 from kubeflow_tpu.ops.sparse_attention import (
-    attend_selected,
     compress_keys,
     compress_last,
     select_blocks,
+    sparse_decode_attention,
 )
 from kubeflow_tpu.models.transformer import (
     MIXER_LIGHTNING,
@@ -1290,8 +1290,9 @@ def _sparse_token(x, mixer, cfg: TransformerConfig, pool_k, pool_v,
                   layer: int, table, ckeys, pos_b, live):
     """A sparse layer for one token a row at positions ``pos_b`` [B]:
     write its K/V, complete the compressed key its position completes,
-    select, gather the selected blocks, attend. Returns (out [B, 1, D],
-    pool_k, pool_v, ckeys)."""
+    select, attend the selected blocks where they lie in the pool (the
+    whole pools go to the op: no layer is sliced out). Returns
+    (out [B, 1, D], pool_k, pool_v, ckeys)."""
     spec = cfg.sparse_spec
     b = x.shape[0]
     hkv, group = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
@@ -1312,12 +1313,8 @@ def _sparse_token(x, mixer, cfg: TransformerConfig, pool_k, pool_v,
                                 table.shape[1], spec)
         idx, ok = idx[:, :, 0], ok[:, :, 0]
     with scope(SCOPE_SPARSE_ATTN):
-        phys = jnp.take_along_axis(
-            jnp.broadcast_to(table[:, None, :], (b, hkv, table.shape[1])),
-            idx, axis=2)
-        heads = jnp.arange(hkv)[None, :, None]
-        o = attend_selected(qg, pool_k[layer, phys, heads],
-                            pool_v[layer, phys, heads], idx, ok, pos_b, spec)
+        o = sparse_decode_attention(qg, pool_k, pool_v, layer, table, idx,
+                                    ok, pos_b, spec)
     return mixer_out(o.reshape(b, 1, -1), x, mixer, cfg), pool_k, pool_v, ckeys
 
 

@@ -535,17 +535,23 @@ def _paged_decode_pallas(qg, k_pool, v_pool, table, pos, sm_scale,
     )(table.astype(jnp.int32), pos.astype(jnp.int32), qg, *operands)
 
 
-def _paged_kernel_unsupported(k_pool) -> str | None:
-    """Why the compiled (non-interpret) paged kernel cannot take this
-    pool (None = it can): it wants a TPU and lane/sublane-aligned
-    tiles."""
+def paged_tile_unsupported(block_size: int, head_dim: int) -> str | None:
+    """Why a compiled (non-interpret) kernel cannot read (block, head)
+    tiles ``[block_size, head_dim]`` out of a block pool (None = it can):
+    it wants a TPU and lane/sublane-aligned tiles. The one rule of every
+    block-table kernel (here and ``ops/sparse_attention.py``)."""
     if why := not_tpu():
         return why
-    _n, bs, _hkv, hd = _kv_payload(k_pool).shape
-    if hd % 128 or bs % 8:
-        return (f"head_dim {hd} must be a multiple of 128 and the block "
-                f"size {bs} a multiple of 8")
+    if head_dim % 128 or block_size % 8:
+        return (f"head_dim {head_dim} must be a multiple of 128 and the "
+                f"block size {block_size} a multiple of 8")
     return None
+
+
+def _paged_kernel_unsupported(k_pool) -> str | None:
+    """:func:`paged_tile_unsupported` of a ``[N, Bs, Hkv, hd]`` pool."""
+    _n, bs, _hkv, hd = _kv_payload(k_pool).shape
+    return paged_tile_unsupported(bs, hd)
 
 
 def _paged_decode_local(qg, k_pool, v_pool, table, pos, sm_scale,

@@ -1,5 +1,5 @@
 """Block-sparse attention over a cache (InfLLM-v2): a query reads block 0,
-the blocks that hold its last ``window`` tokens, and the best of the rest
+the blocks that hold its last ``window`` tokens and the best of the rest
 by a score over *compressed keys*, up to ``topk`` blocks in all; a query
 whose context is no longer than ``dense_len`` reads everything.
 
@@ -9,16 +9,29 @@ whose context is no longer than ``dense_len`` reads everything.
     g_j     = sum of p_{h,j} over the KV head's query heads
     score_b = max of g_j over the windows that overlap block b
 
-One selection per (query, KV head), shared by the head's group. The pieces
-are plain XLA on one layout, keys and values head-major
-``[..., Hkv, tokens, hd]``:
+One selection per (query, KV head), shared by the head's group. Keys and
+values are head-major, ``[..., Hkv, tokens, hd]``, in a row and inside a
+pool block alike, so one (block, KV head) tile is contiguous.
+
+The selection is plain XLA:
 
 - :func:`compress_keys` / :func:`compress_last`: all windows of a row, or
   the one window a decoded token completes;
-- :func:`select_blocks`: block indices and which of them count. A dense
-  query is a query whose every visible block is forced, so rows on both
-  sides of ``dense_len`` share one compiled shape;
-- :func:`attend_selected`: softmax attention over gathered blocks (decode);
+- :func:`select_blocks`: block indices and which of them count, the ones
+  that count first. A dense query is a query whose every visible block is
+  forced, so rows on both sides of ``dense_len`` share one compiled shape.
+
+What reads the selected blocks is one of three:
+
+- :func:`sparse_decode_attention` (decode, one query a row): on a TPU, at
+  lane-aligned tiles, a Pallas kernel that copies each selected
+  (block, KV head) tile from the pool WHERE IT LIES into VMEM, by physical
+  block id, a chunk of tiles at a time and the next chunk in flight, with an
+  online softmax, and stops at the row's own count of blocks. The pool is
+  never sliced, gathered or copied. Elsewhere (the CPU, a head size under
+  128) an XLA gather of the blocks followed by :func:`attend_selected`,
+  which is also what the kernel's tests compare it with;
+- :func:`attend_selected`: softmax attention over gathered blocks;
 - :func:`attend_span`: the same selection as a mask over a whole row, in
   blocks of queries (prefill, and the cache-free forward).
 """
@@ -27,10 +40,17 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
+from kubeflow_tpu.ops.attention import paged_tile_unsupported
+
 _BIG = 1e30
+# Selected blocks the decode kernel copies and attends at a time, one
+# chunk of its inner loop (timings of the alternatives on a v5e: PERF.md,
+# PR 30).
+_BLOCKS_PER_CHUNK = 64
 
 
 @dataclass(frozen=True)
@@ -151,6 +171,200 @@ def attend_selected(q, k_sel, v_sel, idx, ok, pos, spec: SparseSpec):
     scores = jnp.einsum("bkgd,bktd->bkgt", q, k_sel,
                         preferred_element_type=jnp.float32) * hd ** -0.5
     return _softmax_pv(scores, mask, v_sel, q.dtype, "bkgt,bktd->bkgd")
+
+
+def decode_implementation(spec: SparseSpec, head_dim: int) -> str:
+    """What :func:`sparse_decode_attention` runs when it is left to choose:
+    ``"pallas"`` where the kernel compiles (a TPU, lane-aligned tiles),
+    else ``"xla"``."""
+    return "xla" if paged_tile_unsupported(spec.block, head_dim) else "pallas"
+
+
+def sparse_decode_attention(q, pool_k, pool_v, layer: int, table, idx, ok,
+                            pos, spec: SparseSpec, *,
+                            implementation: str | None = None,
+                            interpret: bool = False):
+    """One query per row over the blocks its selection names, read out of
+    the block pool. q [B, Hkv, G, hd]; pool_k, pool_v the WHOLE pools
+    [La, N, Hkv, Bs, hd] with Bs the selection's block and ``layer`` the
+    (static) sparse layer; table [B, MB] (entries >= N are unallocated
+    sentinels and clamp); idx, ok [B, Hkv, n] as :func:`select_blocks`
+    returns them (``ok`` a prefix); pos [B]. → [B, Hkv, G, hd] in q's dtype.
+
+    ``implementation``: None (:func:`decode_implementation`), "pallas" or
+    "xla". An explicit "pallas" that cannot be compiled for this backend or
+    shape raises (``interpret=True`` runs it in the interpreter anywhere).
+    The kernel reads ``ok.sum`` tiles a (row, KV head) in place; the XLA
+    path gathers all n into a copy and masks."""
+    b, hkv, _g, hd = q.shape
+    if implementation is None:
+        implementation = decode_implementation(spec, hd)
+    elif implementation == "pallas" and not interpret:
+        if why := paged_tile_unsupported(spec.block, hd):
+            raise ValueError(
+                f"sparse_decode_attention(implementation='pallas'): {why}")
+    if implementation == "xla":
+        phys = jnp.take_along_axis(
+            jnp.broadcast_to(table[:, None, :], (b, hkv, table.shape[1])),
+            idx, axis=2)
+        heads = jnp.arange(hkv)[None, :, None]
+        return attend_selected(q, pool_k[layer, phys, heads],
+                               pool_v[layer, phys, heads], idx, ok, pos,
+                               spec)
+    if implementation != "pallas":
+        raise ValueError(f"unknown implementation {implementation!r}")
+    # ``ok`` is a prefix, so a count says which slots are real; of those
+    # only the block that holds ``pos`` is cut short (select_blocks drops
+    # every block past it), so its slot and the lanes it keeps say the rest.
+    count = ok.sum(axis=-1, dtype=jnp.int32)
+    cut = jnp.argmax(idx == (pos // spec.block)[:, None, None], axis=-1)
+    return _attend_pool_pallas(
+        q, pool_k, pool_v, layer, table, idx, count, cut.astype(jnp.int32),
+        (pos % spec.block).astype(jnp.int32), interpret=interpret)
+
+
+def _attend_pool_pallas(q, pool_k, pool_v, layer: int, table, idx, count,
+                        cut, keep, *,
+                        blocks_per_chunk: int = _BLOCKS_PER_CHUNK,
+                        interpret: bool = False):
+    """The kernel of :func:`sparse_decode_attention`. table [B, MB] and
+    idx [B, Hkv, n] (a slot's physical block is ``table[row, idx]``, looked
+    up by the kernel's scalar core: as an XLA gather of B·Hkv·n scalars it
+    took half as long as the kernel itself); count [B, Hkv] how many slots
+    are read; cut [B, Hkv] the slot whose block keeps lanes ``<= keep[b]``
+    only (keep [B]). All five are scalar-prefetched.
+
+    Grid (B, Hkv), one (row, KV head) a grid step, in order. The pools stay
+    in HBM; a step's inner loop takes a chunk of ``blocks_per_chunk`` slots
+    at a time: one async copy per (block, head) tile [Bs, hd] (contiguous:
+    the pool is head-major inside a block) into one of two VMEM buffers,
+    the next chunk's copies (the next grid step's first chunk, at a step's
+    last) started before this chunk's are waited for. A chunk is copied whole,
+    so up to ``blocks_per_chunk - 1`` tiles past a row's count are read and
+    masked; chunks past it are neither copied nor computed. Scores, the
+    running maximum, the normaliser and the accumulator are float32; K, V
+    and the probabilities enter the MXU at the pool's dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hkv, g, hd = q.shape
+    n_pool, bs = pool_k.shape[1], pool_k.shape[3]
+    mb = table.shape[1]
+    p = min(blocks_per_chunk, idx.shape[-1])
+    idx = jnp.pad(idx, ((0, 0), (0, 0), (0, -idx.shape[-1] % p)))
+    n = idx.shape[-1]
+    steps = b * hkv
+    sm_scale = hd ** -0.5
+
+    def kernel(table_ref, idx_ref, count_ref, cut_ref, keep_ref, q_ref,
+               k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, nxt_ref):
+        # nxt_ref[0]: the buffer the next chunk to compute lands in;
+        # nxt_ref[1]: whether the grid step before this one has started
+        # this step's first chunk.
+        row, head = pl.program_id(0), pl.program_id(1)
+        i = row * hkv + head
+        n_read, cut_slot, keep_lanes = count_ref[i], cut_ref[i], keep_ref[row]
+        chunks = pl.cdiv(n_read, p)
+
+        def start(step, chunk, buf):
+            """Start the 2p copies of grid step ``step``'s chunk."""
+            r, h = lax.div(step, hkv), lax.rem(step, hkv)
+            for j in range(p):
+                # An unallocated entry (>= N) clamps; it lies past the count.
+                blk = jnp.minimum(
+                    table_ref[r * mb + idx_ref[step * n + chunk * p + j]],
+                    n_pool - 1)
+                for hbm, vmem, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    pltpu.make_async_copy(hbm.at[layer, blk, h],
+                                          vmem.at[buf, j],
+                                          sems.at[s, buf]).start()
+
+        def wait(vmem, s, buf):
+            # A wait needs the copy's shape only: p tiles on one semaphore.
+            for j in range(p):
+                pltpu.make_async_copy(k_hbm.at[layer, 0, 0],
+                                      vmem.at[buf, j],
+                                      sems.at[s, buf]).wait()
+
+        @pl.when(i == 0)
+        def _first_step():
+            nxt_ref[0] = 0
+            nxt_ref[1] = 0
+
+        @pl.when((chunks > 0) & (nxt_ref[1] == 0))
+        def _own_first_chunk():
+            start(i, 0, nxt_ref[0])
+
+        nxt_ref[1] = 0
+        qh = q_ref[...]
+        tok = lax.broadcasted_iota(jnp.int32, (1, p * bs), 1)
+
+        def body(c, carry):
+            m, l, acc = carry
+            buf = nxt_ref[0]
+            other = 1 - buf
+
+            @pl.when(c + 1 < chunks)
+            def _next_chunk():
+                start(i, c + 1, other)
+
+            after = jnp.minimum(i + 1, steps - 1)
+
+            @pl.when((c + 1 == chunks) & (i + 1 < steps)
+                     & (count_ref[after] > 0))
+            def _next_steps_first_chunk():
+                start(after, 0, other)
+                nxt_ref[1] = 1
+
+            nxt_ref[0] = other
+            wait(k_buf, 0, buf)
+            k = k_buf[buf].reshape(p * bs, hd)
+            s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            slot = c * p + tok // bs
+            seen = (slot < n_read) & (
+                (slot != cut_slot) | (tok % bs <= keep_lanes))
+            s = jnp.where(seen, s * sm_scale, -_BIG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            e = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            fade = jnp.exp(m - m_new)
+            wait(v_buf, 1, buf)
+            v = v_buf[buf].reshape(p * bs, hd)
+            acc = acc * fade + jnp.dot(e.astype(v.dtype), v,
+                                       preferred_element_type=jnp.float32)
+            return m_new, l * fade + e.sum(axis=-1, keepdims=True), acc
+
+        _, l, acc = lax.fori_loop(
+            0, chunks, body,
+            (jnp.full((g, 1), -_BIG, jnp.float32),
+             jnp.zeros((g, 1), jnp.float32),
+             jnp.zeros((g, hd), jnp.float32)))
+        # Nothing read (count 0): 0, as _softmax_pv gives.
+        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    q_spec = pl.BlockSpec((None, None, g, hd),
+                          lambda row, head, *_: (row, head, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, hkv),
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, p, bs, hd), pool_k.dtype),
+                pltpu.VMEM((2, p, bs, hd), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="sparse_decode_attention",
+    )(table.reshape(-1), idx.reshape(-1), count.reshape(-1), cut.reshape(-1),
+      keep, q, pool_k, pool_v)
 
 
 def attend_span(q, pos, k_row, v_row, ckeys, spec: SparseSpec,

@@ -102,6 +102,7 @@ from kubeflow_tpu.observability.tracing import (
     TraceStore,
     host_span,
 )
+from kubeflow_tpu.ops.sparse_attention import decode_implementation
 from kubeflow_tpu.serving.affinity import (
     DEFAULT_AFFINITY_TOKENS,
     prefix_affinity_key,
@@ -612,6 +613,12 @@ class ContinuousDecoder:
         # The sparse layers' selection, for the host's count of what a
         # decode step reads (None: every layer reads its whole context).
         self._sparse = cfg.sparse_spec if cfg.mixer_types else None
+        # What the sparse layers' decode read compiles to on this backend
+        # at this shape ("" where no layer selects): the kernel that reads
+        # the selected blocks in place, or the XLA gather.
+        self.sparse_attn_impl = (
+            decode_implementation(self._sparse, cfg.head_dim)
+            if self._sparse else "")
         self._admit_rows = max_admit_rows(cfg)
         if self.mesh is not None:
             # KV payload onto the mesh, head-sharded (and layer-sharded
@@ -3379,6 +3386,7 @@ class ContinuousDecoder:
                 "spec_draft_k": (sum(self._slot_k) / len(self._slot_k)
                                  if self._slot_k else 0.0),
                 "state_bytes": self.state_bytes,
+                "sparse_attn_impl": self.sparse_attn_impl,
                 "sparse_tokens_attended": self.sparse_tokens_attended,
                 "sparse_tokens_in_context": self.sparse_tokens_in_context,
                 "rows_dense": self.rows_dense,

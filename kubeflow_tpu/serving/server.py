@@ -567,6 +567,8 @@ class ModelServer:
                             # the block selection read (presets with
                             # mixer_types; 0 otherwise).
                             "serving_state_bytes": d["state_bytes"],
+                            "serving_sparse_attn_pallas":
+                                int(d["sparse_attn_impl"] == "pallas"),
                             "serving_sparse_tokens_attended_total":
                                 d["sparse_tokens_attended"],
                             "serving_sparse_tokens_in_context_total":

@@ -104,11 +104,20 @@ def test_watch_streams_events_over_http(http_env):
 
     t = threading.Thread(target=consume, daemon=True)
     t.start()
-    client.create({"apiVersion": "v1", "kind": "ConfigMap",
-                   "metadata": {"name": "w1", "namespace": "kubeflow"}})
-    client.delete("v1", "ConfigMap", "w1", "kubeflow")
-    assert done.wait(10), f"watch saw only {seen}"
-    assert ("ADDED", "w1") in seen
+    # The watch connects on a thread of its own: an object made and gone
+    # before it has is never seen, so make one a second until two events
+    # have come (the first attempt's, on a machine that is not loaded).
+    names = []
+    for attempt in range(10):
+        names.append(f"w{attempt + 1}")
+        client.create({"apiVersion": "v1", "kind": "ConfigMap",
+                       "metadata": {"name": names[-1],
+                                    "namespace": "kubeflow"}})
+        client.delete("v1", "ConfigMap", names[-1], "kubeflow")
+        if done.wait(1):
+            break
+    assert done.is_set(), f"watch saw only {seen}"
+    assert any(("ADDED", name) in seen for name in names)
     stream.stop()
 
 

@@ -3,8 +3,10 @@ block-sparse attention layers in one decoder) against the plain reference
 ``benchmarks/reference/minicpm_sala_f32.py`` on seeded weights, at the
 ``sala-test-tiny`` preset in float32: the cache-free forward, prefill and
 decode through the continuous decoder, the forms of the lightning
-equation, the selection's invariants, the muP scalings, and what refuses
-such a model by name."""
+equation, the selection's invariants, the decode kernel that reads the
+selected blocks out of the pool (in interpret mode, at the widths it
+engages at) against the gather it replaces, the muP scalings, and what
+refuses such a model by name."""
 
 import dataclasses
 
@@ -270,6 +272,111 @@ def test_topk_of_every_block_is_dense_attention():
     want = jnp.einsum("bkgqt,bktd->bkgqd", p, v_row)
     want = want[0].transpose(2, 0, 1, 3).reshape(1, len(pos), 4, 16)
     assert float(jnp.abs(got - want).max()) < 1e-5
+
+
+# The decode read at the widths the kernel engages at (head_dim 128,
+# block 64), one batch: a row per position around the block and dense_len
+# boundaries, a long row, and a row with nothing allocated. The pool is
+# handed out in a shuffled order, so no row's blocks are contiguous or
+# ascending.
+KSPEC = sa.SparseSpec(kernel=32, stride=16, block=64, topk=16, init_blocks=1,
+                      window=512, dense_len=2048)
+KPOS = (0, 63, 64, 4095, KSPEC.dense_len - 1, KSPEC.dense_len,
+        KSPEC.dense_len + 1, 6000, 200)
+UNALLOCATED = len(KPOS) - 1  # the last row's table is all sentinels
+
+
+@pytest.fixture(scope="module")
+def pool_read():
+    b, hkv, group, hd, mb, n_pool, layer = len(KPOS), 2, 4, 128, 96, 400, 1
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    pool_k, pool_v = (jax.random.normal(k, (2, n_pool, hkv, KSPEC.block, hd),
+                                        jnp.float32) for k in keys[:2])
+    q = jax.random.normal(keys[2], (b, hkv, group, hd), jnp.float32)
+    order = np.random.default_rng(5).permutation(n_pool).tolist()
+    table = np.full((b, mb), n_pool, np.int32)
+    for row, pos in enumerate(KPOS[:UNALLOCATED]):
+        for j in range(pos // KSPEC.block + 1):
+            table[row, j] = order.pop()
+    assert (np.diff(table[3, :64]) < 0).any()  # scattered, not monotone
+    table, pos = jnp.asarray(table), jnp.asarray(KPOS, jnp.int32)
+    k_rows = decode._hm_row(pool_k, layer, jnp.minimum(table, n_pool - 1))
+    idx, ok = sa.select_blocks(q[:, :, :, None],
+                               sa.compress_keys(k_rows, KSPEC), pos[:, None],
+                               mb, KSPEC)
+    idx, ok = idx[:, :, 0], ok[:, :, 0]
+    read = {impl: np.asarray(sa.sparse_decode_attention(
+        q, pool_k, pool_v, layer, table, idx, ok, pos, KSPEC,
+        implementation=impl, interpret=True)) for impl in ("xla", "pallas")}
+    return np.asarray(ok), read
+
+
+@pytest.mark.parametrize("row", range(len(KPOS)))
+def test_the_block_table_kernel_reads_what_the_gather_reads(pool_read, row):
+    _, read = pool_read
+    assert np.abs(read["xla"][row]).max() > 0.01
+    assert np.abs(read["pallas"][row] - read["xla"][row]).max() < 1e-5
+
+
+@pytest.mark.parametrize("row", range(len(KPOS)))
+def test_the_selection_counts_are_a_prefix(pool_read, row):
+    """What the kernel is told, a count, says all ``ok`` says: the real
+    slots come first, ``topk`` of them past dense_len and every visible
+    block up to it."""
+    ok, _ = pool_read
+    pos, n = KPOS[row], ok.shape[-1]
+    assert n == KSPEC.n_select(96 * KSPEC.block) == 32
+    want = (pos // KSPEC.block + 1 if pos + 1 <= KSPEC.dense_len
+            else KSPEC.topk)
+    for head in range(2):
+        count = int(ok[row, head].sum())
+        assert count == want
+        assert (ok[row, head] == (np.arange(n) < count)).all()
+
+
+def test_the_kernel_reads_a_bfloat16_pool_in_chunks_of_any_size():
+    """The cell's dtype; chunks of 5 blocks do not divide the 8 slots."""
+    keys = jax.random.split(jax.random.PRNGKey(6), 3)
+    pool_k, pool_v = (jax.random.normal(k, (1, 24, 2, 64, 128),
+                                        jnp.bfloat16) for k in keys[:2])
+    q = jax.random.normal(keys[2], (3, 2, 16, 128), jnp.bfloat16)
+    rng = np.random.default_rng(6)
+    table = jnp.asarray(rng.integers(0, 24, (3, 12)), jnp.int32)
+    idx = jnp.asarray(rng.integers(0, 12, (3, 2, 8)), jnp.int32)
+    count = jnp.asarray([[8, 3], [1, 6], [0, 5]], jnp.int32)
+    cut, keep = count - 1, jnp.asarray([0, 17, 63], jnp.int32)
+    got = sa._attend_pool_pallas(q, pool_k, pool_v, 0, table, idx, count, cut,
+                                 keep, blocks_per_chunk=5, interpret=True)
+    phys = jnp.take_along_axis(jnp.broadcast_to(table[:, None], (3, 2, 12)),
+                               idx, axis=2)
+    heads = jnp.arange(2)[None, :, None]
+    # attend_selected masks by virtual position: say the cut slot's block
+    # is the row's last, every other slot's its first.
+    virtual = jnp.where(jnp.arange(8) == cut[..., None], 7, 0)
+    want = sa.attend_selected(
+        q, pool_k[0, phys, heads], pool_v[0, phys, heads], virtual,
+        jnp.arange(8) < count[..., None], 7 * 64 + keep, KSPEC)
+    diff = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+    assert float(diff.max()) < 0.02
+    assert float(jnp.abs(got[2, 0].astype(jnp.float32)).max()) == 0.0
+
+
+def test_an_explicit_kernel_off_the_tpu_or_off_its_shapes_raises():
+    pool = jnp.zeros((1, 4, 2, SPEC.block, 16))
+    args = (jnp.zeros((1, 2, 2, 16)), pool, pool, 0,
+            jnp.zeros((1, 4), jnp.int32), jnp.zeros((1, 2, 2), jnp.int32),
+            jnp.ones((1, 2, 2), bool), jnp.zeros((1,), jnp.int32), SPEC)
+    with pytest.raises(ValueError, match="implementation='pallas'"):
+        sa.sparse_decode_attention(*args, implementation="pallas")
+    with pytest.raises(ValueError, match="unknown implementation"):
+        sa.sparse_decode_attention(*args, implementation="triton")
+    assert sa.decode_implementation(SPEC, 16) == "xla"
+    assert sa.sparse_decode_attention(*args).shape == (1, 2, 2, 16)
+
+
+def test_the_decoder_says_which_read_it_compiled(served):
+    _, _, m = served
+    assert m["sparse_attn_impl"] == "xla"  # the CPU, and head_dim 16
 
 
 @pytest.mark.parametrize("scaling", ["embed_scale", "residual_scale",

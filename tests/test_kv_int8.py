@@ -476,6 +476,7 @@ def test_int8_metrics_and_prometheus_gauges(model):
     finally:
         server.stop()
     assert "serving_kv_dtype_int8 1" in text
+    assert "serving_sparse_attn_pallas 0" in text  # no sparse layer here
     assert f"serving_kv_bytes_per_token {want}" in text
     assert type_line("serving_kv_bytes_in_use", "gauge") in text
     assert "serving_kv_bytes_total" in text

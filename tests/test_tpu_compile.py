@@ -1,0 +1,76 @@
+"""Kernels of the serving path compiled for a v5e that is described, not
+attached: what Mosaic refuses (a misaligned slice, too much VMEM) shows up
+here, where interpret mode shows nothing. Nothing runs, so nothing here is
+a result or a time. The topology is described inside a fixture of this one
+file, never at import: one process at a time may load the TPU's library,
+and every xdist worker imports every test file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from kubeflow_tpu.ops import sparse_attention as sa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache and cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# (rows, pool blocks, block, blocks a row, slots a (row, head), blocks a
+# chunk): the long-decode cell's shapes as the decode step calls the
+# kernel (its block table and selection, 214 KB, fit scalar memory), and
+# a small block with a chunk that does not divide the slots.
+SHAPES = {"long-decode": (64, 18688, 64, 584, 128, None),
+          "block-8-ragged-chunk": (8, 512, 8, 64, 20, 8)}
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_the_sparse_decode_kernel_compiles_for_v5e(one_chip, no_compile_cache,
+                                                   shape):
+    b, n_pool, bs, mb, n, chunk = SHAPES[shape]
+    hkv, group, hd = 2, 16, 128
+
+    def arg(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def read(q, pool_k, pool_v, table, idx, count, cut, keep):
+        kw = {} if chunk is None else {"blocks_per_chunk": chunk}
+        return sa._attend_pool_pallas(q, pool_k, pool_v, 1, table, idx, count,
+                                      cut, keep, **kw)
+
+    pool = arg((2, n_pool, hkv, bs, hd), jnp.bfloat16)
+    compiled = jax.jit(read).lower(
+        arg((b, hkv, group, hd), jnp.bfloat16), pool, pool,
+        arg((b, mb), jnp.int32), arg((b, hkv, n), jnp.int32),
+        arg((b, hkv), jnp.int32),
+        arg((b, hkv), jnp.int32), arg((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The pools go in whole and in place: nothing of their size is made.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
