@@ -20,11 +20,11 @@ JAX_PLATFORMS=cpu python -m kubeflow_tpu.observability.lint --self-check
 # via its type_line(). The AST scan replaces the old grep: it sees
 # through f-strings and concatenation, and it cannot be fooled by the
 # phrase appearing in comments or docs. Scope matches the old gate
-# (package + tests + benches); the full rule suite over kubeflow_tpu/
+# (package + tests); the full rule suite over kubeflow_tpu/
 # runs in the separate static-analysis stage.
 # tests/*.py (not tests/fixtures/ — the analysis bad-fixtures contain
 # a deliberate hand-rolled renderer the checker suite asserts on).
 JAX_PLATFORMS=cpu python -m kubeflow_tpu.analysis \
     --rules metrics-type-literal \
-    kubeflow_tpu tests/*.py bench.py bench_serving.py
+    kubeflow_tpu tests/*.py
 echo "single-renderer invariant ok"

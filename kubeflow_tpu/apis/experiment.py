@@ -2,7 +2,7 @@
 
 Where a StudyJob (apis/tuning.py) tunes an arbitrary trial template, an
 Experiment is specialised for the serving engine: it names a registered
-bench_serving scenario (serving/scenarios.py), a knob space drawn from
+serving scenario (serving/scenarios.py), a knob space drawn from
 the engine's KNOB_CATALOG, and a search algorithm; the controller runs
 measured trials, reads objectives from the histogram exposition via the
 autoscaler's scrape_signals path, and ships the winner through the

@@ -227,7 +227,7 @@ def scheduling_policy_schema() -> dict:
                     },
                     "profiles": {
                         # profile -> accelerator -> measured throughput
-                        # (tokens/s/chip, as bench.py prints them): the
+                        # (tokens/s/chip, in the shape a trial prints): the
                         # Gavel-style heterogeneity signal.
                         "type": "object",
                         "x-kubernetes-preserve-unknown-fields": True,

@@ -18,7 +18,7 @@ from kubeflow_tpu.version import API_GROUP, DEFAULT_NAMESPACE
 
 @prototype(
     "experiment",
-    "Experiment CRD + RBAC + example CR: knob search over a bench_serving "
+    "Experiment CRD + RBAC + example CR: knob search over a serving "
     "scenario, winner promoted through the rollout controller",
     params=[
         ParamSpec("name", "decode-knobs"),

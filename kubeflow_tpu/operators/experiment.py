@@ -3,7 +3,7 @@
 Katib's experiment loop fused with kubebench's measured runs
 (kubeflow/katib studyjobcontroller.libsonnet; kubebench job templates):
 reconcile an Experiment by fanning out measured trials of a registered
-bench_serving scenario (serving/scenarios.py), feeding each trial's
+serving scenario (serving/scenarios.py), feeding each trial's
 objective — read from the histogram exposition through the same
 ``scrape_signals`` vector the autoscaler consumes — back into the
 suggestion algorithm, and shipping the winning knob config through the
@@ -20,9 +20,9 @@ Two trial modes:
   ContinuousDecoder inside the operator process via the scenario
   registry — no cluster round-trip, used by CI and tests;
 - ``job``: the trial renders a **preemptible** JaxJob (low scheduler
-  priority — trials are background load) running the same scenario via
-  the bench CLI; a preempted trial is re-run with its recorded seed
-  rather than poisoning the objective.
+  priority — trials are background load) whose container runs
+  ``python -m kubeflow_tpu.serving.scenarios``; a preempted trial is
+  re-run with its recorded seed rather than poisoning the objective.
 
 Reproducibility: one experiment seed (spec.seed) threads through both
 suggestion sampling and scenario traffic generation; each trial's
@@ -199,9 +199,6 @@ class ExperimentController(Controller):
         space. A spec naming an unknown scenario fails the experiment."""
         from kubeflow_tpu.serving import scenarios
         sc = scenarios.get_scenario(spec["scenario"])
-        if sc.trial is None:
-            raise ValueError(
-                f"scenario {spec['scenario']!r} has no trial runner")
         parameters = spec.get("parameters") or list(sc.parameters)
         if not parameters:
             raise ValueError(
@@ -356,7 +353,8 @@ class ExperimentController(Controller):
                             "name": "trial",
                             "image": "kubeflow-tpu/bench:latest",
                             "command": [
-                                "python", "bench_serving.py",
+                                "python", "-m",
+                                "kubeflow_tpu.serving.scenarios",
                                 "--scenario", spec["scenario"],
                                 "--seed", str(trial["seed"]),
                                 "--quick",

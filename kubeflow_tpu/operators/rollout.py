@@ -89,7 +89,7 @@ def _registry_fleet(namespace: str, name: str):
 class RolloutController(Controller):
     """spec.versions → canary walk → Promoted | RolledBack.
 
-    Injectables (tests and the bench drive all four):
+    Injectables (the tests drive all four):
 
     - ``fleet_for(ns, name)`` → the fleet handle (default: the
       in-process registry);

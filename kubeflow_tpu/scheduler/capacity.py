@@ -195,7 +195,7 @@ class ThroughputBook:
     def from_bench_files(cls, files: Mapping[str, str],
                          extra: Mapping[str, Mapping[str, float]]
                          | None = None) -> "ThroughputBook":
-        """Build profiles from bench output files (one ``bench.py`` JSON
+        """Build profiles from files in the shape a trial prints (one JSON
         line, bare or under a ``"parsed"`` key):
         ``files`` maps accelerator type -> path measured on it. Each file
         contributes its config's leading token (e.g. ``flagship-1b``) as

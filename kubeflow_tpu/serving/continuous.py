@@ -496,7 +496,7 @@ class ContinuousDecoder:
         # Decode steps fused per device dispatch. 1 = one dispatch per
         # token (finest admission/streaming granularity). K>1 trades
         # admission latency (a new request waits up to K steps) for K×
-        # fewer dispatches (bench_serving.py --generate runs both).
+        # fewer dispatches (tests/test_continuous.py runs both).
         # EOS parking moves on-device inside the fused loop either way.
         self.chunk_size = max(1, int(chunk_size))
         # Long-context serving: prefill_chunk_tokens > 0 admits any
@@ -2504,8 +2504,8 @@ class ContinuousDecoder:
         with self._mlock:
             self.weight_pushes += 1
             # The stall decode actually pays: waiting out the in-flight
-            # dispatch for the lock plus the pointer swap — the number
-            # the bench gates at <= one dispatch gap.
+            # dispatch for the lock plus the pointer swap: at most one
+            # dispatch gap by construction (not yet timed on the chip).
             self.last_swap_seconds = swap_s
         tl = self.trace.start(f"weights-v{new_version}")
         tl.event("push", version=new_version,
@@ -3486,7 +3486,7 @@ class ContinuousDecoder:
         if self.kv_directory is not None:
             snap["kv_directory_keys"] = self.kv_directory.stats()["keys"]
         # Histogram-backed latency quantiles (ttft_avg_s above stays for
-        # backward compatibility — bench_serving.py and dashboards read
+        # backward compatibility — dashboards and test_observability read
         # it — but the distribution is what autoscaling policies need).
         # Histogram locks are leaves, taken outside the snapshot locks.
         for key, hist in (("ttft", self._h_ttft),

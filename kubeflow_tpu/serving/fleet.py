@@ -99,7 +99,7 @@ class DecoderFleet:
     outstanding requests (0 = unbounded, never spill); ``kv_pressure``
     bounds its KV pool fill fraction (0 = ignore). ``router`` is
     "affine" (rendezvous, the default) or "random" (the seeded baseline
-    the fleet bench compares against).
+    tests/test_fleet.py compares it against).
 
     **Disaggregated mode**: replicas carrying ``role == "prefill"``
     (the decoder's own attribute — the same knob the CRD's role
@@ -213,7 +213,7 @@ class DecoderFleet:
         where the dispatch set deserialized).
 
         Membership mutation is CONTROL-PLANE and single-writer (the
-        operator's reconcile loop, a test, or the bench harness) —
+        operator's reconcile loop or a test) —
         hot-path readers stay lock-free because the membership dicts
         are never mutated in place: a join builds fresh dicts and
         publishes them by atomic reference swap, so a concurrent
@@ -640,7 +640,7 @@ class DecoderFleet:
                     "max_lag": self.weights_max_lag}
 
     def metrics(self) -> dict:
-        """Per-replica decoder metrics plus fleet aggregates (the bench
+        """Per-replica decoder metrics plus fleet aggregates (the tests
         and the autoscaler read the same names the single-decoder
         metrics() exposes, summed over live replicas)."""
         # Snapshot the mutable fleet state under its lock: mark_dead()

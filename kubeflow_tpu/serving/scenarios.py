@@ -1,34 +1,30 @@
-"""Named serving scenarios — ONE registry shared by three drivers.
+"""Named serving scenarios: the registry the Experiment operator's trials run.
 
-``bench_serving.py`` (the CLI), the CI smoke scripts, and the
-ExperimentController's trials all used to carry their own copy of "drive
-the decoder with a workload, report a number". This module is the single
-implementation: a :class:`Scenario` couples
-
-- a **bench** entry (``fn(args, model) -> dict``, the bench_serving
-  contract: ``metric``/``value``/``unit``/``config`` keys plus a
-  ``regression`` marker) for the CLI/CI path, and
-- a **trial** entry (``fn(assignments, *, seed, model, quick) -> dict``)
-  for the self-tuning loop: knob overrides in, objectives out. The trial
-  drives the SAME serving stack the production replica runs and reads its
-  objectives from the PR-7 histogram exposition through the autoscaler's
-  ``scrape_signals`` reduction — a tuned config wins on the numbers the
-  SLO gates actually judge, not on a bespoke stopwatch.
+A :class:`Scenario` couples a **trial** (``fn(assignments, *, seed, model,
+quick) -> dict``: knob overrides in, objectives out) with its **knob
+search space** (katib-style parameter dicts over the engine knobs it
+honors) and the **checked-in defaults** those knobs hold today. The
+defaults ARE the baseline an experiment must beat. A trial drives the
+SAME serving stack the production replica runs and reads its objectives
+from the histogram exposition through the autoscaler's ``scrape_signals``
+reduction, so a tuned config wins on the numbers the SLO gates judge.
 
 Trial reproducibility: every stochastic choice a trial makes (traffic
 mix, prompt lengths, decode lengths) is drawn from ONE
-``np.random.default_rng(seed)`` — re-running a trial with its recorded
+``np.random.default_rng(seed)``. Re-running a trial with its recorded
 seed observes the same trace, so a preempted trial re-runs instead of
 poisoning the objective with a half-measured sample.
 
-Each scenario also declares its **knob search space** (katib-style
-parameter dicts over the engine knobs it honors) and the **checked-in
-defaults** those knobs currently hold — the defaults ARE the baseline an
-experiment must beat.
+In-process trials call :func:`run_trial`; a job-mode trial's container
+runs this module (``python -m kubeflow_tpu.serving.scenarios --scenario
+<s> --seed <n> [--quick] --assignments <json>``) and the operator reads
+the trial dict from the last line of its output.
 """
 
 from __future__ import annotations
 
+import argparse
+import json
 import math
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -36,6 +32,12 @@ from dataclasses import dataclass, field
 from typing import Callable, Mapping
 
 import numpy as np
+
+from kubeflow_tpu.utils.jaxenv import (
+    device_summary,
+    place_compile_cache,
+    require_tpu,
+)
 
 
 def percentile(sorted_vals, p):
@@ -47,26 +49,6 @@ def percentile(sorted_vals, p):
     return sorted_vals[max(rank, 1) - 1]
 
 
-def decode_burst_tps(d, gen, n_thr=8, rounds=3) -> float:
-    """Decode-heavy tokens/s of ``n_thr`` concurrent full-length
-    generations, best of ``rounds`` after an untimed warm burst. Which
-    admission batch buckets the warm burst compiles depends on thread
-    arrival races, so early timed rounds can still eat a stray compile;
-    the best round is the steady state both paths are compared at."""
-    def one(i):
-        return len(d.submit([3 + (i % 7)] * 8, gen).result()["tokens"])
-
-    with ThreadPoolExecutor(n_thr) as pool:
-        list(pool.map(one, range(n_thr)))  # warm the common buckets
-    best = 0.0
-    for _ in range(rounds):
-        t0 = time.perf_counter()
-        with ThreadPoolExecutor(n_thr) as pool:
-            emitted = sum(pool.map(one, range(n_thr)))
-        best = max(best, emitted / (time.perf_counter() - t0))
-    return best
-
-
 # ---------------------------------------------------------------------------
 # Registry
 # ---------------------------------------------------------------------------
@@ -74,16 +56,12 @@ def decode_burst_tps(d, gen, n_thr=8, rounds=3) -> float:
 
 @dataclass(frozen=True)
 class Scenario:
-    """One named workload. ``bench`` is the CLI/CI entry (args-driven,
-    full regression gates); ``trial`` is the tuning entry (knob
-    assignments in, objective vector out). Either may be None — a
-    bench-only scenario can't be tuned, a trial-only one has no CLI
-    flag of its own (it still runs via ``--scenario <name>``)."""
+    """One named workload: ``trial`` takes knob assignments and returns
+    the objective vector."""
 
     name: str
     description: str
-    bench: Callable | None = None
-    trial: Callable | None = None
+    trial: Callable
     # Knob search space (katib-style parameter dicts) and the checked-in
     # defaults those knobs hold today — the experiment's baseline.
     parameters: list = field(default_factory=list)
@@ -111,10 +89,6 @@ def get_scenario(name: str) -> Scenario:
             f"unknown scenario {name!r}; available {sorted(_REGISTRY)}")
 
 
-def all_scenarios() -> dict[str, Scenario]:
-    return dict(_REGISTRY)
-
-
 def run_trial(name: str, assignments: Mapping | None = None, *,
               seed: int = 0, model: str = "lm-test-tiny",
               quick: bool = True) -> dict:
@@ -123,11 +97,9 @@ def run_trial(name: str, assignments: Mapping | None = None, *,
     ``objectives`` (the scrape_signals vector + throughput/KV numbers),
     ``seed``, ``assignments``, and the ThroughputBook-ingestable
     ``config``/``tokens_per_sec_per_chip`` pair."""
-    sc = get_scenario(name)
-    if sc.trial is None:
-        raise ValueError(f"scenario {name!r} has no trial entry")
-    return sc.trial(dict(assignments or {}), seed=int(seed), model=model,
-                    quick=bool(quick))
+    return get_scenario(name).trial(
+        dict(assignments or {}), seed=int(seed), model=model,
+        quick=bool(quick))
 
 
 # ---------------------------------------------------------------------------
@@ -277,27 +249,6 @@ def _decode_tps_trial(assignments: dict, *, seed: int = 0,
     }
 
 
-def _decode_tps_bench(args, model) -> dict:
-    """CLI entry: the trial at the checked-in defaults, reported in the
-    bench_serving artifact contract."""
-    res = _decode_tps_trial({}, seed=getattr(args, "seed", 0), model=model,
-                            quick=args.quick)
-    obj = res["objectives"]
-    return {
-        "metric": "serving_decode_tps_trial_tokens_per_sec",
-        "value": obj["tokens_per_sec"],
-        "unit": "tokens/s",
-        "vs_baseline": 1.0,
-        "ttft_p99_ms": round(obj["ttft_p99_s"] * 1e3, 2),
-        "queue_wait_p99_ms": round(obj["queue_wait_p99_s"] * 1e3, 2),
-        "kv_bytes_peak": obj["kv_bytes_peak"],
-        "kv_blocks_in_use_after_drain":
-            obj["kv_blocks_in_use_after_drain"],
-        "regression": obj["kv_blocks_in_use_after_drain"] != 0,
-        "config": res["config"],
-    }
-
-
 # ---------------------------------------------------------------------------
 # synthetic-knobs: closed-form trial for CI sweeps and policy tests
 # ---------------------------------------------------------------------------
@@ -349,271 +300,6 @@ def _synthetic_trial(assignments: dict, *, seed: int = 0,
 
 
 # ---------------------------------------------------------------------------
-# Scenario implementations shared with bench_serving.py
-# ---------------------------------------------------------------------------
-
-
-def bench_prefix_reuse(args, model) -> dict:
-    """Prefix-reuse scenario: N concurrent requests sharing an S-token
-    system prompt, decoded greedily through the continuous decoder with
-    the prefix cache ON vs OFF. Reports TTFT, prefill dispatch/token
-    volume, and the cache counters; emitted tokens must be identical
-    both ways (``regression`` flags a mismatch or a <2x volume win)."""
-    import jax
-
-    from kubeflow_tpu.models.registry import get_model
-    from kubeflow_tpu.serving.continuous import ContinuousDecoder
-
-    spec = get_model(model)
-    params = spec.init(jax.random.PRNGKey(0), spec.config)
-    n = 16 if args.quick else max(16, args.requests // 8)
-    gen = min(args.max_new_tokens, 8)
-    system = list(range(3, 3 + args.prefix_len))  # the shared prefix
-    prompts = [system + [200 + i, 17, 11 + (i % 5)] for i in range(n)]
-    prefill_len = max(args.seq_len, args.prefix_len + 8)
-
-    runs = {}
-    for label, cache_slots in (("off", 0), ("on", 8)):
-        d = ContinuousDecoder(
-            params, spec.config, slots=8, prefill_len=prefill_len,
-            max_new_tokens=gen, prefix_cache_slots=cache_slots,
-            prefix_cache_min_len=16, prefill_len_buckets=3)
-        try:
-            if cache_slots:
-                # Preload the shared system prompt (what a production
-                # deployment does at startup) so every request hits.
-                d.prime_prefix(system)
-            # Warm the compiled admission shapes outside the timed burst.
-            d.generate(prompts[0][:4], 1)
-
-            def one(p):
-                h = d.submit(p, gen)
-                res = h.result(timeout=300)
-                return res["tokens"], h.ttft_s * 1e3
-            with ThreadPoolExecutor(args.concurrency) as pool:
-                results = list(pool.map(one, prompts))
-            m = d.metrics()
-        finally:
-            d.stop()
-        runs[label] = {
-            "tokens": [t for t, _ in results],
-            "ttft_p50_ms": round(percentile(
-                sorted(ms for _, ms in results), 50), 2),
-            "prefill_dispatches": m["prefill_dispatches"],
-            "prefill_tokens": m["prefill_tokens"],
-            "prefix_hits": m["prefix_hits"],
-            "prefix_tokens_reused": m["prefix_tokens_reused"],
-        }
-
-    identical = runs["on"]["tokens"] == runs["off"]["tokens"]
-    ratio = runs["off"]["prefill_tokens"] / max(
-        runs["on"]["prefill_tokens"], 1)
-    return {
-        "metric": "serving_prefix_reuse_ttft_p50_ms",
-        "value": runs["on"]["ttft_p50_ms"],
-        "unit": "ms",
-        "vs_baseline": 1.0,
-        "ttft_off_p50_ms": runs["off"]["ttft_p50_ms"],
-        "prefill_tokens_off": runs["off"]["prefill_tokens"],
-        "prefill_tokens_on": runs["on"]["prefill_tokens"],
-        "prefill_volume_ratio": round(ratio, 2),
-        "prefill_dispatches_off": runs["off"]["prefill_dispatches"],
-        "prefill_dispatches": runs["on"]["prefill_dispatches"],
-        "prefix_hits": runs["on"]["prefix_hits"],
-        "prefix_tokens_reused": runs["on"]["prefix_tokens_reused"],
-        "tokens_identical": identical,
-        "regression": (not identical) or ratio < 2.0,
-        "config": f"{model} prefix{args.prefix_len} n{n} gen{gen} "
-                  f"prefill{prefill_len} c{args.concurrency}",
-    }
-
-
-def bench_speculative(args, model) -> dict:
-    """Speculative-decoding scenario: N concurrent greedy requests through
-    the continuous decoder with speculation off / n-gram / draft-model.
-    Tokens must be byte-identical in every mode (speculation may only
-    change cost); the draft-model run (same weights, so acceptance is
-    structural, not luck) must clear >1.5 accepted tokens per verify
-    dispatch — the dispatch economy that motivates the feature."""
-    import jax
-
-    from kubeflow_tpu.models.registry import get_model
-    from kubeflow_tpu.serving.continuous import ContinuousDecoder
-
-    spec = get_model(model)
-    params = spec.init(jax.random.PRNGKey(0), spec.config)
-    n = 8 if args.quick else max(8, args.requests // 16)
-    gen = min(args.max_new_tokens, 16)
-    k = args.speculative_k
-    # Mildly repetitive prompts: gives the n-gram proposer something to
-    # find without rigging the model's own continuations.
-    prompts = [([3 + i, 17, 29, 3 + i, 17] * 3)[:12] for i in range(n)]
-
-    runs = {}
-    modes = (("off", {}),
-             ("ngram", {"speculative_k": k, "draft_mode": "ngram"}),
-             ("draft_model", {"speculative_k": k,
-                              "draft_mode": f"model:{model}"}))
-    for label, kw in modes:
-        d = ContinuousDecoder(params, spec.config, slots=8, prefill_len=32,
-                              max_new_tokens=gen, **kw)
-        try:
-            d.generate(prompts[0][:4], 1)  # warm the compiled shapes
-
-            def one(p):
-                h = d.submit(p, gen)
-                return h.result(timeout=300)["tokens"]
-            t0 = time.perf_counter()
-            with ThreadPoolExecutor(args.concurrency) as pool:
-                tokens = list(pool.map(one, prompts))
-            wall = time.perf_counter() - t0
-            m = d.metrics()
-        finally:
-            d.stop()
-        runs[label] = {
-            "tokens": tokens,
-            "wall_s": wall,
-            "decode_dispatches": m["decode_dispatches"],
-            "spec_drafted_tokens": m["spec_drafted_tokens"],
-            "spec_accepted_tokens": m["spec_accepted_tokens"],
-            "spec_verify_dispatches": m["spec_verify_dispatches"],
-            "spec_draft_dispatches": m["spec_draft_dispatches"],
-            "spec_acceptance_rate": round(m["spec_acceptance_rate"], 3),
-        }
-
-    identical = (runs["ngram"]["tokens"] == runs["off"]["tokens"]
-                 and runs["draft_model"]["tokens"] == runs["off"]["tokens"])
-    dm = runs["draft_model"]
-    accepted_per_dispatch = (dm["spec_accepted_tokens"]
-                             / max(dm["spec_verify_dispatches"], 1))
-    return {
-        "metric": "serving_spec_accepted_tokens_per_dispatch",
-        "value": round(accepted_per_dispatch, 2),
-        "unit": "tokens/dispatch",
-        "vs_baseline": 1.0,
-        "acceptance_rate": dm["spec_acceptance_rate"],
-        "ngram_acceptance_rate": runs["ngram"]["spec_acceptance_rate"],
-        "ngram_accepted_tokens": runs["ngram"]["spec_accepted_tokens"],
-        "drafted_tokens": dm["spec_drafted_tokens"],
-        "accepted_tokens": dm["spec_accepted_tokens"],
-        "verify_dispatches": dm["spec_verify_dispatches"],
-        "draft_dispatches": dm["spec_draft_dispatches"],
-        "decode_dispatches_off": runs["off"]["decode_dispatches"],
-        "decode_dispatches_on": dm["decode_dispatches"],
-        "tokens_identical": identical,
-        "regression": (not identical) or accepted_per_dispatch <= 1.5,
-        "config": f"{model} k{k} n{n} gen{gen} c{args.concurrency}",
-    }
-
-
-def bench_concurrency_sweep(args, model) -> dict:
-    """Dense vs paged KV at EQUAL total pool bytes under an offered-
-    concurrency ladder of mixed-length greedy requests.
-
-    The dense decoder reserves ``slots * total_len`` positions, so its
-    in-flight ceiling is ``slots`` no matter how short the requests are.
-    The paged decoder gets the SAME pool bytes (``slots * total_len /
-    block_size`` blocks) but 4x the slots: admission is bounded by
-    tokens resident, so the mixed-length load packs more concurrent
-    requests into the identical HBM budget. A sequential probe pins
-    byte-identical greedy outputs between layouts; the regression marker
-    fires on divergence, on a paged in-flight peak below 2x dense, or on
-    leaked blocks after drain."""
-    import jax
-
-    from kubeflow_tpu.models.registry import get_model
-    from kubeflow_tpu.serving.continuous import ContinuousDecoder
-
-    spec = get_model(model)
-    params = spec.init(jax.random.PRNGKey(0), spec.config)
-    gen = min(args.max_new_tokens, 16)
-    prefill_len = 32
-    block = 8
-    total = prefill_len + gen
-    dense_slots = 4
-    pool_blocks = dense_slots * (total // block)  # equal KV bytes
-    cfg = spec.config
-    bytes_per_token = (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
-                       * np.dtype(cfg.dtype).itemsize)
-    ladder = [4, 16] if args.quick else [4, 16, 64]
-
-    def request(i):
-        plen = (4, 6, 8, 10)[i % 4]
-        want = (2, 3, 4, gen // 2)[i % 4]
-        return [3 + (i % 7)] * plen, want
-
-    probes = [[1, 2, 3], [7, 5], [9, 9, 9, 9, 2]]
-    runs = {}
-    for layout in ("dense", "paged"):
-        kw = (dict(kv_layout="paged", kv_block_size=block,
-                   kv_pool_blocks=pool_blocks)
-              if layout == "paged" else {})
-        slots = dense_slots * 4 if layout == "paged" else dense_slots
-        d = ContinuousDecoder(params, spec.config, slots=slots,
-                              prefill_len=prefill_len, max_new_tokens=gen,
-                              prefill_len_buckets=2,
-                              stream_timeout_s=300.0, **kw)
-        try:
-            # Sequential parity probe (also warms compiled shapes):
-            # layout must never change tokens.
-            probe_out = [d.generate(p, 4)["tokens"] for p in probes]
-            levels = {}
-            for n in ladder:
-                t0 = time.perf_counter()
-
-                def one(i):
-                    toks, want = request(i)
-                    return len(d.submit(toks, want).result()["tokens"])
-                with ThreadPoolExecutor(n) as pool:
-                    emitted = sum(pool.map(one, range(n)))
-                wall = time.perf_counter() - t0
-                levels[n] = round(emitted / wall, 1)
-            m = d.metrics()
-        finally:
-            d.stop()
-        runs[layout] = {
-            "tokens": probe_out,
-            "levels": levels,
-            "peak_in_flight": m["peak_in_flight"],
-            "kv_blocks_peak": m["kv_blocks_peak"],
-            "kv_blocks_in_use": m["kv_blocks_in_use"],
-            "defer_admissions": m["kv_defer_admissions"],
-            "kv_peak_bytes": (
-                m["kv_blocks_peak"] * block * bytes_per_token
-                if layout == "paged"
-                else slots * total * bytes_per_token),
-        }
-
-    identical = runs["paged"]["tokens"] == runs["dense"]["tokens"]
-    leak = runs["paged"]["kv_blocks_in_use"]
-    dense_peak = runs["dense"]["peak_in_flight"]
-    paged_peak = runs["paged"]["peak_in_flight"]
-    top = ladder[-1]
-    return {
-        "metric": "serving_paged_peak_in_flight",
-        "value": paged_peak,
-        "unit": "requests",
-        "vs_baseline": 1.0,
-        "dense_peak_in_flight": dense_peak,
-        "concurrency_ratio": round(paged_peak / max(dense_peak, 1), 2),
-        "tokens_per_sec_dense": runs["dense"]["levels"],
-        "tokens_per_sec_paged": runs["paged"]["levels"],
-        "pool_bytes": pool_blocks * block * bytes_per_token,
-        "kv_peak_bytes_dense": runs["dense"]["kv_peak_bytes"],
-        "kv_peak_bytes_paged": runs["paged"]["kv_peak_bytes"],
-        "defer_admissions": runs["paged"]["defer_admissions"],
-        "kv_blocks_in_use_after_drain": leak,
-        "tokens_identical": identical,
-        "regression": ((not identical) or leak != 0
-                       or paged_peak < 2 * dense_peak),
-        "config": f"{model} ladder{ladder} gen{gen} "
-                  f"prefill{prefill_len} block{block} "
-                  f"pool{pool_blocks} slots{dense_slots}v"
-                  f"{dense_slots * 4} top{top}",
-    }
-
-
-# ---------------------------------------------------------------------------
 # Registrations
 # ---------------------------------------------------------------------------
 
@@ -622,7 +308,6 @@ register(Scenario(
     description="Mixed-length decode throughput at a fixed KV pool "
                 "budget; knobs reapportion the pool (slots, block size, "
                 "prefill bucketing).",
-    bench=_decode_tps_bench,
     trial=_decode_tps_trial,
     parameters=DECODE_TPS_PARAMETERS,
     defaults=dict(DECODE_TPS_DEFAULTS),
@@ -642,23 +327,39 @@ register(Scenario(
     optimization="maximize",
 ))
 
-register(Scenario(
-    name="prefix-reuse",
-    description="Shared-system-prompt TTFT and prefill volume, prefix "
-                "cache on vs off (byte-identical tokens required).",
-    bench=bench_prefix_reuse,
-))
 
-register(Scenario(
-    name="speculative",
-    description="Speculative decoding off / n-gram / draft-model: "
-                "acceptance economy at byte-identical greedy tokens.",
-    bench=bench_speculative,
-))
+# ---------------------------------------------------------------------------
+# The job-mode trial's entry point
+# ---------------------------------------------------------------------------
 
-register(Scenario(
-    name="concurrency-sweep",
-    description="Dense vs paged KV at equal pool bytes under an "
-                "offered-concurrency ladder.",
-    bench=bench_concurrency_sweep,
-))
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description="Run one trial of a scenario.")
+    ap.add_argument("--scenario", required=True)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="threads through the scenario's traffic "
+                         "generation, so a re-run observes the same trace")
+    ap.add_argument("--quick", action="store_true",
+                    help="the tiny preset on whatever backend JAX finds")
+    ap.add_argument("--assignments", default="",
+                    help="JSON knob assignments; empty = the checked-in "
+                         "defaults")
+    args = ap.parse_args(argv)
+
+    # --quick is the tiny preset wherever JAX runs (a smoke of the
+    # plumbing); anything else is a measurement and needs the TPU. It
+    # never substitutes the CPU.
+    place_compile_cache()
+    device = device_summary() if args.quick else require_tpu()
+    result = run_trial(
+        args.scenario,
+        json.loads(args.assignments) if args.assignments else {},
+        seed=args.seed, model="lm-test-tiny" if args.quick else "llama-1b",
+        quick=args.quick)
+    result["device"] = device
+    print(json.dumps(result))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -398,7 +398,7 @@ def _train(cfg, info, model, mesh, opt_cfg, state, start_step, ckpt,
         # Input-stall accounting: fraction of wall time the loop sat
         # blocked on input, mean per-step host wait, and the step-time
         # EMA — the numbers that make the overlap win gated, not
-        # asserted (bench.py train_input_stall_pct).
+        # asserted (tests/test_input_pipeline.py reads them).
         "input_stall_pct": round(
             100.0 * host_wait_total / max(total_time, 1e-9), 2),
         "host_wait_ms_per_step": round(
@@ -410,8 +410,8 @@ def _train(cfg, info, model, mesh, opt_cfg, state, start_step, ckpt,
         "accum_steps": cfg.accum_steps,
         # Elastic reshard timeline: one event per live remap (direction,
         # devices, remap seconds, full downtime incl. drain + stream
-        # re-anchor) — the Timeline-style record dashboards and the
-        # run_elastic bench read.
+        # re-anchor) — the Timeline-style record dashboards and
+        # tests/test_elastic.py read.
         "devices": int(mesh.devices.size),
         "reshard_count": len(reshards),
         "reshards": reshards,

@@ -20,15 +20,15 @@ self-training degenerate case of policy gradient with unit reward).
 The loop's value is the SYSTEMS contract it exercises end-to-end:
 rollout generation, prefetched consumption, periodic zero-drain weight
 push, bounded version skew (``weights_max_lag``), and the
-restart-per-update baseline it must beat
-(``bench_serving.py --weight-push-sweep`` gates the ratio).
+restart-per-update baseline it is compared with (the ratio of the two
+has not been measured on the chip; tests/test_rl.py runs the push path).
 
 ``restart_per_update=True`` runs that baseline: instead of pushing,
 every update STOPS the actor fleet, drops the compiled executables
 (a real restart pays recompilation — ``jax.clear_caches`` is the
 in-process stand-in), and rebuilds the decoders from scratch on the
-new params — the kill-restore path the elastic bench measured at ~3.1s
-against ~124ms for a live remap.
+new params: the kill-restore path, which pays a recompilation that a
+live remap does not.
 """
 
 from __future__ import annotations
@@ -71,7 +71,7 @@ class RLConfig:
     kv_block_size: int = 8
     seed: int = 0
     temperature: float = 0.0
-    # Baseline mode for the bench: tear actors down and rebuild them on
+    # The restart baseline: tear actors down and rebuild them on
     # every update instead of live-pushing.
     restart_per_update: bool = False
 

@@ -1,7 +1,7 @@
 """What the chip bring-up added, as far as a CPU can check it: the smoke
 refuses to run without a TPU, the compile cache is placed from outside,
-the bench parents stay off the backend and fail with their children, and
-a warm that failed says so."""
+a trial that is a measurement refuses to run without one, and a warm that
+failed says so."""
 
 from __future__ import annotations
 
@@ -21,6 +21,7 @@ from kubeflow_tpu.models.registry import get_model
 from kubeflow_tpu.serving.compile_cache import CompileCache
 from kubeflow_tpu.serving.continuous import ContinuousDecoder
 from kubeflow_tpu.serving.engine import EngineConfig
+from kubeflow_tpu.serving.scenarios import run_trial
 from kubeflow_tpu.serving.server import ModelServer
 from kubeflow_tpu.utils import jaxenv
 
@@ -148,62 +149,34 @@ def test_cache_helper_defaults_to_one_fixed_path_in_the_checkout(
 
 
 # ---------------------------------------------------------------------------
-# bench.py / bench_serving.py: one process per chip, no silent CPU bench
+# The Experiment trial's entry: a measurement never substitutes the CPU
 # ---------------------------------------------------------------------------
 
 
-def test_bench_parent_never_touches_the_backend():
-    """A real (non --quick) bench.py run measures in children only: with
-    the children stubbed out, main() runs to its JSON line and the parent
-    has still not initialised any backend — on the chip, a parent that
-    had would hold it and every child would fail."""
-    driver = (
-        "import json, bench\n"
-        "from jax._src import xla_bridge\n"
-        "calls = []\n"
-        "def train(*a, **k):\n"
-        "    calls.append(('train', a, k))\n"
-        "    assert not xla_bridge.backends_are_initialized()\n"
-        "    return {'mfu': 0.5, 'device': {'platform': 'tpu'},\n"
-        "            'tokens_per_sec_per_chip': 1.0, 'params_m': 1.0,\n"
-        "            'model_tflops_per_token': 1.0, 'final_loss': 1.0,\n"
-        "            'config': 'stub', 'input_stall_pct': 0.0,\n"
-        "            'samples_per_sec': 1.0, 'loss': 1.0}\n"
-        "def serve(extra, n):\n"
-        "    calls.append(('serve', extra, n))\n"
-        "    return dict.fromkeys(['value', 'p99_ms', 'config',\n"
-        "        'ttft_p50_ms', 'p50_ms', 'lockstep_p50_ms',\n"
-        "        'continuous_vs_lockstep', 'decode_tokens_per_sec',\n"
-        "        'mixed_p50_ms', 'lockstep_mixed_p50_ms'], 1.0)\n"
-        "bench.run_training_isolated = train\n"
-        "bench.run_serving_isolated = serve\n"
-        "assert bench.main(['--steps', '1']) == 0\n"
-        "assert not xla_bridge.backends_are_initialized()\n"
-        "print(json.dumps(len(calls)))\n"
-    )
-    proc = _run([sys.executable, "-c", driver])
+def test_trial_entry_prints_what_run_trial_returns():
+    """What a job-mode trial's container runs: the last line of its
+    output is the in-process trial's dict plus the device it ran on."""
+    assignments = {"slots": 9, "kv_block_size": 12}
+    proc = _run([sys.executable, "-m", "kubeflow_tpu.serving.scenarios",
+                 "--scenario", "synthetic-knobs", "--seed", "5", "--quick",
+                 "--assignments", json.dumps(assignments)])
     assert proc.returncode == 0, proc.stderr[-2000:]
-    # flagship + accum + four deep + two pipeline + two serving children.
-    assert json.loads(proc.stdout.splitlines()[-1]) == 10
+    printed = json.loads(proc.stdout.splitlines()[-1])
+    assert printed.pop("device")["platform"] == "cpu"
+    assert printed == run_trial(
+        "synthetic-knobs", assignments, seed=5, quick=True)
 
 
-def test_bench_child_without_a_tpu_fails_the_run(capsys):
-    import bench
-
-    with pytest.raises(RuntimeError, match=r"failed \(exit 1\)"):
-        bench.run_training_isolated("lm-test-tiny", 1, 8, 1, "adamw")
-    assert "no TPU" in capsys.readouterr().err  # the child's own words
-    with pytest.raises(ValueError, match="no documented bf16 peak"):
-        bench.peak_bf16_flops("cpu")
-    assert bench.peak_bf16_flops("TPU v5 lite") == 197e12
-
-
-def test_bench_serving_measurement_mode_needs_a_tpu():
-    proc = _run([sys.executable, "bench_serving.py", "--generate",
-                 "--requests", "1"])
+def test_trial_entry_without_quick_needs_a_tpu():
+    proc = _run([sys.executable, "-m", "kubeflow_tpu.serving.scenarios",
+                 "--scenario", "synthetic-knobs", "--seed", "3",
+                 "--assignments", "{}"])
     assert proc.returncode != 0
     assert "no TPU" in proc.stderr
-    assert "lm-test-tiny" not in proc.stdout  # no tiny-model stand-in ran
+    # No trial ran in its place: not the tiny preset, not even the
+    # closed-form scenario that needs no device.
+    assert "lm-test-tiny" not in proc.stdout
+    assert "objectives" not in proc.stdout
 
 
 # ---------------------------------------------------------------------------

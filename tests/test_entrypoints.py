@@ -1,16 +1,19 @@
 """Every `python -m kubeflow_tpu.X` command the manifest layer renders must
 be a real module whose CLI parses (the operator-image contract: the
 Deployment command is an actual binary,
-kubeflow/tf-training/tf-job-operator.libsonnet:99-143).
+kubeflow/tf-training/tf-job-operator.libsonnet:99-143). And every program
+the README and the verify notes tell a reader to run must exist.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
 
 import pytest
 
@@ -155,3 +158,24 @@ def test_rendered_args_are_accepted_by_each_parser():
             failures.append(f"{' '.join(cmd)}: rc={proc.returncode}\n"
                             f"{proc.stderr[-500:]}")
     assert not failures, "\n\n".join(failures)
+
+
+REPO = Path(__file__).resolve().parent.parent
+_FENCE = re.compile(r"^```[^\n]*\n(.*?)^```", re.M | re.S)
+_RUN = re.compile(r"\bpython3?\s+(-m\s+)?([\w./-]+)")
+
+
+@pytest.mark.parametrize("doc", ["README.md", ".claude/skills/verify/SKILL.md"])
+def test_documented_commands_name_programs_that_exist(doc):
+    """Each `python <file>.py` and `python -m <module>` inside a fenced
+    block names a file of the repo or an importable module: a reader is
+    never sent to a program that was deleted."""
+    named = [(bool(m.group(1)), m.group(2))
+             for block in _FENCE.findall((REPO / doc).read_text())
+             for m in _RUN.finditer(block)
+             if m.group(1) or m.group(2).endswith(".py")]
+    assert named, f"{doc}: no command found; did the fences change?"
+    missing = [target for is_module, target in named
+               if not (importlib.util.find_spec(target) if is_module
+                       else (REPO / target).exists())]
+    assert not missing, f"{doc} names programs that do not exist: {missing}"

@@ -29,7 +29,7 @@ from kubeflow_tpu.operators.experiment import (
     TRIAL_PRIORITY,
     ExperimentController,
 )
-from kubeflow_tpu.serving.scenarios import SYNTHETIC_DEFAULTS
+from kubeflow_tpu.serving.scenarios import SYNTHETIC_DEFAULTS, run_trial
 
 NS = "kubeflow"
 
@@ -183,12 +183,28 @@ def test_job_mode_renders_preemptible_trial_jobs(api):
     cmd = job["spec"]["replicaSpecs"]["Worker"]["template"]["spec"][
         "containers"][0]["command"]
     # The trial job replays the named scenario with the recorded seed
-    # and knob assignments through the bench CLI.
-    assert cmd[:2] == ["python", "bench_serving.py"]
+    # and knob assignments through the scenarios module.
+    assert cmd[:3] == ["python", "-m", "kubeflow_tpu.serving.scenarios"]
     assert cmd[cmd.index("--scenario") + 1] == "synthetic-knobs"
     assert cmd[cmd.index("--seed") + 1] == str(5 * 100_003)
     assert json.loads(cmd[cmd.index("--assignments") + 1]) \
         == SYNTHETIC_DEFAULTS
+
+
+def test_decode_tps_trial_drives_the_live_engine_and_drains():
+    """The one scenario that boots a ContinuousDecoder. A CPU shows its
+    counts: the block size legalized to a divisor of the row, the pool
+    held at sixteen rows whatever the knobs, every block back after the
+    drain, and the line the ThroughputBook reads."""
+    res = run_trial("decode-tps", {"slots": 8, "kv_block_size": 10},
+                    seed=3, quick=True)
+    obj = res["objectives"]
+    # 48-token rows: 10 snaps down to 8, and 16 rows are 96 blocks of 8.
+    assert res["config"] == \
+        "decode-tps slots8 block8 buckets0 pool96 n24 seed3"
+    assert obj["kv_blocks_in_use_after_drain"] == 0
+    assert obj["kv_bytes_peak"] > 0
+    assert res["tokens_per_sec_per_chip"] == obj["tokens_per_sec"] > 0
 
 
 def test_preempted_trial_reruns_same_assignments_and_seed(api):

@@ -147,8 +147,8 @@ def test_spill_under_pressure_is_deterministic_least_loaded():
 def test_affinity_concentrates_groups_vs_random_routing():
     """Prefix-affine placement sends a whole shared-prefix group to ONE
     replica; seeded-random routing spreads it — the trie-concentration
-    property the fleet bench gates with real decoders, pinned here on
-    the placement alone."""
+    property, pinned here on the placement alone and on real decoders by
+    test_routing_moves_prefix_hits_never_tokens."""
     groups = {g: [[g, g + 1, g + 2, 7] + [r] for r in range(8)]
               for g in range(20)}
     affine = DecoderFleet({f"r{i}": _StubReplica() for i in range(4)},
@@ -242,6 +242,41 @@ def _decoder(tiny, **kw):
     kw.setdefault("kv_block_size", 8)
     kw.setdefault("stream_timeout_s", 60.0)
     return ContinuousDecoder(params, spec.config, **kw)
+
+
+def test_routing_moves_prefix_hits_never_tokens(tiny):
+    """Six groups of four prompts, each group sharing a 12-token prefix,
+    served one at a time by three real replicas: whichever replica the
+    router picks, tokens equal a lone cache-less decoder's; prefix-affine
+    placement keeps a group on one trie and hits strictly more often than
+    seeded-random placement; no slot still holds a block afterwards."""
+    prompts = [[(g * 7 + j) % 97 + 3 for j in range(12)] + [200 + g, 11 + r]
+               for g in range(6) for r in range(4)]
+    kw = dict(max_new_tokens=8)
+    lone = _decoder(tiny, **kw)
+    try:
+        want = [lone.generate(p, 4, timeout=60)["tokens"] for p in prompts]
+    finally:
+        lone.stop()
+
+    def hits(router):
+        reps = {f"r{i}": _decoder(tiny, prefix_cache_slots=8,
+                                  prefix_cache_min_len=8, **kw)
+                for i in range(3)}
+        fleet = DecoderFleet(reps, affinity_tokens=12, router=router, seed=7)
+        try:
+            got = [fleet.generate(p, 4, timeout=60)["tokens"]
+                   for p in prompts]
+            assert got == want, router
+            for nm, d in reps.items():
+                assert all(not b for b in d._slot_blocks), (router, nm)
+            return sum(d.metrics()["prefix_hits"] for d in reps.values())
+        finally:
+            fleet.stop()
+
+    affine = hits("affine")
+    assert affine == 6 * 3  # every follower of every group
+    assert affine > hits("random")
 
 
 def test_replica_kill_mid_stream_fails_fast_and_remaps(tiny):

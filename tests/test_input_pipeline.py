@@ -1,7 +1,7 @@
 """Overlapped input pipeline + gradient-accumulation microbatching tests:
 byte-identical batch order (incl. resume), accum loss/grad parity with the
 equivalent single large batch, prefetcher shutdown on every exit path, and
-the stall accounting the bench gate reads."""
+the stall accounting the loop's result carries."""
 
 import threading
 import time

@@ -206,6 +206,9 @@ def test_peer_import_byte_identical_and_saves_prefill(model):
         held = mb["kv_blocks_in_use"]
         b.generate(p2, 6, timeout=120)
         assert b.metrics()["kv_blocks_in_use"] == held
+        # Exporter and importer alike: what is still held is the tries'.
+        for rep in (a, b):
+            assert all(not blks for blks in rep._slot_blocks)
     finally:
         fleet.stop()
 

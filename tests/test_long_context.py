@@ -238,6 +238,29 @@ def test_tp2_byte_identity_chunked(tiny, wide_greedy):
     assert m["prefill_chunks"] > 0
 
 
+def test_decode_progresses_while_a_long_admission_chunks(tiny):
+    """A live decode stream keeps stepping between the chunks of an
+    80-token admission (nine interior chunks of 8 and the final admit):
+    the chain never holds the scheduler for its whole length, and the
+    stream's tokens equal an undisturbed run's."""
+    d = _chunked(tiny, max_new_tokens=64)
+    try:
+        stream = d.submit(SHORT, 64).tokens(timeout=120)
+        got = [next(stream)]  # live before the long prompt arrives
+        before = d.metrics()
+        first = next(iter(d.submit(LONG, 4).tokens(timeout=120)))
+        during = d.metrics()
+        got.extend(stream)
+        chunks = during["prefill_chunks"] - before["prefill_chunks"]
+        assert chunks == (len(LONG) - 1) // 8
+        assert (during["decode_dispatches"]
+                - before["decode_dispatches"]) >= chunks
+        assert got == d.generate(SHORT, 64, timeout=120)["tokens"]
+        assert first == d.generate(LONG, 1, timeout=120)["tokens"][0]
+    finally:
+        d.stop()
+
+
 def test_no_leaked_blocks_after_chunked_drain(tiny):
     d = _chunked(tiny, prefix_cache_slots=4, prefix_cache_min_len=8)
     try:

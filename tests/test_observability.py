@@ -292,7 +292,7 @@ def test_decoder_timelines_close_on_finish_and_on_loop_death(model):
 
 
 def test_decoder_metrics_expose_histogram_quantiles(model):
-    """Satellite: ttft_avg_s stays (bench_serving compatibility) but
+    """Satellite: ttft_avg_s stays (dashboards read it) but
     histogram-backed p50/p90/p99 ride alongside, and the decoder's
     registry renders a lint-clean exposition."""
     spec, params = model

@@ -21,6 +21,7 @@ from kubeflow_tpu.serving.continuous import (
     _Request,
 )
 from kubeflow_tpu.serving.engine import EngineConfig
+from kubeflow_tpu.serving.kv_allocator import kv_bytes_per_token
 from kubeflow_tpu.serving.server import ModelServer
 
 
@@ -303,6 +304,55 @@ def test_memory_deferred_admission_completes_everything(model):
         d.stop()
 
 
+def _drained_metrics(model, offered, **kw):
+    """Offer ``offered`` two-block requests at once; metrics after all
+    of them finished."""
+    d = _decoder(model, max_new_tokens=16, stream_timeout_s=300.0, **kw)
+    try:
+        handles = [d.submit([3 + i % 7] * 6, 10) for i in range(offered)]
+        assert all(len(h.result(timeout=300)["tokens"]) == 10
+                   for h in handles)
+        return d.metrics()
+    finally:
+        d.stop()
+
+
+@pytest.mark.parametrize("kv_dtype, at_least", [("fp", 2.0), ("int8", 1.8)])
+def test_in_flight_peak_at_equal_pool_bytes(model, kv_dtype, at_least):
+    """Admission is bounded by tokens resident, not by rows. The bytes of
+    four dense 48-token rows, cut into blocks of 8, hold at least twice
+    as many 16-token requests in flight as the dense layout's four; the
+    same bytes as int8 blocks hold at least 1.8x the fp blocks' count."""
+    fp_blocks = 4 * (32 + 16) // 8
+    fp_kw = dict(kv_layout="paged", kv_block_size=8,
+                 kv_pool_blocks=fp_blocks)
+    if kv_dtype == "fp":
+        base = _drained_metrics(model, 16, slots=4)
+        more = _drained_metrics(model, 16, slots=16, **fp_kw)
+        assert base["peak_in_flight"] == 4
+    else:
+        # One head of 64: at the preset's head_dim of 16 the scale a
+        # position carries per head eats the density int8 buys.
+        from kubeflow_tpu.models.registry import get_model
+
+        spec = get_model("lm-test-tiny", n_heads=1, n_kv_heads=1)
+        cfg = spec.config
+        wide = spec, spec.init(jax.random.PRNGKey(0), cfg)
+        bpt = {d: kv_bytes_per_token(
+            cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
+            jax.numpy.dtype(cfg.dtype).itemsize, d) for d in ("fp", "int8")}
+        base = _drained_metrics(wide, 32, slots=32, **fp_kw)
+        more = _drained_metrics(
+            wide, 32, slots=32, kv_dtype="int8",
+            **{**fp_kw,
+               "kv_pool_blocks": fp_blocks * bpt["fp"] // bpt["int8"]})
+        assert more["kv_bytes_total"] <= base["kv_bytes_total"]
+        assert base["peak_in_flight"] == fp_blocks // 2  # memory-bound
+        assert base["kv_blocks_in_use"] == 0
+    assert more["peak_in_flight"] >= at_least * base["peak_in_flight"]
+    assert more["kv_blocks_in_use"] == 0  # drained: every block freed
+
+
 def test_admission_pressure_reclaims_cached_prefix_blocks(model):
     """Cache-held blocks are reclaimable memory: when a new admission
     needs them, unpinned prefix entries are evicted rather than the
@@ -401,7 +451,7 @@ def test_paged_counters_exported_as_prometheus(model):
 
 
 def test_concurrent_same_round_prefix_hits_stay_exact(model):
-    """Regression (found by the fleet bench's shared-prefix traffic): a
+    """Regression (found under concurrent shared-prefix traffic): a
     freed slot's block-table row must stay SENTINEL until the slot's
     own admission dispatch. Pointing it at freshly shared blocks at pop
     time let an earlier same-round hit admission's fused decode step

@@ -4,13 +4,11 @@ Covers the host trie (match-through-interior-nodes, LRU eviction order,
 in-flight pins), the decoder end-to-end (cold vs warm determinism for
 greedy AND fixed-seed sampled decoding, eviction under pool pressure,
 suffix-only prefill accounting), the shared ``pow2_bucket`` rule,
-``bench_serving.percentile``'s nearest-rank fix, and the Prometheus
+``scenarios.percentile``'s nearest-rank rule, and the Prometheus
 export of the new counters.
 """
 
 import http.client
-import importlib.util
-from pathlib import Path
 
 import jax
 import pytest
@@ -19,6 +17,7 @@ from kubeflow_tpu.observability.metrics import type_line
 from kubeflow_tpu.serving.continuous import ContinuousDecoder
 from kubeflow_tpu.serving.engine import EngineConfig, pow2_bucket
 from kubeflow_tpu.serving.prefix_cache import PrefixCache
+from kubeflow_tpu.serving.scenarios import percentile
 from kubeflow_tpu.serving.server import ModelServer
 
 
@@ -53,31 +52,22 @@ def test_pow2_bucket_boundaries():
 
 
 # ---------------------------------------------------------------------------
-# bench_serving.percentile (nearest-rank fix)
+# scenarios.percentile (nearest rank)
 # ---------------------------------------------------------------------------
 
 
-def _load_bench():
-    path = Path(__file__).resolve().parent.parent / "bench_serving.py"
-    spec = importlib.util.spec_from_file_location("bench_serving", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def test_percentile_nearest_rank():
-    p = _load_bench().percentile
     # Even length: rank ceil(4*0.5)=2 -> the LOWER middle element (the
     # old int() index read one high).
-    assert p([1, 2, 3, 4], 50) == 2
-    assert p([1, 2, 3], 50) == 2
-    assert p([5], 50) == 5
-    assert p([5], 99) == 5
+    assert percentile([1, 2, 3, 4], 50) == 2
+    assert percentile([1, 2, 3], 50) == 2
+    assert percentile([5], 50) == 5
+    assert percentile([5], 99) == 5
     hundred = list(range(1, 101))
-    assert p(hundred, 50) == 50
-    assert p(hundred, 99) == 99
-    assert p(hundred, 100) == 100
-    assert p(hundred, 1) == 1
+    assert percentile(hundred, 50) == 50
+    assert percentile(hundred, 99) == 99
+    assert percentile(hundred, 100) == 100
+    assert percentile(hundred, 1) == 1
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +244,7 @@ def test_seq_bucketed_prefill_parity(model):
 
 
 def test_concurrent_shared_prefix_burst(model):
-    """The bench scenario in miniature: a burst sharing a primed system
+    """A shared-system-prompt burst: requests sharing a primed system
     prompt all hit, decode correctly, and prefill only suffixes."""
     system = list(range(5, 25))
     d = _decoder(model, prefix_cache_slots=4, prefix_cache_min_len=8)

@@ -202,6 +202,64 @@ def test_regressed_candidate_rolls_back_with_evidence(renv):
     assert _rollout(api)["phase"] == "RolledBack"
 
 
+@pytest.mark.parametrize("regress_canary, phase, reason", [
+    (False, "Promoted", None), (True, "RolledBack", "gate-breach")],
+    ids=["good-push", "bad-push"])
+def test_walk_over_real_decoders_serves_the_winner(api, regress_canary,
+                                                   phase, reason):
+    """The same two walks over a DecoderFleet of ContinuousDecoders: the
+    fleet ends on ONE epoch and its greedy tokens equal a decoder
+    cold-started on the winner's weights (the candidate after a
+    promotion; the incumbent, restored exactly, after a rollback)."""
+    import jax
+
+    from kubeflow_tpu.models.registry import get_model
+    from kubeflow_tpu.serving.continuous import ContinuousDecoder
+    from kubeflow_tpu.serving.fleet import DecoderFleet
+
+    spec = get_model("lm-test-tiny")
+    p_inc = spec.init(jax.random.PRNGKey(0), spec.config)
+    p_cand = spec.init(jax.random.PRNGKey(1), spec.config)
+    prompts = [[3 + j % 29 for j in range(10)] + [5 + i] * 4
+               for i in range(3)]
+
+    def mk(params):
+        return ContinuousDecoder(params, spec.config, slots=2,
+                                 prefill_len=16, max_new_tokens=8)
+
+    api.apply(inference_service_crd())
+    api.create(_cr(replicas=3, max_replicas=3))
+    fleet = DecoderFleet({f"llm-r{i}": mk(p_inc) for i in range(3)})
+    clock = {"t": 0.0}
+
+    def fetch(addr):
+        canaries = {f"{m}.{NS}:8500"
+                    for m in _rollout(api).get("canaryMembers", [])}
+        return dict(SLOW if regress_canary and addr in canaries else CALM)
+
+    rc = RolloutController(
+        api, fleet_for=lambda ns, n: fleet,
+        weights_for={"ckpt/v1": p_inc, "ckpt/v2": p_cand}.get,
+        fetch_metrics=fetch, clock=lambda: clock["t"])
+    try:
+        rc.reconcile_all()
+        _drive(rc, clock, 8)  # the walk and a terminal convergence pass
+        ro = _rollout(api)
+        assert ro["phase"] == phase
+        assert (ro.get("evidence") or {}).get("reason") == reason
+        installed = fleet.weights_versions()["installed"]
+        assert len({installed[m] for m in fleet.live_members()}) == 1
+        got = [fleet.generate(p, 8, timeout=120)["tokens"] for p in prompts]
+    finally:
+        fleet.stop()
+    cold = mk(p_inc if regress_canary else p_cand)
+    try:
+        assert got == [cold.generate(p, 8, timeout=120)["tokens"]
+                       for p in prompts]
+    finally:
+        cold.stop()
+
+
 def test_error_rate_gate_breaches(renv):
     api, rc, ic, fleet, clock, sig = renv
     api.create(_cr())

@@ -172,8 +172,8 @@ def test_throughput_book_prefers_measured_faster_pool():
 def test_throughput_book_from_bench_files(tmp_path):
     """Profiles load from bench-format files: the config's leading token
     names the profile, tokens/s/chip is the throughput the Gavel scoring
-    normalizes. The file is SYNTHETIC — round numbers in bench.py's
-    output shape, measured nowhere."""
+    normalizes. The file is SYNTHETIC — round numbers in the shape a
+    trial prints, measured nowhere."""
     bench_file = tmp_path / "synthetic_bench.json"
     bench_file.write_text(json.dumps({"parsed": {
         "config": "flagship-1b bs4 seq2048 adafactor bf16 x1chip",
@@ -1097,19 +1097,22 @@ def _wait_for(predicate, timeout=30.0, interval=0.05, message="condition"):
     raise AssertionError(f"timed out waiting for {message}")
 
 
-def test_kubelet_evict_honors_pod_termination_grace(api):
+def test_kubelet_evict_honors_pod_termination_grace(api, tmp_path):
     """SIGTERM is delivered and the pod's own
     terminationGracePeriodSeconds bounds the window before SIGKILL: a
     graceful pod exits 0 inside it; a stubborn pod is killed at it."""
+    # Each program says, by creating its file, that its handler is in:
+    # a SIGTERM before that would kill the graceful pod by signal.
+    ready = {n: tmp_path / f"{n}.ready" for n in ("graceful", "stubborn")}
     graceful = ("import signal, sys, time\n"
                 "signal.signal(signal.SIGTERM,"
                 " lambda *a: (print('sigterm-handled', flush=True),"
                 " sys.exit(0)))\n"
-                "print('ready', flush=True)\n"
+                f"open({str(ready['graceful'])!r}, 'w').close()\n"
                 "time.sleep(120)\n")
     stubborn = ("import signal, time\n"
                 "signal.signal(signal.SIGTERM, signal.SIG_IGN)\n"
-                "print('ready', flush=True)\n"
+                f"open({str(ready['stubborn'])!r}, 'w').close()\n"
                 "time.sleep(120)\n")
     for name, prog, grace in (("graceful", graceful, 30),
                               ("stubborn", stubborn, 1)):
@@ -1125,12 +1128,9 @@ def test_kubelet_evict_honors_pod_termination_grace(api):
     try:
         kubelet.step()
         _wait_for(lambda: all(
-            "ready" not in (api.get("v1", "Pod", n, NS)["status"]
-                            .get("log") or "")
-            and api.get("v1", "Pod", n, NS)["status"].get("phase")
-            == "Running"
-            for n in ("graceful", "stubborn")), message="pods running")
-        time.sleep(0.3)  # let both processes print "ready"
+            api.get("v1", "Pod", n, NS)["status"].get("phase") == "Running"
+            and ready[n].exists() for n in ready),
+            message="pods running with their handlers installed")
 
         t0 = time.monotonic()
         assert kubelet.evict("graceful", NS)  # grace from the pod spec

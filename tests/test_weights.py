@@ -215,6 +215,8 @@ def test_streams_straddle_swap_without_disruption():
         d.update_weights(P1)  # same weights, new epoch
         for th in threads:
             th.join(timeout=120)
+        # The swap leaked nothing: no slot still holds a block.
+        assert all(not blks for blks in d._slot_blocks)
     finally:
         d.stop()
     assert sorted(results) == [0, 1, 2]
