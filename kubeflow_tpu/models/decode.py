@@ -65,7 +65,10 @@ _NEG_INF = -1e30
 
 
 def init_cache(cfg: TransformerConfig, batch: int, total_len: int):
-    """Per-layer K/V cache, stacked on a leading layer dim like the params."""
+    """K/V cache ``[L, batch, total_len, Hkv, hd]``, stacked on a leading
+    layer dim like the params. The decode state's cache is carried whole
+    through the layer loop and written in place at ``[layer, ...]``
+    (:func:`_layer_loop` says why it is not scanned layer by layer)."""
     if cfg.mixer_types:
         raise ValueError(
             "mixer_types: the dense KV cache (and the lockstep generate "
@@ -99,14 +102,8 @@ def _gqa_attention(q, k_cache, v_cache, mask, cfg):
 def _cached_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos, valid):
     """x: [B, S, D] at cache slots pos..pos+S; attends over the full cache
     masked by ``valid`` [B, total]. Returns (out, k_cache, v_cache)."""
-    b, s, _d = x.shape
-    hd = cfg.head_dim
-    cos, sin = rope_bt  # [B, S, hd//2] gathered per row by the caller
-    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = _rope(q, cos, sin)
-    k = _rope(k, cos, sin)
+    s = x.shape[1]
+    q, k, v = _qkv_rope(x, layer, cfg, rope_bt)
     k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
     v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
 
@@ -127,6 +124,19 @@ def _rope(x, cos, sin):
     return jnp.concatenate(
         [x1 * c - x2 * s, x1 * s + x2 * c], axis=-1
     ).astype(x.dtype)
+
+
+def _qkv_rope(x, layer, cfg, rope_bt):
+    """x [B, S, D] → rotary-embedded q [B, S, H, hd], k and v
+    [B, S, Hkv, hd]; ``rope_bt`` is (cos, sin) [B, S, hd//2] gathered per
+    row by the caller, which also holds the ``attn`` scope."""
+    b, s, _d = x.shape
+    hd = cfg.head_dim
+    cos, sin = rope_bt
+    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
+    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    return _rope(q, cos, sin), _rope(k, cos, sin), v
 
 
 def _embed(params, tokens, cfg):
@@ -268,6 +278,17 @@ def generate(params, prompt_tokens, prompt_lengths, cfg: TransformerConfig,
 # pieces (callers that need the row cache itself). Unlike ``generate``'s
 # shared scalar ``pos``, rows here sit at *different* sequence positions,
 # so the cache write and attention mask are per-row.
+#
+# The persistent K/V storage (dense cache or paged pool) is CARRIED WHOLE
+# through the layer loop (``_layer_loop``): each layer scatters its token
+# at ``[layer, ...]`` of the whole array and attends over ``store[layer]``
+# read where it lies, so a donated state is updated in place. It is
+# deliberately not the scan's ``xs``/``ys``: a scan cannot alias an input
+# it slices with the output it stacks, so XLA keeps a second whole store,
+# slices each layer out of the first, writes the slice into the second
+# and copies one over the other every step (0.6 GB of temporaries and
+# 2.5 ms of an 8.1 ms step at 6 layers x 32 slots x 768 on a v5e, dense
+# and paged alike; tests/test_tpu_compile.py holds the compile to it).
 
 
 def _kv_arr(pool):
@@ -290,101 +311,130 @@ def _quantize_kv(vals):
     return {"q": q.astype(jnp.int8), "scale": scale}
 
 
-def _pool_gather(pool, table):
-    """Read a layer's block pool ``[N, Bs, H, hd]`` through block table
-    ``[B, MB]`` into virtual rows ``[B, MB*Bs, H, hd]`` — virtual position
-    ``p`` of row ``b`` lives at block ``table[b, p // Bs]``, offset
-    ``p % Bs``. Sentinel entries (``>= N``, the unallocated marker) clamp
-    to the last block; the junk they surface sits in positions the
-    validity mask already excludes, so it contributes exact zeros.
-    Quantized pools dequantize after the gather (this materialized path
-    is the reference; the fused kernel dequantizes in-register)."""
+def _pool_gather(pool, layer, table):
+    """Read layer ``layer`` of the whole block pool ``[L, N, Bs, H, hd]``
+    through block table ``[B, MB]`` into virtual rows ``[B, MB*Bs, H, hd]``
+    — virtual position ``p`` of row ``b`` lives at block
+    ``table[b, p // Bs]``, offset ``p % Bs``. The layer rides the gather's
+    index (``layer`` may be traced), so no layer is sliced out first.
+    Sentinel entries (``>= N``, the unallocated marker) clamp to the last
+    block; the junk they surface sits in positions the validity mask
+    already excludes, so it contributes exact zeros. Quantized pools
+    dequantize after the gather (this materialized path is the reference;
+    the fused kernel dequantizes in-register)."""
+    b = table.shape[0]
     if isinstance(pool, dict):
-        b = table.shape[0]
-        h = pool["scale"].shape[2]
-        hd = pool["q"].shape[3]
-        q = pool["q"][table].reshape(b, -1, h, hd).astype(jnp.float32)
-        s = pool["scale"][table].reshape(b, -1, h)
+        h, hd = pool["q"].shape[3:]
+        q = pool["q"][layer, table].reshape(b, -1, h, hd).astype(jnp.float32)
+        s = pool["scale"][layer, table].reshape(b, -1, h)
         return q * s[..., None]
-    _n, _bs, h, hd = pool.shape
-    return pool[table].reshape(table.shape[0], -1, h, hd)
+    h, hd = pool.shape[3:]
+    return pool[layer, table].reshape(b, -1, h, hd)
 
 
-def _pool_write(pool, table, cols, vals):
-    """Scatter ``vals`` [B, S, H, hd] at per-row virtual positions
-    ``cols`` [B, S] through the block table. Out-of-range cols (rows
-    parked at ``total``) and sentinel table entries resolve to a
-    physical index past the pool, which scatter semantics drop — the
-    paged twin of the dense path's parked-row no-op write. Quantized
-    pools abs-max-quantize at scatter time: each written position's int8
-    codes and per-head scale land together, so a block's payload and its
-    scales can never drift apart."""
+def _pool_write(pool, layer, table, cols, vals):
+    """Scatter ``vals`` [B, S, H, hd] into layer ``layer`` of the whole
+    pool ``[L, N, Bs, H, hd]`` at per-row virtual positions ``cols``
+    [B, S] through the block table — one in-place scatter on the whole
+    array. Out-of-range cols (rows parked at ``total``) and sentinel
+    table entries resolve to a physical index past the pool, which
+    scatter semantics drop — the paged twin of the dense path's
+    parked-row no-op write. Quantized pools abs-max-quantize at scatter
+    time: each written position's int8 codes and per-head scale land
+    together, so a block's payload and its scales can never drift
+    apart."""
     arr = _kv_arr(pool)
-    n, bs = arr.shape[0], arr.shape[1]
+    n, bs = arr.shape[1], arr.shape[2]
     mb = table.shape[1]
     blk = jnp.take_along_axis(table, jnp.clip(cols // bs, 0, mb - 1), axis=1)
     blk = jnp.where((cols >= 0) & (cols < mb * bs), blk, n)
     if isinstance(pool, dict):
         qd = _quantize_kv(vals)
-        return {"q": pool["q"].at[blk, cols % bs].set(qd["q"]),
-                "scale": pool["scale"].at[blk, cols % bs].set(qd["scale"])}
-    return pool.at[blk, cols % bs].set(vals)
+        return {"q": pool["q"].at[layer, blk, cols % bs].set(qd["q"]),
+                "scale": pool["scale"].at[layer, blk, cols % bs].set(
+                    qd["scale"])}
+    return pool.at[layer, blk, cols % bs].set(vals)
+
+
+def _layer_of(store, layer):
+    """Layer ``layer`` (traced) of a whole K/V store, as a dynamic index
+    XLA fuses into the consumer; a quantized pool yields both leaves."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        store)
+
+
+def _store_write(store, layer, table, cols, vals):
+    """Write ``vals`` [B, S, Hkv, hd] at ``[layer, row, cols[row]]`` of
+    the WHOLE K/V store, in place: the dense cache ``[L, B, T, Hkv, hd]``
+    (``table`` None) or the paged pool through ``table``. Out-of-bounds
+    cols (a retired row parked at ``total``, a span's spill past the
+    cache tail) are dropped by scatter semantics — they write nowhere."""
+    if table is None:
+        rows = jnp.arange(vals.shape[0])[:, None]
+        return store.at[layer, rows, cols].set(vals)
+    return _pool_write(store, layer, table, cols, vals)
+
+
+def _store_rows(store, layer, table):
+    """The rows ``[B, T, Hkv, hd]`` layer ``layer`` attends over, read
+    from where they lie in the whole store: a dynamic index of the dense
+    cache, a gather through ``table`` of the pool."""
+    if table is None:
+        return _layer_of(store, layer)
+    return _pool_gather(store, layer, table)
 
 
 @scope(SCOPE_ATTN)
-def _ragged_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b, valid,
-                      table=None, fused=False, mesh=None):
-    """Single-token attention where row ``b`` writes cache slot ``pos_b[b]``
-    — the continuous-batching variant of :func:`_cached_attention` (rows at
+def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
+                      valid, table=None, fused=False, mesh=None):
+    """Single-token attention of layer ``li`` (traced: the layer loop's
+    index) where row ``b`` writes cache slot ``pos_b[b]`` — the
+    continuous-batching variant of :func:`_cached_attention` (rows at
     heterogeneous positions). x: [B, 1, D]; pos_b: [B]; valid: [B, total].
 
-    With ``table`` ([B, max_blocks]) the caches are a paged block pool
-    ``[N, Bs, H, hd]``: the write scatters through the table and the
+    ``k_store``/``v_store`` are the WHOLE K/V storage, all layers,
+    written in place: the token's K/V is scattered at
+    ``[li, row, pos_b[row]]`` and the attention reads ``store[li]`` where
+    it lies (a dynamic index XLA fuses into its consumer) — no layer is
+    sliced out and written back, which is what a cache scanned as
+    ``xs``/``ys`` costs (:func:`_layer_loop`). Returns
+    (out, k_store, v_store).
+
+    With ``table`` ([B, max_blocks]) the storage is the paged block pool
+    ``[L, N, Bs, H, hd]``: the write scatters through the table and the
     attention reads the row gathered at block granularity — same math,
     same mask, so outputs are byte-identical to the dense layout. With
     ``fused`` the gather never happens: the block-table attention kernel
     (ops/attention.py:paged_decode_attention) walks the table with an
     online softmax, so the dense ``[B, total]`` view of the cache is
     never materialized (its numerics are f32-equivalent, not bitwise —
-    the gather path stays the pinned-parity reference). ``mesh`` (a
+    the gather path stays the pinned-parity reference). The kernel takes
+    one layer's pool (it relays it head-major for its tiles), so this arm
+    alone still reads ``pool[li]`` out as an operand. ``mesh`` (a
     tensor-parallel serving mesh) routes the fused read through the
     kernel's shard_map twin: each shard walks the same table over its
     local KV heads."""
     b, s, _d = x.shape
-    hd = cfg.head_dim
-    cos, sin = rope_bt
-    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = _rope(q, cos, sin)
-    k = _rope(k, cos, sin)
-    rows = jnp.arange(b)
-    # Out-of-bounds pos_b (a retired row parked at total) is dropped by
-    # scatter semantics — retired rows write nowhere.
-    if table is None:
-        k_cache = k_cache.at[rows, pos_b].set(k[:, 0])
-        v_cache = v_cache.at[rows, pos_b].set(v[:, 0])
-        k_read, v_read = k_cache, v_cache
+    q, k, v = _qkv_rope(x, layer, cfg, rope_bt)
+    k_store = _store_write(k_store, li, table, pos_b[:, None], k)
+    v_store = _store_write(v_store, li, table, pos_b[:, None], v)
+    if fused:
+        # The decode step's validity mask is exactly "positions
+        # <= pos_b" (the just-written token included), which is the
+        # fused kernel's span contract.
+        out = paged_decode_attention(
+            q[:, 0], _layer_of(k_store, li), _layer_of(v_store, li), table,
+            pos_b, n_kv_heads=cfg.n_kv_heads, mesh=mesh,
+        ).reshape(b, s, cfg.n_heads * cfg.head_dim)
     else:
-        k_cache = _pool_write(k_cache, table, pos_b[:, None], k)
-        v_cache = _pool_write(v_cache, table, pos_b[:, None], v)
-        if fused:
-            # The decode step's validity mask is exactly "positions
-            # <= pos_b" (the just-written token included), which is the
-            # fused kernel's span contract.
-            out = paged_decode_attention(
-                q[:, 0], k_cache, v_cache, table, pos_b,
-                n_kv_heads=cfg.n_kv_heads, mesh=mesh,
-            ).reshape(b, s, cfg.n_heads * hd).astype(cfg.dtype)
-            return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
-        k_read = _pool_gather(k_cache, table)
-        v_read = _pool_gather(v_cache, table)
-    out = _gqa_attention(q, k_read, v_read,
-                         valid[:, None, None, None, :], cfg)
+        out = _gqa_attention(q, _store_rows(k_store, li, table),
+                             _store_rows(v_store, li, table),
+                             valid[:, None, None, None, :], cfg)
     # Quantized pools dequantize to f32; fold back to the compute dtype
     # (identity for fp pools) so the residual stream's dtype is stable.
     return (out.astype(cfg.dtype) @ cast_param(layer["wo"], cfg.dtype),
-            k_cache, v_cache)
+            k_store, v_store)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "total_len"))
@@ -665,15 +715,50 @@ def _with_kv(state, k, v):
     return {**state, "cache": {"k": k, "v": v}}
 
 
+def _layer_loop(params, cfg: TransformerConfig, x, k_store, v_store, attend,
+                token_valid):
+    """THE layer loop of every forward over persistent K/V storage
+    (:func:`_single_token_forward`, :func:`_block_forward`): a scan whose
+    CARRY is ``(x, k_store, v_store)`` — the whole storage, dense cache
+    or paged pool (both leaves of a quantized one) — and whose scanned
+    inputs are the per-layer parameters and the layer index.
+    ``attend(h, attn_params, k_store, v_store, li)`` writes layer ``li``'s
+    K/V into the storage in place and attends over it; it returns
+    (out, k_store, v_store). The storage is never the scan's ``xs``/``ys``:
+    a scan cannot alias the two, so that form holds a second whole store
+    and copies it every step. Returns (logits [B, S, V], k_store,
+    v_store)."""
+
+    def layer_fn(carry, layer_and_index):
+        x, k_store, v_store = carry
+        layer, li = layer_and_index
+        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
+        attn, k_store, v_store = attend(h, layer["attn"], k_store, v_store,
+                                        li)
+        x = x + attn
+        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
+        x = x + _ffn(h, layer["mlp"], cfg, token_valid)
+        return (x, k_store, v_store), None
+
+    n_layers = _kv_arr(k_store).shape[0]
+    (x, k_store, v_store), _ = lax.scan(
+        layer_fn, (x, k_store, v_store),
+        (params["layers"], jnp.arange(n_layers)))
+    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+    return _head(params, x, cfg), k_store, v_store
+
+
 def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
                           tok, pos_b, token_valid, table=None, fused=False,
                           mesh=None):
     """One [B, 1] forward at per-row cache positions ``pos_b`` against the
-    persistent caches (the layer loop shared by :func:`_decode_step_body`
-    and the verify commit pass). With ``table`` the caches are the paged
-    block pool read/written through the block table (``fused`` swaps the
-    gathered read for the block-walking attention kernel). Returns
-    (logits [B, V], k, v)."""
+    persistent caches (shared by :func:`_decode_step_body` and the verify
+    commit pass). The caches ride :func:`_layer_loop` as its carry, whole
+    — written and read in place at ``[layer, ...]``, never scanned as
+    ``xs``/``ys`` (that form copies the whole cache every step). With
+    ``table`` the caches are the paged block pool read/written through
+    the block table (``fused`` swaps the gathered read for the
+    block-walking attention kernel). Returns (logits [B, V], k, v)."""
     total = (k_cache0.shape[2] if table is None
              else table.shape[1] * _kv_arr(k_cache0).shape[2])
     cos_t, sin_t = rotary_frequencies(cfg.head_dim, total,
@@ -682,23 +767,14 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
     x = _embed(params, tok, cfg)[:, None]
     valid = jnp.arange(total)[None, :] <= pos_b[:, None]
 
-    def layer_fn(x, layer_and_cache):
-        layer, k_cache, v_cache = layer_and_cache
-        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
-        attn, k_cache, v_cache = _ragged_attention(
-            h, layer["attn"], cfg, rope_bt, k_cache, v_cache, pos_b, valid,
-            table=table, fused=fused, mesh=mesh,
-        )
-        x = x + attn
-        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        x = x + _ffn(h, layer["mlp"], cfg, token_valid[:, None])
-        return x, (k_cache, v_cache)
+    def attend(h, attn, k_store, v_store, li):
+        return _ragged_attention(h, attn, cfg, rope_bt, k_store, v_store, li,
+                                 pos_b, valid, table=table, fused=fused,
+                                 mesh=mesh)
 
-    x, (k_new, v_new) = lax.scan(
-        layer_fn, x, (params["layers"], k_cache0, v_cache0)
-    )
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    return _head(params, x, cfg)[:, 0], k_new, v_new
+    logits, k_new, v_new = _layer_loop(params, cfg, x, k_cache0, v_cache0,
+                                       attend, token_valid[:, None])
+    return logits[:, 0], k_new, v_new
 
 
 def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
@@ -815,15 +891,16 @@ def decode_chunk(state, params, cfg: TransformerConfig, steps: int,
 
 
 @scope(SCOPE_ATTN)
-def _span_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b,
+def _span_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
                     table=None, fused=False, mesh=None, ring=None):
-    """Block attention where row ``b``'s ``S`` tokens occupy cache slots
-    ``pos_b[b]..pos_b[b]+S-1`` — the S-wide sibling of
-    :func:`_ragged_attention` (rows at heterogeneous positions). Block
+    """Block attention of layer ``li`` where row ``b``'s ``S`` tokens
+    occupy cache slots ``pos_b[b]..pos_b[b]+S-1`` — the S-wide sibling of
+    :func:`_ragged_attention` (rows at heterogeneous positions; the same
+    whole storage written and read in place at ``[li, ...]``). Block
     token ``s`` attends every cache slot ``<= pos_b + s`` (its own K/V
     was just written), so causality holds within the block and over the
     row's history. Out-of-bounds writes (parked rows, cache-tail spill)
-    are dropped by scatter semantics. With ``table`` the caches are the
+    are dropped by scatter semantics. With ``table`` the storage is the
     paged block pool, written/read through the block table; ``fused``
     swaps the gathered read for the span block-walk
     (ops/attention.py:paged_span_attention) so the dense
@@ -834,46 +911,29 @@ def _span_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos_b,
     (ops/attention.py:ring_span_attention) — chunked-prefill's long-
     prompt path, same f32-equivalence caveat."""
     b, s, _d = x.shape
-    hd = cfg.head_dim
-    cos, sin = rope_bt
-    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    q = _rope(q, cos, sin)
-    k = _rope(k, cos, sin)
+    q, k, v = _qkv_rope(x, layer, cfg, rope_bt)
     cols = pos_b[:, None] + jnp.arange(s)[None, :]
-    if table is None:
-        rows = jnp.arange(b)[:, None]
-        k_cache = k_cache.at[rows, cols].set(k)
-        v_cache = v_cache.at[rows, cols].set(v)
-        k_read, v_read = k_cache, v_cache
-        total = k_cache.shape[1]
+    k_store = _store_write(k_store, li, table, cols, k)
+    v_store = _store_write(v_store, li, table, cols, v)
+    if fused:
+        # Span contract: token ``s`` attends positions <= pos_b + s —
+        # exactly the mask below, walked block-by-block instead of
+        # gathered dense.
+        out = paged_span_attention(
+            q, _layer_of(k_store, li), _layer_of(v_store, li), table, pos_b,
+            n_kv_heads=cfg.n_kv_heads, mesh=mesh)
     else:
-        k_cache = _pool_write(k_cache, table, cols, k)
-        v_cache = _pool_write(v_cache, table, cols, v)
-        if fused:
-            # Span contract: token ``s`` attends positions <= pos_b + s
-            # — exactly the mask below, walked block-by-block instead
-            # of gathered dense.
-            out = paged_span_attention(
-                q, k_cache, v_cache, table, pos_b,
-                n_kv_heads=cfg.n_kv_heads, mesh=mesh,
-            ).reshape(b, s, cfg.n_heads * hd).astype(cfg.dtype)
-            return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
-        k_read = _pool_gather(k_cache, table)
-        v_read = _pool_gather(v_cache, table)
-        total = table.shape[1] * _kv_arr(k_cache).shape[1]
+        k_read = _store_rows(k_store, li, table)
+        v_read = _store_rows(v_store, li, table)
         if ring is not None:
-            out = ring_span_attention(
-                q, k_read, v_read, pos_b, n_kv_heads=cfg.n_kv_heads,
-                mesh=ring,
-            ).astype(cfg.dtype)
-            return (out.reshape(b, s, cfg.n_heads * hd)
-                    @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache)
-    mask = jnp.arange(total)[None, None, :] <= cols[:, :, None]
-    out = _gqa_attention(q, k_read, v_read, mask[:, None, None], cfg)
-    return (out.astype(cfg.dtype) @ cast_param(layer["wo"], cfg.dtype),
-            k_cache, v_cache)
+            out = ring_span_attention(q, k_read, v_read, pos_b,
+                                      n_kv_heads=cfg.n_kv_heads, mesh=ring)
+        else:
+            mask = (jnp.arange(k_read.shape[1])[None, None, :]
+                    <= cols[:, :, None])
+            out = _gqa_attention(q, k_read, v_read, mask[:, None, None], cfg)
+    out = out.reshape(b, s, cfg.n_heads * cfg.head_dim).astype(cfg.dtype)
+    return out @ cast_param(layer["wo"], cfg.dtype), k_store, v_store
 
 
 def _block_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
@@ -882,8 +942,9 @@ def _block_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
     """[B, S] forward writing K/V at per-row start positions ``pos_b`` →
     (logits [B, S, V], k, v). The verify scoring pass, the paged
     suffix-only prefill, and the draft model's catch-up feed all ride
-    this; ``fused`` routes the paged span read through the block-walk
-    instead of the dense gather."""
+    this, over the same storage and through the same :func:`_layer_loop`
+    as the single-token step; ``fused`` routes the paged span read
+    through the block-walk instead of the dense gather."""
     total = (k_cache0.shape[2] if table is None
              else table.shape[1] * _kv_arr(k_cache0).shape[2])
     _b, s = tokens.shape
@@ -893,23 +954,13 @@ def _block_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
     rope_bt = (cos_t[pos], sin_t[pos])
     x = _embed(params, tokens, cfg)
 
-    def layer_fn(x, layer_and_cache):
-        layer, k_cache, v_cache = layer_and_cache
-        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
-        attn, k_cache, v_cache = _span_attention(
-            h, layer["attn"], cfg, rope_bt, k_cache, v_cache, pos_b,
-            table=table, fused=fused, mesh=mesh, ring=ring,
-        )
-        x = x + attn
-        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        x = x + _ffn(h, layer["mlp"], cfg, token_valid)
-        return x, (k_cache, v_cache)
+    def attend(h, attn, k_store, v_store, li):
+        return _span_attention(h, attn, cfg, rope_bt, k_store, v_store, li,
+                               pos_b, table=table, fused=fused, mesh=mesh,
+                               ring=ring)
 
-    x, (k_new, v_new) = lax.scan(
-        layer_fn, x, (params["layers"], k_cache0, v_cache0)
-    )
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    return _head(params, x, cfg), k_new, v_new
+    return _layer_loop(params, cfg, x, k_cache0, v_cache0, attend,
+                       token_valid)
 
 
 def _target_probs(logits, temperature, top_k: int):

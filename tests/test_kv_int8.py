@@ -268,18 +268,17 @@ def test_copy_block_carries_scales():
         np.testing.assert_array_equal(
             np.asarray(pool2[side]["scale"][:, 3]),
             expect[side]["scale"][:, 1])
-    # Overwrite the copy (one layer's view); the donor block must be
-    # untouched.
-    layer0 = {"q": pool2["k"]["q"][0], "scale": pool2["k"]["scale"][0]}
+    # Overwrite the copy (layer 0 of the whole pool); the donor block
+    # must be untouched.
     table = jnp.asarray(np.array([[3]], np.int32))
     new = jnp.asarray(rng.randn(1, 1, h, hd).astype(np.float32))
-    k3 = decode_mod._pool_write(layer0, table,
+    k3 = decode_mod._pool_write(pool2["k"], 0, table,
                                 jnp.zeros((1, 1), jnp.int32), new)
-    np.testing.assert_array_equal(np.asarray(k3["q"][1]),
+    np.testing.assert_array_equal(np.asarray(k3["q"][0, 1]),
                                   expect["k"]["q"][0, 1])
-    np.testing.assert_array_equal(np.asarray(k3["scale"][1]),
+    np.testing.assert_array_equal(np.asarray(k3["scale"][0, 1]),
                                   expect["k"]["scale"][0, 1])
-    assert not np.array_equal(np.asarray(k3["q"][3]),
+    assert not np.array_equal(np.asarray(k3["q"][0, 3]),
                               expect["k"]["q"][0, 1])  # copy did change
 
 

@@ -74,3 +74,52 @@ def test_the_sparse_decode_kernel_compiles_for_v5e(one_chip, no_compile_cache,
     assert "tpu_custom_call" in text
     # The pools go in whole and in place: nothing of their size is made.
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# `chat-steady`'s decode step: 6 Mistral-7B layers held at bf16, 32 rows of
+# 768 (dense), or the same tokens in a pool of 16-token blocks. The most
+# temporaries the step may have, in MB, whatever the layout and the read.
+# A K/V store that rides the layer loop as a scanned input and output, not
+# as its carry, costs a second whole store (605.7 MB dense, 739.7 paged,
+# 606.5 fused, at PR 31) and a copy of it every step. In place: 1.6, 34.4
+# and 52.3 MB (the fused kernel still relays one layer's pool head-major
+# for its tiles).
+DECODE_STEP_TEMP_MB = 64
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged", "paged-kv_fused"])
+def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
+                                                      no_compile_cache,
+                                                      layout):
+    import re
+
+    from kubeflow_tpu.models import decode, transformer
+
+    cfg = transformer.TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=6, n_heads=32, n_kv_heads=8,
+        d_ff=14336, max_seq_len=768, rope_theta=1e6, dtype=jnp.bfloat16)
+
+    def described(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(
+        lambda: transformer.serving_params(
+            transformer.init(jax.random.PRNGKey(0), cfg), cfg)))
+    if layout == "dense":
+        state = jax.eval_shape(lambda: decode.init_decode_state(cfg, 32, 768))
+        store = state["cache"]["k"]
+    else:
+        state = jax.eval_shape(
+            lambda: decode.init_paged_state(cfg, 32, 1536, 16, 48))
+        store = state["pool"]["k"]
+    compiled = decode.decode_step.lower(
+        described(state), params, cfg,
+        kv_fused=layout.endswith("kv_fused")).compile()
+    whole = "bf16[" + ",".join(map(str, store.shape)) + "]"
+    copies = [line.strip()[:120] for line in compiled.as_text().splitlines()
+              if re.search(re.escape(whole) + r"\S* copy\(", line)]
+    assert not copies, copies
+    temp_mb = compiled.memory_analysis().temp_size_in_bytes / 1e6
+    assert temp_mb < DECODE_STEP_TEMP_MB, temp_mb
