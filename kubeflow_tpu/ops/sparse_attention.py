@@ -20,6 +20,12 @@ The selection is plain XLA:
 - :func:`select_blocks`: block indices and which of them count, the ones
   that count first. A dense query is a query whose every visible block is
   forced, so rows on both sides of ``dense_len`` share one compiled shape.
+  The pick is ``lax.top_k`` of the float32 ranks (on a TPU a stable sort
+  of every row, NB log^2 NB compare-exchanges a (row, head, query), the
+  rows in parallel along the lanes): exact, the forced blocks first, then
+  by score, of equal scores the lower block first. Equal scores are
+  common: neighbouring blocks share a window of compressed keys, and a
+  block's score is a maximum.
 
 What reads the selected blocks is one of three:
 
@@ -108,12 +114,11 @@ def compress_last(k_last):
     return k_last.astype(jnp.float32).mean(axis=2).astype(k_last.dtype)
 
 
-def select_blocks(q, ckeys, pos, n_blocks: int, spec: SparseSpec):
-    """q [B, Hkv, G, Q, hd] at positions ``pos`` [B, Q]; ckeys
-    [B, Hkv, W, hd]. Returns (idx [B, Hkv, Q, n] int32 block indices, ok
-    [B, Hkv, Q, n] bool) with n = ``spec.n_select``: the blocks to read,
-    the forced ones first, then by score; a slot that is not ``ok`` is
-    padding."""
+def _block_ranks(q, ckeys, pos, n_blocks: int, spec: SparseSpec):
+    """What :func:`select_blocks` picks by: rank [B, Hkv, Q, NB] float32,
+    ``_BIG`` for a block the query must read, the block's score for one it
+    may, ``-_BIG`` for one past it; and dense [B, Q, 1], whether the
+    query's context is no longer than ``dense_len``."""
     hd = q.shape[-1]
     w = ckeys.shape[2]
     scores = jnp.einsum("bkgqd,bkwd->bkgqw", q, ckeys,
@@ -141,9 +146,24 @@ def select_blocks(q, ckeys, pos, n_blocks: int, spec: SparseSpec):
         blk >= jnp.maximum(p_ - spec.window + 1, 0) // spec.block)
     dense = (p_ + 1) <= spec.dense_len
     rank = jnp.where((forced | dense)[:, None], _BIG, block_score)
-    rank = jnp.where((blk <= p_ // spec.block)[:, None], rank, -_BIG)
+    return jnp.where((blk <= p_ // spec.block)[:, None], rank, -_BIG), dense
+
+
+def select_blocks(q, ckeys, pos, n_blocks: int, spec: SparseSpec):
+    """q [B, Hkv, G, Q, hd] at positions ``pos`` [B, Q]; ckeys
+    [B, Hkv, W, hd]. Returns (idx [B, Hkv, Q, n] int32 block indices, ok
+    [B, Hkv, Q, n] bool) with n = ``spec.n_select``: the blocks to read,
+    the forced ones first, then by score, of equal scores the lower block
+    first; a slot that is not ``ok`` is padding."""
+    rank, dense = _block_ranks(q, ckeys, pos, n_blocks, spec)
     n = spec.n_select(n_blocks * spec.block)
-    vals, idx = lax.top_k(rank, n)
+    # Every (row, head, query) a row of ONE leading dimension: the TPU's
+    # compiler then lays the rows along the lanes and sorts each down the
+    # sublanes. Handed [B, Hkv, 1, NB], a decode step's shape, it sorts
+    # along the lanes, a row to a tile, several times as long (PERF.md,
+    # PR 35).
+    vals, idx = lax.top_k(rank.reshape(-1, n_blocks), n)
+    vals, idx = (a.reshape(*rank.shape[:-1], n) for a in (vals, idx))
     ok = (vals > -_BIG / 2) & (dense[:, None] | (jnp.arange(n) < spec.topk))
     return idx.astype(jnp.int32), ok
 

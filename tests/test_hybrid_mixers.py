@@ -274,6 +274,71 @@ def test_topk_of_every_block_is_dense_attention():
     assert float(jnp.abs(got - want).max()) < 1e-5
 
 
+# What select_blocks must pick: the n largest of the float32 ranks, the
+# largest first and of equals the lower block first, which is what
+# ``lax.top_k`` of one row gives and what a stable sort of the negated
+# ranks gives numpy.
+WIDE = sa.SparseSpec(kernel=32, stride=16, block=64, topk=16, init_blocks=1,
+                     window=512, dense_len=2048)
+ORACLE_CASES = {
+    # name: (spec, blocks a row, positions of the queries, keys)
+    "tied-scores-across-blocks": (SPEC, 12, [95, 80], "periodic"),
+    "at-dense-len": (SPEC, 12, [SPEC.dense_len - 1], "random"),
+    "past-dense-len": (SPEC, 12, [SPEC.dense_len], "random"),
+    "pos-in-block-0": (SPEC, 12, [3], "random"),
+    "row-shorter-than-n": (SPEC, 12, [20], "random"),
+    "queries-dense-and-sparse": (SPEC, 12, [5, 31, 32, 60, 95], "random"),
+    "wide-spec-96-blocks": (WIDE, 96, [700, 2047, 2048, 6143], "random"),
+    "wide-spec-tied": (WIDE, 96, [6143, 4000], "periodic"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORACLE_CASES))
+def test_the_selection_is_a_stable_sorts(case):
+    spec, n_blocks, pos, keys_are = ORACLE_CASES[case]
+    keys = jax.random.split(jax.random.PRNGKey(11), 2)
+    q = jax.random.normal(keys[0], (2, 2, 2, len(pos), 16), jnp.float32)
+    k_row = jax.random.normal(keys[1], (2, 2, n_blocks * spec.block, 16),
+                              jnp.float32)
+    if keys_are == "periodic":  # every block holds the same keys
+        k_row = jnp.tile(k_row[:, :, :spec.block], (1, 1, n_blocks, 1))
+    pos = np.tile(np.asarray(pos, np.int32), (2, 1))
+    args = (q, sa.compress_keys(k_row, spec), jnp.asarray(pos), n_blocks,
+            spec)
+    idx, ok = (np.asarray(a) for a in sa.select_blocks(*args))
+    ranks = np.asarray(sa._block_ranks(*args)[0])
+    n = spec.n_select(n_blocks * spec.block)
+    want_idx = np.argsort(-ranks, axis=-1, kind="stable")[..., :n]
+    dense = (pos + 1 <= spec.dense_len)[:, None, :, None]
+    want_ok = (np.take_along_axis(ranks, want_idx, -1) > -1e29) & (
+        dense | (np.arange(n) < spec.topk))
+    assert idx.shape == want_idx.shape == (2, 2, pos.shape[1], n)
+    assert idx.dtype == np.int32 and ok.dtype == np.bool_
+    tied = False
+    for b, h, j in np.ndindex(*idx.shape[:3]):
+        mine, theirs = idx[b, h, j], want_idx[b, h, j]
+        count = int(ok[b, h, j].sum())
+        assert (ok[b, h, j] == (np.arange(n) < count)).all()  # a prefix
+        assert (ok[b, h, j] == want_ok[b, h, j]).all()
+        assert set(mine[:count]) == set(theirs[:count])
+        assert len(set(mine[:count])) == count
+        home = pos[b, j] // spec.block
+        assert mine[:count].max() <= home  # nothing past the query
+        # What the decode read derives: the count, and the slot to cut.
+        assert home in mine[:count]
+        assert np.argmax(mine == home) == np.argmax(theirs == home) < count
+        # The forced ones first, then by score: the order too.
+        assert (mine == theirs).all()
+        rank = ranks[b, h, j]
+        left_out = np.setdiff1d(np.arange(n_blocks), mine[:count])
+        if count and len(left_out) and (
+                rank[left_out].max() == rank[mine[count - 1]]):
+            tied = True  # the last block taken ties with one left out
+            assert mine[count - 1] < left_out[
+                rank[left_out] == rank[mine[count - 1]]].min()
+    assert tied or keys_are != "periodic"
+
+
 # The decode read at the widths the kernel engages at (head_dim 128,
 # block 64), one batch: a row per position around the block and dense_len
 # boundaries, a long row, and a row with nothing allocated. The pool is

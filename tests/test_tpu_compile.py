@@ -6,6 +6,7 @@ file, never at import: one process at a time may load the TPU's library,
 and every xdist worker imports every test file."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,14 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+@pytest.fixture()
+def arg(one_chip):
+    """A described argument: a shape and dtype placed on the one chip."""
+    def described(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return described
+
+
 # (rows, pool blocks, block, blocks a row, slots a (row, head), blocks a
 # chunk): the long-decode cell's shapes as the decode step calls the
 # kernel (its block table and selection, 214 KB, fit scalar memory), and
@@ -51,13 +60,10 @@ SHAPES = {"long-decode": (64, 18688, 64, 584, 128, None),
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
-def test_the_sparse_decode_kernel_compiles_for_v5e(one_chip, no_compile_cache,
+def test_the_sparse_decode_kernel_compiles_for_v5e(arg, no_compile_cache,
                                                    shape):
     b, n_pool, bs, mb, n, chunk = SHAPES[shape]
     hkv, group, hd = 2, 16, 128
-
-    def arg(dims, dtype):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def read(q, pool_k, pool_v, table, idx, count, cut, keep):
         kw = {} if chunk is None else {"blocks_per_chunk": chunk}
@@ -76,6 +82,38 @@ def test_the_sparse_decode_kernel_compiles_for_v5e(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
+# The selection as `long-decode` calls it: a decode step's 64 rows of one
+# query, and a prefill chunk's block of 128 queries of one row, over 584
+# blocks' compressed keys (MiniCPM-SALA's sparse_config), two KV heads.
+# (rows, queries, most temporaries in MB: a query block has its float32
+# scores over the compressed keys, 48 MB; a decode step has none.)
+SELECT_CALLS = {"decode-step": (64, 1, 1), "prefill-query-block": (1, 128, 64)}
+
+
+@pytest.mark.parametrize("call", sorted(SELECT_CALLS))
+def test_the_block_selection_sorts_down_the_sublanes_on_v5e(
+        arg, no_compile_cache, call):
+    """On the chip a sort of ``f32[64,2,1,584]`` along its lanes, one row a
+    tile, took 0.32-0.63 ms of `long-decode`'s step, twice; the same rows
+    as ``f32[128,584]``, the rows along the lanes and each sorted down the
+    sublanes (layout ``{0,1}``), under 0.1 (PERF.md, PR 35)."""
+    rows, queries, temp_mb = SELECT_CALLS[call]
+    spec = sa.SparseSpec(kernel=32, stride=16, block=64, topk=64,
+                         init_blocks=1, window=2048, dense_len=8192)
+    n_blocks, hkv = 584, 2
+
+    compiled = jax.jit(
+        lambda q, ckeys, pos: sa.select_blocks(q, ckeys, pos, n_blocks, spec)
+    ).lower(arg((rows, hkv, 16, queries, 128), jnp.bfloat16),
+            arg((rows, hkv, spec.n_windows(n_blocks * 64), 128),
+                jnp.bfloat16),
+            arg((rows, queries), jnp.int32)).compile()
+    sorts = re.findall(r"= \((f32\[[\d,]+\]\{[\d,]+)\S*, s32\S+ sort\(",
+                       compiled.as_text())
+    assert sorts == [f"f32[{rows * hkv * queries},{n_blocks}]{{0,1"], sorts
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb << 20
+
+
 # `chat-steady`'s decode step: 6 Mistral-7B layers held at bf16, 32 rows of
 # 768 (dense), or the same tokens in a pool of 16-token blocks. The most
 # temporaries the step may have, in MB, whatever the layout and the read.
@@ -91,8 +129,6 @@ DECODE_STEP_TEMP_MB = 64
 def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
                                                       no_compile_cache,
                                                       layout):
-    import re
-
     from kubeflow_tpu.models import decode, transformer
 
     cfg = transformer.TransformerConfig(
