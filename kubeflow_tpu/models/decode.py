@@ -51,6 +51,7 @@ from kubeflow_tpu.models.transformer import (
     MIXER_SPARSE,
     TransformerConfig,
     cast_param,
+    close_pass,
     final_hidden,
     head_kernel,
     lightning_span,
@@ -58,6 +59,7 @@ from kubeflow_tpu.models.transformer import (
     mixer_out,
     mixer_qkv,
     moe_ffn,
+    post_norm,
     sparse_span,
 )
 
@@ -65,8 +67,10 @@ _NEG_INF = -1e30
 
 
 def init_cache(cfg: TransformerConfig, batch: int, total_len: int):
-    """K/V cache ``[L, batch, total_len, Hkv, hd]``, stacked on a leading
-    layer dim like the params. The decode state's cache is carried whole
+    """K/V cache ``[cache layers, batch, total_len, Hkv, hd]``, stacked on
+    a leading dim like the params: one entry per layer, and of a looped
+    stack one per (pass, layer) at ``pass * n_layers + layer``
+    (``cfg.cache_layers``). The decode state's cache is carried whole
     through the layer loop and written in place at ``[layer, ...]``
     (:func:`_layer_loop` says why it is not scanned layer by layer)."""
     if cfg.mixer_types:
@@ -74,8 +78,22 @@ def init_cache(cfg: TransformerConfig, batch: int, total_len: int):
             "mixer_types: the dense KV cache (and the lockstep generate "
             "built on it) holds K and V for every layer and nothing else; "
             "a model with recurrent state serves on kv_layout='paged'")
-    shape = (cfg.n_layers, batch, total_len, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.cache_layers, batch, total_len, cfg.n_kv_heads,
+             cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def _scratch_len(cfg: TransformerConfig, t0: int, total: int,
+                 block: int = 1) -> int:
+    """Positions a row of an admission's scratch cache holds: the whole
+    row, which also clears what the slot's last user left. A looped
+    stack's scratch is ``n_passes`` times the layers (2 GB a row of 1,280
+    at Ouro-2.6B's sizes, beside 13.4 GB held), so it holds the prompt's
+    positions alone, to a whole block: what lies past them in the slot is
+    masked until a decode step has written it."""
+    if cfg.n_passes == 1:
+        return total
+    return min(total, -(-t0 // block) * block)
 
 
 def _gqa_attention(q, k_cache, v_cache, mask, cfg):
@@ -99,22 +117,34 @@ def _gqa_attention(q, k_cache, v_cache, mask, cfg):
 
 
 @scope(SCOPE_ATTN)
-def _cached_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos, valid):
+def _cached_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos, valid,
+                      li=None):
     """x: [B, S, D] at cache slots pos..pos+S; attends over the full cache
-    masked by ``valid`` [B, total]. Returns (out, k_cache, v_cache)."""
+    masked by ``valid`` [B, total]. Returns (out, k_cache, v_cache). With
+    ``li`` (traced) the two are the WHOLE cache, every cache layer,
+    written and read in place at ``[li]`` as :func:`_ragged_attention`
+    does."""
     s = x.shape[1]
     q, k, v = _qkv_rope(x, layer, cfg, rope_bt)
-    k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
-    v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
+    if li is None:
+        k_cache = lax.dynamic_update_slice(k_cache, k, (0, pos, 0, 0))
+        v_cache = lax.dynamic_update_slice(v_cache, v, (0, pos, 0, 0))
+        k_rows, v_rows = k_cache, v_cache
+    else:
+        k_cache = lax.dynamic_update_slice(k_cache, k[None],
+                                           (li, 0, pos, 0, 0))
+        v_cache = lax.dynamic_update_slice(v_cache, v[None],
+                                           (li, 0, pos, 0, 0))
+        k_rows, v_rows = _layer_of(k_cache, li), _layer_of(v_cache, li)
 
-    total = k_cache.shape[1]
+    total = k_rows.shape[1]
     # Causality within the new block: query at slot pos+i sees key slot j
     # iff j <= pos+i; prompt padding and unwritten slots are masked by
     # ``valid`` (which already includes slots pos..pos+S for this block).
     j_idx = jnp.arange(total)[None, None, :]
     i_idx = pos + jnp.arange(s)[None, :, None]
     mask = (j_idx <= i_idx) & valid[:, None, :]
-    out = _gqa_attention(q, k_cache, v_cache, mask[:, None, None], cfg)
+    out = _gqa_attention(q, k_rows, v_rows, mask[:, None, None], cfg)
     return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
 
 
@@ -171,6 +201,17 @@ def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
                                       theta=cfg.rope_theta)
     rope_bt = (cos_t[positions], sin_t[positions])
     x = _embed(params, tokens, cfg)
+    if cfg.n_passes > 1:
+        # A looped stack cannot scan a cache of n_passes * n_layers
+        # entries beside n_layers layers of parameters: the cache rides
+        # THE layer loop as its carry, as a decode step's does.
+        def attend(h, attn, k_all, v_all, li):
+            return _cached_attention(h, attn, cfg, rope_bt, k_all, v_all,
+                                     pos, valid, li)
+
+        logits, k_new, v_new = _layer_loop(params, cfg, x, cache["k"],
+                                           cache["v"], attend, token_valid)
+        return logits, {"k": k_new, "v": v_new}
 
     def layer_fn(x, layer_and_cache):
         layer, k_cache, v_cache = layer_and_cache
@@ -178,9 +219,10 @@ def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
         attn, k_cache, v_cache = _cached_attention(
             h, layer["attn"], cfg, rope_bt, k_cache, v_cache, pos, valid
         )
-        x = x + attn
+        x = x + post_norm(attn, layer, "ln_attn_post", cfg)
         h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        x = x + _ffn(h, layer["mlp"], cfg, token_valid)
+        x = x + post_norm(_ffn(h, layer["mlp"], cfg, token_valid), layer,
+                          "ln_mlp_post", cfg)
         return x, (k_cache, v_cache)
 
     x, (k_new, v_new) = lax.scan(
@@ -502,8 +544,8 @@ def insert_row(state, slot, row_cache, last_logits, length, remaining,
 @scope(SCOPE_PREFILL)
 def _admit_rows_body(state, params, cfg: TransformerConfig, slots,
                      prompt_tokens, prompt_lengths, remaining, temperature):
-    total_len = state["cache"]["k"].shape[2]
     b, t0 = prompt_tokens.shape
+    total_len = _scratch_len(cfg, t0, state["cache"]["k"].shape[2])
     cache = init_cache(cfg, b, total_len)
     prompt_lengths = jnp.maximum(prompt_lengths, 1)
     valid = jnp.arange(total_len)[None, :] < prompt_lengths[:, None]
@@ -515,10 +557,16 @@ def _admit_rows_body(state, params, cfg: TransformerConfig, slots,
     last = jnp.take_along_axis(
         logits, (prompt_lengths - 1)[:, None, None], axis=1
     )[:, 0]
+
+    def rows(store, new):
+        if new.shape[2] == store.shape[2]:
+            return store.at[:, slots].set(new)
+        return store.at[:, slots, :new.shape[2]].set(new)
+
     return {
         "cache": {
-            "k": state["cache"]["k"].at[:, slots].set(cache["k"]),
-            "v": state["cache"]["v"].at[:, slots].set(cache["v"]),
+            "k": rows(state["cache"]["k"], cache["k"]),
+            "v": rows(state["cache"]["v"], cache["v"]),
         },
         "length": state["length"].at[slots].set(prompt_lengths),
         "remaining": state["remaining"].at[slots].set(remaining),
@@ -572,7 +620,7 @@ def init_prefix_pool(cfg: TransformerConfig, pool_slots: int,
     """Device prefix pool: ``pool_slots`` rows of per-layer K/V for up to
     ``max_prefix_len`` positions, laid out like the decode cache (layer
     dim leading) so row gather/scatter is a contiguous copy."""
-    shape = (cfg.n_layers, pool_slots, max_prefix_len, cfg.n_kv_heads,
+    shape = (cfg.cache_layers, pool_slots, max_prefix_len, cfg.n_kv_heads,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
 
@@ -718,15 +766,22 @@ def _with_kv(state, k, v):
 def _layer_loop(params, cfg: TransformerConfig, x, k_store, v_store, attend,
                 token_valid):
     """THE layer loop of every forward over persistent K/V storage
-    (:func:`_single_token_forward`, :func:`_block_forward`): a scan whose
-    CARRY is ``(x, k_store, v_store)`` — the whole storage, dense cache
-    or paged pool (both leaves of a quantized one) — and whose scanned
-    inputs are the per-layer parameters and the layer index.
-    ``attend(h, attn_params, k_store, v_store, li)`` writes layer ``li``'s
-    K/V into the storage in place and attends over it; it returns
+    (:func:`_single_token_forward`, :func:`_block_forward`, and a looped
+    stack's :func:`forward_cached`): a scan whose CARRY is
+    ``(x, k_store, v_store)`` — the whole storage, dense cache or paged
+    pool (both leaves of a quantized one) — and whose scanned inputs are
+    the per-layer parameters and the layer's index into the storage.
+    ``attend(h, attn_params, k_store, v_store, li)`` writes cache layer
+    ``li``'s K/V into the storage in place and attends over it; it returns
     (out, k_store, v_store). The storage is never the scan's ``xs``/``ys``:
     a scan cannot alias the two, so that form holds a second whole store
-    and copies it every step. Returns (logits [B, S, V], k_store,
+    and copies it every step.
+
+    A looped stack (``cfg.n_passes`` > 1) makes the loop a nest: passes
+    outside, the same scan over the same parameters inside, pass ``u``
+    writing and reading cache layers ``u * n_layers + layer``, each pass
+    closed by the final norm whose output is the next one's input. One
+    pass is the inner scan alone. Returns (logits [B, S, V], k_store,
     v_store)."""
 
     def layer_fn(carry, layer_and_index):
@@ -735,16 +790,25 @@ def _layer_loop(params, cfg: TransformerConfig, x, k_store, v_store, attend,
         h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
         attn, k_store, v_store = attend(h, layer["attn"], k_store, v_store,
                                         li)
-        x = x + attn
+        x = x + post_norm(attn, layer, "ln_attn_post", cfg)
         h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
-        x = x + _ffn(h, layer["mlp"], cfg, token_valid)
+        x = x + post_norm(_ffn(h, layer["mlp"], cfg, token_valid), layer,
+                          "ln_mlp_post", cfg)
         return (x, k_store, v_store), None
 
-    n_layers = _kv_arr(k_store).shape[0]
-    (x, k_store, v_store), _ = lax.scan(
-        layer_fn, (x, k_store, v_store),
-        (params["layers"], jnp.arange(n_layers)))
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+    def one_pass(carry, cache_layers):
+        (x, k_store, v_store), _ = lax.scan(
+            layer_fn, carry, (params["layers"], cache_layers))
+        return close_pass(x, params, cfg), k_store, v_store
+
+    layers = jnp.arange(cfg.n_layers)
+    carry = (x, k_store, v_store)
+    if cfg.n_passes == 1:
+        x, k_store, v_store = one_pass(carry, layers)
+    else:
+        (x, k_store, v_store), _ = lax.scan(
+            lambda c, u: (one_pass(c, u * cfg.n_layers + layers), None),
+            carry, jnp.arange(cfg.n_passes))
     return _head(params, x, cfg), k_store, v_store
 
 
@@ -1204,7 +1268,9 @@ def init_paged_state(cfg: TransformerConfig, slots: int, num_blocks: int,
                      block_size: int, max_blocks_per_seq: int, seed: int = 0,
                      kv_dtype: str = "fp"):
     """Paged server decode state: a device block pool
-    ``[L, num_blocks, block_size, Hkv, hd]`` shared by all slots plus a
+    ``[cache layers, num_blocks, block_size, Hkv, hd]`` shared by all
+    slots (``cfg.cache_layers``: a looped stack's block holds K/V of
+    every pass) plus a
     per-slot block table. Virtual row width is
     ``max_blocks_per_seq * block_size`` (the dense ``total_len``).
 
@@ -1217,7 +1283,7 @@ def init_paged_state(cfg: TransformerConfig, slots: int, num_blocks: int,
     if cfg.mixer_types:
         return _init_hybrid_state(cfg, slots, num_blocks, block_size,
                                   max_blocks_per_seq, seed, kv_dtype)
-    shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
+    shape = (cfg.cache_layers, num_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
     if kv_dtype == "int8":
         def _pool():
@@ -1487,9 +1553,9 @@ def _paged_admit_rows_body(state, params, cfg: TransformerConfig, slots,
                          temperature, last), last
     pool_k, pool_v = state["pool"]["k"], state["pool"]["v"]
     bs = _kv_arr(pool_k).shape[2]
-    mb = state["block_table"].shape[1]
-    total = mb * bs
     b, t0 = prompt_tokens.shape
+    total = _scratch_len(cfg, t0, state["block_table"].shape[1] * bs, bs)
+    mb = total // bs
     cache = init_cache(cfg, b, total)
     prompt_lengths = jnp.maximum(prompt_lengths, 1)
     valid = jnp.arange(total)[None, :] < prompt_lengths[:, None]
@@ -1502,9 +1568,11 @@ def _paged_admit_rows_body(state, params, cfg: TransformerConfig, slots,
         logits, (prompt_lengths - 1)[:, None, None], axis=1
     )[:, 0]
     rows_tbl = state["block_table"][slots]  # [b, mb]
-    upd_k = cache["k"].reshape(cfg.n_layers, b, mb, bs, cfg.n_kv_heads,
+    if mb < rows_tbl.shape[1]:
+        rows_tbl = rows_tbl[:, :mb]
+    upd_k = cache["k"].reshape(cfg.cache_layers, b, mb, bs, cfg.n_kv_heads,
                                cfg.head_dim)
-    upd_v = cache["v"].reshape(cfg.n_layers, b, mb, bs, cfg.n_kv_heads,
+    upd_v = cache["v"].reshape(cfg.cache_layers, b, mb, bs, cfg.n_kv_heads,
                                cfg.head_dim)
 
     def _scatter(pool, upd):
@@ -1752,8 +1820,12 @@ def max_admit_rows(cfg: TransformerConfig) -> int | None:
     """The most rows one admission dispatch may hold (None: as many as
     there are). A sparse layer scores a whole virtual row per block of
     queries, so a batch of such rows is a batch of those score tensors:
-    one row a dispatch."""
-    return 1 if cfg.mixer_types and cfg.layers_of(MIXER_SPARSE) else None
+    one row a dispatch. An admission's scratch cache is as many rows of
+    ``cfg.cache_layers`` layers; a looped stack's is ``n_passes`` times a
+    plain one's (2 GB a row of 1,280 tokens at Ouro-2.6B's sizes): one
+    row a dispatch too."""
+    sparse = cfg.mixer_types and cfg.layers_of(MIXER_SPARSE)
+    return 1 if sparse or cfg.n_passes > 1 else None
 
 
 # ---------------------------------------------------------------------------

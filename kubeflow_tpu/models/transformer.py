@@ -36,7 +36,9 @@ from kubeflow_tpu.observability.tracing import (
     SCOPE_HEAD,
     SCOPE_HEAD_LOSS,
     SCOPE_LINEAR_ATTN,
+    SCOPE_LOOP_NORM,
     SCOPE_MLP,
+    SCOPE_POST_NORM,
     SCOPE_SPARSE_ATTN,
     SCOPE_SPARSE_SELECT,
     scope,
@@ -161,8 +163,36 @@ class TransformerConfig:
     sparse_dense_len: int = 8192
     # Tokens per chunk of the lightning layers' prefill form.
     lightning_chunk: int = 128
+    # Looped stack: the ``n_layers`` layers run ``n_passes`` times a token
+    # with the SAME weights; the final norm closes every pass and its
+    # output is the next pass's input; every (pass, layer) holds K/V of
+    # its own (``cache_layers``). The logits are the last pass's: an exit
+    # gate (a d_model -> 1 map on a pass's normed hidden, in the tree as
+    # ``exit_gate``) would end a token at the first pass whose cumulative
+    # exit probability reaches ``exit_threshold``; at 1.0 that is always
+    # the last, the forward does not evaluate the gate, and anything
+    # lower is refused (a row whose step cost varies: ROADMAP Queue 2).
+    n_passes: int = 1
+    exit_threshold: float = 1.0
+    # Sandwich norms: a second RMSNorm after each sublayer, inside the
+    # residual branch (``x + norm(attn(norm(x)))``).
+    post_norms: bool = False
 
     def __post_init__(self):
+        if self.n_passes < 1:
+            raise ValueError(f"n_passes must be >= 1, got {self.n_passes}")
+        if self.exit_threshold < 1.0:
+            raise ValueError(
+                f"exit_threshold {self.exit_threshold} < 1: exit before "
+                "the last pass is not supported (every token runs all "
+                f"{self.n_passes} passes; the logits are the last pass's)")
+        if (self.n_passes > 1 or self.post_norms) and (
+                self.mixer_types or self.n_experts or self.context_parallel
+                or self.pipeline_stages):
+            raise ValueError(
+                "n_passes > 1 / post_norms compose with the stacked dense "
+                "block on one chip or a tensor mesh: no mixer_types, MoE, "
+                "context_parallel or pipeline_stages")
         if not self.mixer_types:
             return
         if len(self.mixer_types) != self.n_layers:
@@ -182,6 +212,16 @@ class TransformerConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def cache_layers(self) -> int:
+        """The layers of K/V a row holds, the one number every K/V store
+        is sized by: one per (pass, layer) of the stack, index
+        ``pass * n_layers + layer``; of a ``mixer_types`` model, its
+        sparse layers alone."""
+        if self.mixer_types:
+            return len(self.layers_of(MIXER_SPARSE))
+        return self.n_layers * self.n_passes
 
     @property
     def sparse_spec(self) -> SparseSpec:
@@ -280,6 +320,22 @@ PRESETS: dict[str, TransformerConfig] = {
         sparse_topk=6, sparse_init_blocks=1, sparse_window=16,
         sparse_dense_len=32, lightning_chunk=8,
     ),
+    # Ouro-2.6B as published (ByteDance/Ouro-2.6B config.json): 48 layers
+    # run total_ut_steps = 4 times a token, sandwich norms, MHA 16 x 128,
+    # untied head; early_exit_threshold 1.0, so the logits are the fourth
+    # pass's. Serving only; 192 cache layers = 1.5 MiB of K/V a token
+    # (docs/serving.md says what refuses it).
+    "ouro-2.6b": TransformerConfig(
+        vocab_size=49_152, d_model=2048, n_layers=48, n_heads=16,
+        n_kv_heads=16, d_ff=5632, max_seq_len=65_536, rope_theta=1e6,
+        norm_eps=1e-6, remat=False, n_passes=4, post_norms=True,
+    ),
+    # The same loop at toy widths: what the CPU tests serve.
+    "ouro-test-tiny": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        d_ff=128, max_seq_len=128, rope_theta=1e6, norm_eps=1e-6,
+        remat=False, n_passes=4, post_norms=True,
+    ),
 }
 
 
@@ -343,6 +399,15 @@ def init(key, cfg: TransformerConfig):
         },
         "final_norm": jnp.ones((d,), jnp.float32),
     }
+    if cfg.post_norms:
+        for name in ("ln_attn_post", "ln_mlp_post"):
+            params["layers"][name] = jnp.ones((cfg.n_layers, d), jnp.float32)
+    if cfg.n_passes > 1:
+        # The exit gate is the model's, so it is in the tree; at
+        # exit_threshold 1.0 no forward reads it.
+        params["exit_gate"] = {
+            "kernel": dense(jax.random.fold_in(key, 97), (d, 1), d),
+            "bias": jnp.zeros((1,), jnp.float32)}
     if not cfg.tie_embeddings:
         params["lm_head"] = {
             "kernel": dense(jax.random.fold_in(key, 99), (d, cfg.vocab_size), d)
@@ -743,11 +808,32 @@ def final_hidden(x, params, cfg: TransformerConfig):
     return x * cfg.head_scale if cfg.head_scale != 1.0 else x
 
 
+def post_norm(y, layer, name: str, cfg: TransformerConfig):
+    """A sublayer's output ``y`` through its after-norm ``layer[name]``
+    where the block has them (``cfg.post_norms``); ``y`` itself where it
+    has none, so a plain block's trace holds nothing of this."""
+    if not cfg.post_norms:
+        return y
+    with scope(SCOPE_POST_NORM):
+        return rms_norm(y, layer[name], eps=cfg.norm_eps)
+
+
+def close_pass(x, params, cfg: TransformerConfig):
+    """The final norm, which closes a pass of the stack: the one before
+    the head, and in a looped stack also the input of the next pass
+    (there it carries the ``loop_norm`` scope)."""
+    if cfg.n_passes == 1:
+        return rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+    with scope(SCOPE_LOOP_NORM):
+        return rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
+
+
 def _layer_fn(cfg: TransformerConfig, mesh, rope, carry, layer):
     x, aux = carry
     act_spec = batch_partition_spec(cfg) + (None,)
     h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
-    x = x + _attention(h, layer["attn"], cfg, rope, mesh)
+    x = x + post_norm(_attention(h, layer["attn"], cfg, rope, mesh), layer,
+                      "ln_attn_post", cfg)
     x = _constrain(x, mesh, P(*act_spec))
     h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
     if cfg.n_experts:
@@ -755,7 +841,8 @@ def _layer_fn(cfg: TransformerConfig, mesh, rope, carry, layer):
         x = x + y
         aux = aux + layer_aux
     else:
-        x = x + _mlp(h, layer["mlp"], cfg)
+        x = x + post_norm(_mlp(h, layer["mlp"], cfg), layer, "ln_mlp_post",
+                          cfg)
     x = _constrain(x, mesh, P(*act_spec))
     return (x, aux), None
 
@@ -773,15 +860,17 @@ def _layer_fn_attn_saved(cfg: TransformerConfig, mesh, rope, mlp_policy,
     x, aux = carry
     act_spec = batch_partition_spec(cfg) + (None,)
     h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
-    x = x + _attention(h, layer["attn"], cfg, rope, mesh)
+    x = x + post_norm(_attention(h, layer["attn"], cfg, rope, mesh), layer,
+                      "ln_attn_post", cfg)
     x = _constrain(x, mesh, P(*act_spec))
 
     @functools.partial(jax.checkpoint, policy=mlp_policy)
-    def mlp_part(x, ln, mlp):
+    def mlp_part(x, ln, mlp, post):
         h = rms_norm(x, ln, eps=cfg.norm_eps)
-        return x + _mlp(h, mlp, cfg)
+        return x + post_norm(_mlp(h, mlp, cfg), post, "ln_mlp_post", cfg)
 
-    x = mlp_part(x, layer["ln_mlp"], layer["mlp"])
+    x = mlp_part(x, layer["ln_mlp"], layer["mlp"],
+                 {k: v for k, v in layer.items() if k == "ln_mlp_post"})
     x = _constrain(x, mesh, P(*act_spec))
     return (x, aux), None
 
@@ -925,21 +1014,28 @@ def hidden_states(params, tokens, cfg: TransformerConfig, *, mesh=None):
                 ),
                 params["layers"],
             )
-            carry, _ = lax.scan(group_fn, carry, grouped)
+
+            def stack(carry):
+                return lax.scan(group_fn, carry, grouped)[0]
         else:
             if cfg.remat and not attn_saved:
                 # llm_attn checkpoints inside the layer fn (FFN half only).
                 layer_fn = jax.checkpoint(layer_fn, policy=policy)
-            if cfg.scan_layers:
-                carry, _ = lax.scan(layer_fn, carry, params["layers"])
-            else:
+
+            def stack(carry):
+                if cfg.scan_layers:
+                    return lax.scan(layer_fn, carry, params["layers"])[0]
                 for i in range(cfg.n_layers):
                     layer = jax.tree.map(lambda w: w[i], params["layers"])
                     carry, _ = layer_fn(carry, layer)
-        x, aux = carry
+                return carry
+        # A looped stack runs the same layers n_passes times, each pass
+        # closed by the final norm, whose output the next one takes.
+        x, aux = stack(carry)
+        for _ in range(1, cfg.n_passes):
+            x, aux = stack((close_pass(x, params, cfg), aux))
 
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps)
-    return x, aux
+    return close_pass(x, params, cfg), aux
 
 
 def head_kernel(params, cfg: TransformerConfig):

@@ -63,6 +63,13 @@ SCOPE_LINEAR_ATTN = "linear_attn"
 SCOPE_SPARSE_SELECT = "sparse_select"
 SCOPE_SPARSE_ATTN = "sparse_attn"
 MIXER_SCOPES = (SCOPE_LINEAR_ATTN, SCOPE_SPARSE_SELECT, SCOPE_SPARSE_ATTN)
+# Norms only a looped stack has (models/transformer.py: ``n_passes``,
+# ``post_norms``): the final norm that closes every pass and hands its
+# output to the next, and a block's two after-norms (one inside each
+# residual branch). A tuple of their own for the same reason.
+SCOPE_LOOP_NORM = "loop_norm"
+SCOPE_POST_NORM = "post_norm"
+LOOP_SCOPES = (SCOPE_LOOP_NORM, SCOPE_POST_NORM)
 
 # Host spans of the scheduler thread (serving/continuous.py:_run): one
 # ``sched.round`` per pass of the loop, its children named by phase.

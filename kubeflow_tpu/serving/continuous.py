@@ -92,7 +92,7 @@ from kubeflow_tpu.models.decode import (
     store_prefix_row,
     verify_chunk,
 )
-from kubeflow_tpu.models.transformer import MIXER_SPARSE, serving_params
+from kubeflow_tpu.models.transformer import serving_params
 from kubeflow_tpu.observability.metrics import MetricRegistry
 from kubeflow_tpu.observability.tracing import (
     PHASE_COUNTER,
@@ -305,6 +305,33 @@ def _check_hybrid(**using) -> None:
                 "model with recurrent state and compressed keys")
 
 
+def _check_looped(**using) -> None:
+    """A looped stack (``n_passes`` > 1) holds K/V of every pass and runs
+    its layers several times a token. What shards the stack by layer
+    ranges is refused by name at construction, and so is what exports
+    K/V blocks to another holder (the payloads carry ``cache_layers``
+    layers and import as they are, but no test has moved a looped
+    model's). Everything else that sizes or moves K/V does so by
+    ``cfg.cache_layers``: both layouts, int8 KV, the fused read, the
+    prefix cache, speculation, chunked admission, tensor parallelism
+    (tests/test_looped_stack.py serves four passes through each)."""
+    refused = {
+        "pp_stages": "pipeline parallelism (pp_stages > 1): a stage would "
+                     "hold a range of layers that every pass runs again",
+        "cp_shards": "context parallelism (cp_shards > 1): the ring read "
+                     "has not been run over a looped stack's cache layers",
+        "host_kv_bytes": "stream suspension to the host tier "
+                         "(host_kv_bytes)",
+        "role": "the prefill/decode handoff (role)",
+        "kv_directory": "the fleet KV economy (kv_directory / cold_store)",
+    }
+    for option, on in using.items():
+        if on:
+            raise ValueError(
+                f"n_passes > 1: {refused[option]} is not supported for a "
+                "stack that runs several times a token")
+
+
 def _weights_footprint(params) -> tuple[int, str]:
     """(bytes of every leaf of a serving tree, dtype of its matrices)."""
     nbytes = sum(int(leaf.nbytes) for leaf in jax.tree.leaves(params))
@@ -376,6 +403,12 @@ class ContinuousDecoder:
                 host_kv_bytes=host_kv_bytes > 0, role=bool(role),
                 kv_directory=(kv_directory is not None
                               or cold_store is not None))
+        if cfg.n_passes > 1:
+            _check_looped(pp_stages=self.pp_stages > 1,
+                          cp_shards=self.cp_shards > 1,
+                          host_kv_bytes=host_kv_bytes > 0, role=bool(role),
+                          kv_directory=(kv_directory is not None
+                                        or cold_store is not None))
         if self.tp_shards > 1:
             if cfg.n_kv_heads % self.tp_shards:
                 raise ValueError(
@@ -562,6 +595,16 @@ class ContinuousDecoder:
         # while a row's drafts keep missing (verify compute is then pure
         # overhead), recover on clean sweeps.
         self._slot_k = [self.speculative_k] * slots
+        # K/V bytes one resident token costs, PER CHIP (a tp-sharded
+        # store holds Hkv / tp heads per position on each chip, and the
+        # fill gauges must reflect the HBM a chip actually spends), over
+        # the model's CACHE layers, not its depth: only the sparse layers
+        # of a mixer_types model hold K/V, and a looped stack holds K/V
+        # of every pass.
+        self.kv_bytes_per_token = kv_bytes_per_token(
+            cfg.cache_layers, cfg.n_kv_heads, cfg.head_dim,
+            jnp.dtype(cfg.dtype).itemsize, kv_dtype,
+            tp_shards=self.tp_shards)
         if kv_layout == "paged":
             self.kv_block_size = max(1, int(kv_block_size))
             if self.total_len % self.kv_block_size:
@@ -580,18 +623,9 @@ class ContinuousDecoder:
                 raise ValueError(
                     f"kv_pool_blocks {num_blocks} cannot back even one "
                     f"worst-case sequence ({mb} blocks)")
-            # Bytes are priced PER CHIP: a tp-sharded pool holds
-            # Hkv / tp heads per position on each chip, and the fill
-            # gauges must reflect the HBM a chip actually spends.
-            # Only the sparse layers of a mixer_types model hold K/V.
-            kv_layers = (len(cfg.layers_of(MIXER_SPARSE)) if cfg.mixer_types
-                         else cfg.n_layers)
             self._alloc = BlockAllocator(
                 num_blocks, self.kv_block_size,
-                bytes_per_token=kv_bytes_per_token(
-                    kv_layers, cfg.n_kv_heads, cfg.head_dim,
-                    jnp.dtype(cfg.dtype).itemsize, kv_dtype,
-                    tp_shards=self.tp_shards))
+                bytes_per_token=self.kv_bytes_per_token)
             self._max_blocks_per_seq = mb
             # Host mirror of the device block table; sentinel
             # ``num_blocks`` marks unallocated entries (writes through
@@ -656,10 +690,11 @@ class ContinuousDecoder:
         self._host_tier = (HostKvTier(int(host_kv_bytes))
                            if host_kv_bytes else None)
         # Host-global bytes one tiered token costs (the tier holds the
-        # gathered, unsharded payload even under tp).
+        # gathered, unsharded payload even under tp), every cache layer.
         self._host_bytes_per_token = (
-            kv_bytes_per_token(cfg.n_layers, cfg.n_kv_heads, cfg.head_dim,
-                               jnp.dtype(cfg.dtype).itemsize, kv_dtype)
+            kv_bytes_per_token(cfg.cache_layers, cfg.n_kv_heads,
+                               cfg.head_dim, jnp.dtype(cfg.dtype).itemsize,
+                               kv_dtype)
             if self._alloc is not None else 0)
         # Fleet KV economy (HBM -> host -> PEER -> COLD): the shared
         # prefix->holder directory (serving/kv_directory.py), the
@@ -767,6 +802,14 @@ class ContinuousDecoder:
         self.sparse_tokens_in_context = 0  # tokens the rows held
         self.rows_dense = 0                # row-steps at or under dense_len
         self.rows_sparse = 0               # row-steps over it
+        # Looped-stack counter (zero unless n_passes > 1): per emitted
+        # token the length of its row, i.e. the tokens each of the step's
+        # cache layers attended, from the lengths the host has. Such a
+        # model routes tokens through _dispatch_looped; a plain model's
+        # round runs no statement of this.
+        self.kv_tokens_attended = 0
+        if cfg.n_passes > 1:
+            self._dispatch = self._dispatch_looped
         self.kv_blocks_peak = 0      # high-water blocks_in_use
         self.peak_in_flight = 0      # high-water concurrent requests
         # Counter mutations and metrics() reads go through this lock so
@@ -2795,6 +2838,21 @@ class ContinuousDecoder:
             for t, n in tenant_tok.items():
                 self._tenant_served[t] = self._tenant_served.get(t, 0.0) + n
 
+    def _dispatch_looped(self, toks: np.ndarray, emitted: np.ndarray) -> None:
+        """:meth:`_dispatch` for a looped stack (bound over it at
+        construction where ``n_passes`` > 1): counts, per emitted token,
+        the tokens its row held when the step that emitted it read the
+        row, then routes as every model does. Plain decode steps and
+        admissions' steps come through here; a verify round's tokens
+        (``speculative_k``) do not, and are not counted."""
+        attended = sum(
+            len(req.tokens) + len(req.out) + 1
+            for slot, req in enumerate(self._slot_req)
+            if req is not None and emitted[slot])
+        ContinuousDecoder._dispatch(self, toks, emitted)
+        with self._mlock:
+            self.kv_tokens_attended += attended
+
     def _dispatch_block(self, toks: np.ndarray, emitted: np.ndarray) -> None:
         """Route one verify step's tokens ([slots, K+1], ``emitted`` a
         per-row prefix mask) to their requests — the multi-token sibling
@@ -3391,6 +3449,9 @@ class ContinuousDecoder:
                 "sparse_tokens_in_context": self.sparse_tokens_in_context,
                 "rows_dense": self.rows_dense,
                 "rows_sparse": self.rows_sparse,
+                "cache_layers": self.cfg.cache_layers,
+                "loop_passes": self.steps * self.cfg.n_passes,
+                "kv_tokens_attended": self.kv_tokens_attended,
                 "kv_cow_copies": self.kv_cow_copies,
                 "kv_shared_blocks": self.kv_shared_blocks,
                 "kv_defer_admissions": self.kv_defer_admissions,
@@ -3453,8 +3514,7 @@ class ContinuousDecoder:
             # kv_dtype (an int8 block is ~half an fp block).
             snap["kv_dtype"] = self.kv_dtype if self._alloc else "fp"
             snap["kv_fused"] = self.kv_fused
-            snap["kv_bytes_per_token"] = (self._alloc.bytes_per_token
-                                          if self._alloc else 0)
+            snap["kv_bytes_per_token"] = self.kv_bytes_per_token
             snap["kv_bytes_in_use"] = (self._alloc.bytes_in_use
                                        if self._alloc else 0)
             snap["kv_bytes_total"] = (self._alloc.bytes_total

@@ -34,13 +34,19 @@ from __future__ import annotations
 KV_SCALE_BYTES = 4
 
 
-def kv_bytes_per_token(n_layers: int, n_kv_heads: int, head_dim: int,
+def kv_bytes_per_token(cache_layers: int, n_kv_heads: int, head_dim: int,
                        fp_bytes: int, kv_dtype: str = "fp",
                        tp_shards: int = 1) -> int:
-    """HBM bytes one resident K+V position costs in the paged pool,
-    PER CHIP.
+    """HBM bytes one resident K+V position costs, PER CHIP, in the paged
+    pool or a dense row alike.
 
-    ``fp``: ``2 * L * Hkv * hd * fp_bytes``. ``int8``: the payload drops
+    ``cache_layers`` is the model's ``TransformerConfig.cache_layers``,
+    the layers of K/V a token holds — NOT its depth: a stack run four
+    times a token holds four (Ouro-2.6B: 192 cache layers of 16 heads of
+    128 at bf16 = 1,572,864 bytes a token, beside 48 layers' weights),
+    a ``mixer_types`` model only its sparse layers'.
+
+    ``fp``: ``2 * cache_layers * Hkv * hd * fp_bytes``. ``int8``: the payload drops
     to one byte per element but each (position, head) carries a
     :data:`KV_SCALE_BYTES` scale, so the per-head cost is
     ``hd + KV_SCALE_BYTES`` — the honest number an autoscaler must see
@@ -66,7 +72,7 @@ def kv_bytes_per_token(n_layers: int, n_kv_heads: int, head_dim: int,
         per_head = head_dim * fp_bytes
     else:
         raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
-    return 2 * n_layers * (n_kv_heads // tp_shards) * per_head
+    return 2 * cache_layers * (n_kv_heads // tp_shards) * per_head
 
 
 class BlockAllocator:

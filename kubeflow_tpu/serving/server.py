@@ -575,6 +575,14 @@ class ModelServer:
                                 d["sparse_tokens_in_context"],
                             "serving_rows_dense_total": d["rows_dense"],
                             "serving_rows_sparse_total": d["rows_sparse"],
+                            # K/V is held per CACHE layer (a looped
+                            # stack: n_layers x n_passes), which is what
+                            # serving_kv_bytes_per_token prices; the
+                            # weights beside it are n_layers' alone.
+                            "serving_cache_layers": d["cache_layers"],
+                            "serving_loop_passes_total": d["loop_passes"],
+                            "serving_kv_tokens_attended_total":
+                                d["kv_tokens_attended"],
                             "serving_kv_blocks_total": d["kv_blocks_total"],
                             "serving_kv_blocks_in_use":
                                 d["kv_blocks_in_use"],
