@@ -788,6 +788,28 @@ def child_kernels() -> int:
     check("sparse_decode_attention[bf16 pool, block 64]", 3e-2,
           lambda: sparse("pallas"), lambda: sparse("xla"))
 
+    # --- dense decode attention at Ouro-2.6B's widths (16 query heads on 16
+    # KV heads) and Mistral's (32 on 8): a 3-layer store read at its middle
+    # layer, rows that hold nothing, one position, a chunk's edge and one
+    # past it, and all of a row of 1,280 (ten chunks of 128).
+    from kubeflow_tpu.ops.attention import dense_decode_attention
+
+    d_len = jnp.asarray([0, 1, 64, 128, 129, 840, 1280, 0], jnp.int32)
+    for d_hq, d_hkv in ((16, 16), (32, 8)):
+        dq = (4 * jax.random.normal(kq, (len(d_len), d_hq, 128))).astype(
+            jnp.bfloat16)
+        dk, dv = (jax.random.normal(k_, (3, len(d_len), 1280, d_hkv, 128)
+                                    ).astype(jnp.bfloat16) for k_ in (kk, kv))
+
+        def dense(impl):  # called by ``check`` inside this iteration
+            return jax.jit(
+                lambda q_, k_, v_, li, n: dense_decode_attention(
+                    q_, k_, v_, li, n, n_kv_heads=d_hkv,
+                    implementation=impl))(dq, dk, dv, jnp.int32(1), d_len)
+        check(f"dense_decode_attention[bf16 store, {d_hq} heads on "
+              f"{d_hkv}]", 3e-2, lambda: dense("pallas"),
+              lambda: dense("xla"))
+
     # --- rms_norm, forward and (custom-VJP) backward, training shape.
     x = jax.random.normal(kq, (8, SEQ_LEN, d)).astype(jnp.bfloat16)
     w = 1.0 + 0.1 * jax.random.normal(kk, (d,), jnp.float32)

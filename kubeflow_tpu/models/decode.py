@@ -35,6 +35,8 @@ from kubeflow_tpu.observability.tracing import (
 )
 from kubeflow_tpu.ops import rms_norm
 from kubeflow_tpu.ops.attention import (
+    dense_decode_attention,
+    dense_decode_implementation,
     paged_decode_attention,
     paged_span_attention,
     ring_span_attention,
@@ -429,7 +431,8 @@ def _store_rows(store, layer, table):
 
 @scope(SCOPE_ATTN)
 def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
-                      valid, table=None, fused=False, mesh=None):
+                      valid, token_valid, table=None, fused=False,
+                      mesh=None):
     """Single-token attention of layer ``li`` (traced: the layer loop's
     index) where row ``b`` writes cache slot ``pos_b[b]`` — the
     continuous-batching variant of :func:`_cached_attention` (rows at
@@ -442,6 +445,13 @@ def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
     sliced out and written back, which is what a cache scanned as
     ``xs``/``ys`` costs (:func:`_layer_loop`). Returns
     (out, k_store, v_store).
+
+    Where the dense cache's kernel compiles (a TPU, ``head_dim`` a
+    multiple of 128; ops/attention.py:dense_decode_attention) the read
+    stops at what each row holds: positions ``<= pos_b`` of a row that
+    emits (``token_valid``), nothing of one that does not, copied out of
+    the whole store in place. Under a ``mesh`` the kernel could not be
+    partitioned, and the XLA read stays.
 
     With ``table`` ([B, max_blocks]) the storage is the paged block pool
     ``[L, N, Bs, H, hd]``: the write scatters through the table and the
@@ -468,6 +478,12 @@ def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
         out = paged_decode_attention(
             q[:, 0], _layer_of(k_store, li), _layer_of(v_store, li), table,
             pos_b, n_kv_heads=cfg.n_kv_heads, mesh=mesh,
+        ).reshape(b, s, cfg.n_heads * cfg.head_dim)
+    elif (table is None and mesh is None and dense_decode_implementation(
+            cfg.head_dim, k_store.dtype) == "pallas"):
+        out = dense_decode_attention(
+            q[:, 0], k_store, v_store, li,
+            jnp.where(token_valid, pos_b + 1, 0), n_kv_heads=cfg.n_kv_heads,
         ).reshape(b, s, cfg.n_heads * cfg.head_dim)
     else:
         out = _gqa_attention(q, _store_rows(k_store, li, table),
@@ -577,12 +593,13 @@ def _admit_rows_body(state, params, cfg: TransformerConfig, slots,
     }, last
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "top_k", "eos_id"),
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "top_k", "eos_id", "mesh"),
                    donate_argnames=("state",))
 def admit_rows_and_step(state, params, cfg: TransformerConfig, slots,
                         prompt_tokens, prompt_lengths, remaining,
                         temperature, top_k: int = 0,
-                        eos_id: int | None = None):
+                        eos_id: int | None = None, mesh=None):
     """Fused admission: prefill ``[K, T0]`` prompts, scatter them into
     rows ``slots`` of the persistent state, AND run one decode step for
     every active row — a single dispatch, so the new requests' first
@@ -592,12 +609,15 @@ def admit_rows_and_step(state, params, cfg: TransformerConfig, slots,
     exactly as a separate ramp step would have advanced them. ``slots``
     may repeat indices only as bucket padding that duplicates a real
     admission verbatim (identical data per duplicate index keeps the
-    scatter deterministic). Returns (state, prefill last-logits [K, V],
-    sampled token [slots], emitted mask [slots])."""
+    scatter deterministic). ``mesh`` (static) is the serving mesh of a
+    sharded decoder, for the fused step as for :func:`decode_step`.
+    Returns (state, prefill last-logits [K, V], sampled token [slots],
+    emitted mask [slots])."""
     state, last = _admit_rows_body(state, params, cfg, slots,
                                    prompt_tokens, prompt_lengths,
                                    remaining, temperature)
-    state, tok, emit = _decode_step_body(state, params, cfg, top_k, eos_id)
+    state, tok, emit = _decode_step_body(state, params, cfg, top_k, eos_id,
+                                         mesh=mesh)
     return state, last, tok, emit
 
 
@@ -692,24 +712,27 @@ def _admit_prefix_body(state, params, cfg: TransformerConfig, slot, pool,
     }, last
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "top_k", "eos_id"),
+@functools.partial(jax.jit,
+                   static_argnames=("cfg", "top_k", "eos_id", "mesh"),
                    donate_argnames=("state",))
 def admit_prefix_and_step(state, params, cfg: TransformerConfig, slot, pool,
                           pool_slot, prefix_len, suffix_tokens, prompt_len,
                           remaining, temperature, top_k: int = 0,
-                          eos_id: int | None = None):
+                          eos_id: int | None = None, mesh=None):
     """Prefix-hit admission: gather pool row ``pool_slot``'s first
     ``prefix_len`` K/V positions into decode-state row ``slot``, prefill
     ONLY the suffix (``suffix_tokens`` [1, S], padded to a length
     bucket), and run one fused decode step — the prefix-reuse twin of
     :func:`admit_rows_and_step`, still a single dispatch. ``prefix_len``
     and ``prompt_len`` are traced, so one executable per suffix bucket
-    serves every cached prefix length. Returns (state, prefill
+    serves every cached prefix length. ``mesh`` as in
+    :func:`admit_rows_and_step`. Returns (state, prefill
     last-logits [1, V], sampled token [slots], emitted mask [slots])."""
     state, last = _admit_prefix_body(state, params, cfg, slot, pool,
                                      pool_slot, prefix_len, suffix_tokens,
                                      prompt_len, remaining, temperature)
-    state, tok, emit = _decode_step_body(state, params, cfg, top_k, eos_id)
+    state, tok, emit = _decode_step_body(state, params, cfg, top_k, eos_id,
+                                         mesh=mesh)
     return state, last, tok, emit
 
 
@@ -833,8 +856,8 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
 
     def attend(h, attn, k_store, v_store, li):
         return _ragged_attention(h, attn, cfg, rope_bt, k_store, v_store, li,
-                                 pos_b, valid, table=table, fused=fused,
-                                 mesh=mesh)
+                                 pos_b, valid, token_valid, table=table,
+                                 fused=fused, mesh=mesh)
 
     logits, k_new, v_new = _layer_loop(params, cfg, x, k_cache0, v_cache0,
                                        attend, token_valid[:, None])
@@ -902,7 +925,8 @@ def decode_step(state, params, cfg: TransformerConfig, top_k: int = 0,
     ``kv_fused`` (paged states only) reads the cache through the
     block-table attention kernel instead of the gathered dense view;
     ``mesh`` (static, a tensor-parallel serving mesh) routes that fused
-    read through the kernel's shard_map mesh twin."""
+    read through the kernel's shard_map mesh twin, and keeps a dense
+    cache's read the XLA one."""
     return _decode_step_body(state, params, cfg, top_k, eos_id, kv_fused,
                              mesh)
 

@@ -659,6 +659,237 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *,
     return out.reshape(b, hq, hd)
 
 
+# ---------------------------------------------------------------------------
+# Length-bounded decode attention over the dense store (models/decode.py's
+# single-token read where no block table is)
+# ---------------------------------------------------------------------------
+#
+# The dense cache is ``[cache layers, B, T, Hkv, hd]``: a row's positions
+# lie one after another, all KV heads of a position together. The XLA read
+# slices layer ``li`` out whole and masks: every row's T positions are read
+# whatever the row holds. The kernel below reads positions
+# ``0 .. length[row] - 1`` of each row out of the WHOLE store where it lies
+# (the layer index is a scalar it is handed, so no layer is sliced out and
+# nothing of the store's size is made), a chunk of positions at a time.
+
+# Positions a chunk of the kernel's inner loop copies and attends (timings
+# of the alternatives on a v5e: PERF.md, PR 37).
+_DENSE_CHUNK = 128
+
+
+def _dense_kernel_unsupported(head_dim: int, dtype) -> str | None:
+    """Why the compiled kernel cannot read a dense store of this head size
+    and dtype (None = it can)."""
+    if why := not_tpu():
+        return why
+    if head_dim % 128 or not jnp.issubdtype(dtype, jnp.floating):
+        return (f"head_dim {head_dim} must be a multiple of 128 and the "
+                f"store ({jnp.dtype(dtype).name}) floating-point")
+    return None
+
+
+def dense_decode_implementation(head_dim: int, dtype) -> str:
+    """What :func:`dense_decode_attention` runs when it is left to choose:
+    ``"pallas"`` where the kernel compiles (a TPU, lane-aligned heads, a
+    floating-point store), else ``"xla"``."""
+    return "xla" if _dense_kernel_unsupported(head_dim, dtype) else "pallas"
+
+
+def _dense_decode_xla(qg, k_store, v_store, li, lengths):
+    """The layer's rows sliced out whole and masked: qg [B, Hkv, G, hd]
+    → [B, Hkv, G, hd] in q's dtype."""
+    k = lax.dynamic_index_in_dim(k_store, li, 0, keepdims=False)
+    v = lax.dynamic_index_in_dim(v_store, li, 0, keepdims=False)
+    scores = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
+                        k.astype(jnp.float32)) * qg.shape[-1] ** -0.5
+    seen = (jnp.arange(k.shape[1])[None] < lengths[:, None])[:, None, None]
+    top = jnp.max(jnp.where(seen, scores, _NEG_INF), axis=-1, keepdims=True)
+    e = jnp.where(seen, jnp.exp(scores - top), 0.0)
+    p = e / jnp.maximum(e.sum(axis=-1, keepdims=True), 1e-30)
+    return jnp.einsum("bkgt,btkd->bkgd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(qg.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
+                         chunk: int = _DENSE_CHUNK, interpret: bool = False):
+    """The kernel of :func:`dense_decode_attention`. q [B, Hq, hd]; the
+    stores whole, in HBM; ``li`` (traced) and ``lengths`` [B] are
+    scalar-prefetched. Jitted on its own so that the kernel is traced once
+    a process and shape: a decoder jits a decode step into every admission
+    shape it serves, two dozen of them, and each would trace it again.
+
+    Grid (B,), one row a grid step, in order. A step's inner loop takes
+    ``chunk`` positions at a time: ``[chunk, Hkv, hd]`` of K and of V, one
+    contiguous run of the store each, one async copy each into one of two
+    VMEM buffers, the next chunk's copies (the next row's first chunk, at a
+    row's last) started before this chunk's are waited for. A chunk is
+    copied whole, so up to ``chunk - 1`` positions past a row's length are
+    read and masked; chunks past it are neither copied nor computed. A row
+    of length 0 copies and computes nothing and writes zeros.
+
+    All heads of a chunk are ONE product a side: scores
+    ``[Hq, hd] · [chunk·Hkv, hd]ᵀ`` with the columns of the other KV heads
+    masked, values ``[Hq, chunk·Hkv] · [chunk·Hkv, hd]``. A position's
+    heads lie along the sublanes of one tile, so a head's own ``[chunk,
+    hd]`` would be a strided gather; as it is the MXU sees each K and V
+    element once, as a product a head would, and the scores of a chunk
+    are ``Hq·Hkv·chunk`` floats. Scores, running maximum, normaliser and
+    accumulator are float32; K, V and the probabilities enter the MXU at
+    the store's dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hq, hd = q.shape
+    t, hkv = k_store.shape[2], k_store.shape[3]
+    group = hq // hkv
+    tc = min(chunk, t)
+    sm_scale = hd ** -0.5
+
+    def kernel(li_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+               sems, nxt_ref):
+        # nxt_ref[0]: the buffer the next chunk to compute lands in;
+        # nxt_ref[1]: whether the row before has started this row's first
+        # chunk.
+        row = pl.program_id(0)
+        layer, length = li_ref[0], len_ref[row]
+        chunks = pl.cdiv(length, tc)
+
+        def first(c):
+            # A last chunk that would pass the row's end starts earlier,
+            # and attends only what the chunk before it did not.
+            return jnp.minimum(c * tc, t - tc)
+
+        def copies(r, c, buf):
+            return [pltpu.make_async_copy(
+                hbm.at[layer, r, pl.ds(first(c), tc)], vmem.at[buf],
+                sems.at[s, buf])
+                for s, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
+
+        def start(r, c, buf):
+            for copy in copies(r, c, buf):
+                copy.start()
+
+        @pl.when(row == 0)
+        def _first_row():
+            nxt_ref[0] = 0
+            nxt_ref[1] = 0
+
+        @pl.when((chunks > 0) & (nxt_ref[1] == 0))
+        def _own_first_chunk():
+            start(row, 0, nxt_ref[0])
+
+        nxt_ref[1] = 0
+        qh = q_ref[...]
+
+        def body(c, carry):
+            m, l, acc = carry
+            buf = nxt_ref[0]
+            other = 1 - buf
+
+            @pl.when(c + 1 < chunks)
+            def _next_chunk():
+                start(row, c + 1, other)
+
+            after = jnp.minimum(row + 1, b - 1)
+
+            @pl.when((c + 1 == chunks) & (row + 1 < b)
+                     & (len_ref[after] > 0))
+            def _next_rows_first_chunk():
+                start(after, 0, other)
+                nxt_ref[1] = 1
+
+            nxt_ref[0] = other
+            k_copy, v_copy = copies(row, c, buf)
+            k_copy.wait()
+            k = k_buf[buf].reshape(tc * hkv, hd)
+            s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            pos = first(c) + col // hkv
+            seen = (col % hkv == head) & (pos >= c * tc) & (pos < length)
+            s = jnp.where(seen, s * sm_scale, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            e = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            fade = jnp.exp(m - m_new)
+            v_copy.wait()
+            v = v_buf[buf].reshape(tc * hkv, hd)
+            acc = acc * fade + jnp.dot(e.astype(v.dtype), v,
+                                       preferred_element_type=jnp.float32)
+            return m_new, l * fade + e.sum(axis=-1, keepdims=True), acc
+
+        _, l, acc = lax.fori_loop(
+            0, chunks, body,
+            (jnp.full((hq, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((hq, 1), jnp.float32),
+             jnp.zeros((hq, hd), jnp.float32)))
+        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    q_spec = pl.BlockSpec((None, hq, hd), lambda row, *_: (row, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, tc, hkv, hd), k_store.dtype),
+                pltpu.VMEM((2, tc, hkv, hd), v_store.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="dense_decode_attention",
+    )(jnp.reshape(li, (1,)).astype(jnp.int32),
+      jnp.clip(lengths, 0, t).astype(jnp.int32), q, k_store, v_store)
+
+
+def dense_decode_attention(q, k_store, v_store, li, lengths, *,
+                           n_kv_heads: int,
+                           implementation: str | None = None,
+                           interpret: bool = False):
+    """Single-token attention over cache layer ``li`` of the dense K/V
+    store, each row over the positions it holds.
+
+    q: [B, Hq, hd] (one decode token per row, already rotary-embedded);
+    k_store/v_store: the WHOLE stores [cache layers, B, T, Hkv, hd]; li:
+    the cache layer, traced or not; lengths: [B] — row ``b`` attends
+    positions ``< lengths[b]``, and a row of length 0 (a free slot, a row
+    that emits nothing this step) reads nothing and yields zeros. Returns
+    [B, Hq, hd] in q's dtype.
+
+    ``implementation``: None (:func:`dense_decode_implementation`),
+    "pallas" or "xla". An explicit "pallas" that cannot be compiled for
+    this backend or shape raises (``interpret=True`` runs it in the
+    interpreter anywhere). The kernel reads ``lengths[b]`` positions a row
+    in place; the XLA path slices the layer out whole and masks."""
+    b, hq, hd = q.shape
+    if hq % n_kv_heads:
+        raise ValueError(
+            f"query heads {hq} not a multiple of kv heads {n_kv_heads}")
+    if implementation is None:
+        implementation = dense_decode_implementation(hd, k_store.dtype)
+    elif implementation == "pallas" and not interpret:
+        if why := _dense_kernel_unsupported(hd, k_store.dtype):
+            raise ValueError(
+                f"dense_decode_attention(implementation='pallas'): {why}")
+    if implementation == "pallas":
+        return _dense_decode_pallas(q, k_store, v_store, li, lengths,
+                                    interpret=interpret)
+    if implementation == "xla":
+        return _dense_decode_xla(
+            q.reshape(b, n_kv_heads, hq // n_kv_heads, hd), k_store, v_store,
+            li, lengths).reshape(b, hq, hd)
+    raise ValueError(f"unknown implementation {implementation!r}")
+
+
 def _paged_span_xla(qg, k_pool, v_pool, table, pos, sm_scale):
     """Blockwise online-softmax walk for an S-wide query span. qg:
     [B, S, Hkv, G, hd]; pools: [N, Bs, Hkv, hd] (or quantized dicts);

@@ -102,6 +102,7 @@ from kubeflow_tpu.observability.tracing import (
     TraceStore,
     host_span,
 )
+from kubeflow_tpu.ops.attention import dense_decode_implementation
 from kubeflow_tpu.ops.sparse_attention import decode_implementation
 from kubeflow_tpu.serving.affinity import (
     DEFAULT_AFFINITY_TOKENS,
@@ -653,6 +654,15 @@ class ContinuousDecoder:
         self.sparse_attn_impl = (
             decode_implementation(self._sparse, cfg.head_dim)
             if self._sparse else "")
+        # What the dense cache's single-token read compiles to ("" where
+        # the K/V lies in a block pool): the kernel that stops at each
+        # row's length, or the whole-row XLA read (off the TPU, heads not
+        # lane-aligned, and under a mesh, where the kernel could not be
+        # partitioned).
+        cache = self._state.get("cache")
+        self.dense_attn_impl = (
+            "" if cache is None else "xla" if self.mesh is not None
+            else dense_decode_implementation(cfg.head_dim, cache["k"].dtype))
         self._admit_rows = max_admit_rows(cfg)
         if self.mesh is not None:
             # KV payload onto the mesh, head-sharded (and layer-sharded
@@ -669,8 +679,11 @@ class ContinuousDecoder:
                                                        self.mesh,
                                                        pp_axis=pp_axis)
         # The fused block-table kernel walks its mesh twin only under a
-        # tensor mesh; the gather path partitions under plain GSPMD.
-        self._kmesh = self.mesh if self.kv_fused else None
+        # tensor mesh, and the dense cache's kernel stands back for the
+        # XLA read under one; the gather path partitions under plain
+        # GSPMD.
+        self._kmesh = (self.mesh if self.kv_fused or self.dense_attn_impl
+                       else None)
         # Ring mesh for chunk dispatches: only cp > 1 routes the chunk's
         # span attention through the sequence-axis ring (decode steps
         # stay on the plain GSPMD path regardless).
@@ -1346,7 +1359,8 @@ class ContinuousDecoder:
                     self._state, self.params, self.cfg,
                     jnp.asarray(slots), jnp.asarray(toks),
                     jnp.asarray(lengths), jnp.asarray(wants),
-                    jnp.asarray(temps), self.top_k, self.eos_id)
+                    jnp.asarray(temps), self.top_k, self.eos_id,
+                    self._kmesh)
         with self._mlock:
             self.prefill_dispatches += 1
             self.admitted += k
@@ -1537,7 +1551,7 @@ class ContinuousDecoder:
                     jnp.asarray(toks), jnp.int32(len(req.tokens)),
                     jnp.int32(req.want_left),
                     jnp.float32(req.temperature),
-                    self.top_k, self.eos_id)
+                    self.top_k, self.eos_id, self._kmesh)
         return last, tok, emit
 
     def _begin_chunked(self, req: _Request, slot: int) -> None:
@@ -3445,6 +3459,7 @@ class ContinuousDecoder:
                                  if self._slot_k else 0.0),
                 "state_bytes": self.state_bytes,
                 "sparse_attn_impl": self.sparse_attn_impl,
+                "dense_attn_impl": self.dense_attn_impl,
                 "sparse_tokens_attended": self.sparse_tokens_attended,
                 "sparse_tokens_in_context": self.sparse_tokens_in_context,
                 "rows_dense": self.rows_dense,

@@ -569,6 +569,8 @@ class ModelServer:
                             "serving_state_bytes": d["state_bytes"],
                             "serving_sparse_attn_pallas":
                                 int(d["sparse_attn_impl"] == "pallas"),
+                            "serving_dense_attn_pallas":
+                                int(d["dense_attn_impl"] == "pallas"),
                             "serving_sparse_tokens_attended_total":
                                 d["sparse_tokens_attended"],
                             "serving_sparse_tokens_in_context_total":

@@ -167,7 +167,7 @@ def _slice_loop_forward(params, cfg, k, v, tok, pos_b, live, table, fused):
         v_l = jax.tree.map(lambda a: a[l][None], v)
         h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
         attn, k_l, v_l = D._ragged_attention(
-            h, layer["attn"], cfg, rope_bt, k_l, v_l, 0, pos_b, valid,
+            h, layer["attn"], cfg, rope_bt, k_l, v_l, 0, pos_b, valid, live,
             table=table, fused=fused)
         k = jax.tree.map(lambda a, s: a.at[l].set(s[0]), k, k_l)
         v = jax.tree.map(lambda a, s: a.at[l].set(s[0]), v, v_l)

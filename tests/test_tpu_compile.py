@@ -123,17 +123,32 @@ def test_the_block_selection_sorts_down_the_sublanes_on_v5e(
 # and 52.3 MB (the fused kernel still relays one layer's pool head-major
 # for its tiles).
 DECODE_STEP_TEMP_MB = 64
+# `reason-decode`'s: Ouro-2.6B whole, 192 cache layers of 4 rows of 1,280.
+# Its step's temporaries are three whole-stack transposed copies of wq, wk
+# and wv, 1,208.9 MB (PERF.md, PR 36); the dense read's kernel adds none.
+OURO_STEP_TEMP_MB = 1210
 
 
-@pytest.mark.parametrize("layout", ["dense", "paged", "paged-kv_fused"])
+@pytest.mark.parametrize("layout", ["dense", "dense-ouro-2.6b", "paged",
+                                    "paged-kv_fused"])
 def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
                                                       no_compile_cache,
-                                                      layout):
+                                                      monkeypatch, layout):
     from kubeflow_tpu.models import decode, transformer
+    from kubeflow_tpu.ops import attention
 
     cfg = transformer.TransformerConfig(
         vocab_size=32768, d_model=4096, n_layers=6, n_heads=32, n_kv_heads=8,
         d_ff=14336, max_seq_len=768, rope_theta=1e6, dtype=jnp.bfloat16)
+    slots, total, most_temp_mb = 32, 768, DECODE_STEP_TEMP_MB
+    if layout == "dense-ouro-2.6b":
+        cfg = transformer.config("ouro-2.6b", dtype=jnp.bfloat16,
+                                 max_seq_len=1280)
+        slots, total, most_temp_mb = 4, 1280, OURO_STEP_TEMP_MB
+    if layout.startswith("dense"):
+        # The process's backend is the CPU, whatever is compiled for: the
+        # dense read asks, and on the chip the answer is the kernel.
+        monkeypatch.setattr(attention, "not_tpu", lambda: None)
 
     def described(tree):
         return jax.tree.map(
@@ -143,8 +158,9 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
     params = described(jax.eval_shape(
         lambda: transformer.serving_params(
             transformer.init(jax.random.PRNGKey(0), cfg), cfg)))
-    if layout == "dense":
-        state = jax.eval_shape(lambda: decode.init_decode_state(cfg, 32, 768))
+    if layout.startswith("dense"):
+        state = jax.eval_shape(
+            lambda: decode.init_decode_state(cfg, slots, total))
         store = state["cache"]["k"]
     else:
         state = jax.eval_shape(
@@ -153,9 +169,18 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
     compiled = decode.decode_step.lower(
         described(state), params, cfg,
         kv_fused=layout.endswith("kv_fused")).compile()
+    text = compiled.as_text()
     whole = "bf16[" + ",".join(map(str, store.shape)) + "]"
-    copies = [line.strip()[:120] for line in compiled.as_text().splitlines()
+    copies = [line.strip()[:120] for line in text.splitlines()
               if re.search(re.escape(whole) + r"\S* copy\(", line)]
     assert not copies, copies
     temp_mb = compiled.memory_analysis().temp_size_in_bytes / 1e6
-    assert temp_mb < DECODE_STEP_TEMP_MB, temp_mb
+    assert temp_mb < most_temp_mb, temp_mb
+    # The dense read is the length-bounded kernel, handed both stores whole
+    # (one call in the text: the layer loop's body); no other layout has it.
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "dense_decode_attention" in line]
+    assert len(calls) == (1 if layout.startswith("dense") else 0), calls
+    for line in calls:
+        layouts = line[line.index("operand_layout_constraints="):]
+        assert layouts[:layouts.index("}}")].count(whole) == 2, layouts[:300]
