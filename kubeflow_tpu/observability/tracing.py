@@ -25,14 +25,27 @@ Every name is a constant here; the program, the benchmark's readers, the
 tests and the docs import or quote these and nobody spells one twice. A
 ``sched.*`` span carries ``round=<n>``, the number a request's
 ``admitted`` and ``first_token`` timeline events carry too.
+
+The round is also the unit of the scheduler's own record: one
+:class:`RoundRecord` a pass of the loop (what kind it was, what it
+launched and routed, each phase's wall seconds, the thread's CPU
+seconds), built by the scheduler thread from the clock reads its phases
+make anyway. Under a capture its scalars ride the
+``sched.round`` span; always, it lands in the decoder's :class:`RoundLog`,
+two bounded rings served at ``/debug/rounds``, which also judges whether
+the round was slow and says so once in the log.
 """
 
 from __future__ import annotations
 
+import logging
+import statistics
 import threading
 import time
 import uuid
 from collections import deque
+
+log = logging.getLogger(__name__)
 
 REQUEST_ID_HEADER = "X-Request-ID"
 
@@ -77,6 +90,17 @@ SPAN_PREFIX = "sched."
 SPAN_ROUND = SPAN_PREFIX + "round"
 SCHED_PHASES = ("idle", "plan", "build", "dispatch", "fetch", "route")
 PHASE_COUNTER = "serving_scheduler_phase_seconds_total"
+# A round is slow when its wall time less its ``idle`` phase is over
+# SLOW_ROUND_FACTOR x the median of the recent rounds of its kind AND over
+# that median by SLOW_ROUND_EXCESS_S. (Twice the median was tried on the
+# chip, for the sake of 110-150 ms stalls on a 40 ms step: it also called
+# the last chunk of every long admission slow, 2.05 x an interior one.)
+SLOW_ROUND_FACTOR = 4.0
+SLOW_ROUND_EXCESS_S = 0.025
+SLOW_COUNTER = "serving_scheduler_slow_rounds_total"
+# Where a slow round's excess lies when no phase holds most of it: inside
+# the round and under no ``sched.<phase>`` span.
+PHASE_OTHER = "other"
 
 
 def scope(name: str):
@@ -278,6 +302,273 @@ class TraceStore:
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
+class RoundRecord:
+    """One pass of the scheduler loop, built by the scheduler thread alone
+    (no lock): ``round``, its start on the profiler's clock (``t_wall``,
+    ``time.time()``, as :attr:`Timeline.start_wall`) and its ``wall_s``;
+    the ``kind`` of its last dispatch; rows ``active`` at its start,
+    requests ``admitted``, ``prompt_tokens`` prefilled (chunks included);
+    ``phase_s``, wall seconds of each of :data:`SCHED_PHASES`;
+    ``host_cpu_s``, the thread's CPU seconds over the round
+    (``time.thread_time()``, read once where two rounds meet: a system
+    call, and on a sandboxed host a clock that moves 10 ms at a time, so
+    true of a long round or of a sum of many) — ``host_wall_s``, the
+    round less the phases that block by design, less ``host_cpu_s`` is
+    time the thread had work and was not running (the wait for the GIL,
+    or for the machine); ``launches``, the step dispatches it enqueued
+    (admission, chunk, verify, draft, decode: one XLA module execution
+    each) and
+    ``first_launch``, the first one's ordinal among all of this decoder's;
+    ``routed`` tokens handed to streams and ``routed_late``, those whose
+    gap since the stream's last token this round stretched: they came out
+    of a dispatch of another kind than ``decode``, or of a decode step
+    enqueued behind a chunk or a suspension; ``slow``, the phase that held
+    most of the excess if :class:`RoundLog` judged the round slow, else
+    ``""``, and ``median_s``, what it was judged against.
+
+    Rounds tile the thread's time: one starts where the last one ended, so
+    what falls under no phase is the round's too (:attr:`other_s`)."""
+
+    __slots__ = ("round", "t_wall", "wall_s", "busy_s", "kind", "active",
+                 "admitted", "prompt_tokens", "phase_s", "host_cpu_s",
+                 "launches", "first_launch", "routed", "routed_late", "slow",
+                 "median_s", "late", "_t0", "_cpu0")
+
+    def __init__(self, round_no: int, active: int, t0: float,
+                 t_wall: float, cpu0: float) -> None:
+        # What close() sets is left unset until then: a record is read
+        # only once its round has closed.
+        self.round = round_no
+        self.t_wall = t_wall
+        self._t0, self._cpu0 = t0, cpu0
+        self.active = active
+        self.prompt_tokens = 0
+        self.phase_s = dict.fromkeys(SCHED_PHASES, 0.0)
+        self.launches = self.first_launch = 0
+        self.routed = self.routed_late = 0
+        # Whether tokens routed NOW count as late: the route phase sets it.
+        self.late = False
+
+    def launched(self, ordinal: int) -> None:
+        if not self.launches:
+            self.first_launch = ordinal
+        self.launches += 1
+
+    def close(self, kind: str, admitted: int, now: float,
+              cpu: float) -> None:
+        self.kind, self.admitted = kind, admitted
+        self.wall_s = now - self._t0
+        self.busy_s = self.wall_s - self.phase_s["idle"]
+        self.host_cpu_s = cpu - self._cpu0
+        self.slow, self.median_s = "", 0.0
+
+    @property
+    def host_wall_s(self) -> float:
+        """The round less ``fetch`` and ``idle``: the four host phases
+        and what lies under no phase."""
+        return self.busy_s - self.phase_s["fetch"]
+
+    @property
+    def other_s(self) -> float:
+        """Seconds of the round under no phase."""
+        return self.wall_s - sum(self.phase_s.values())
+
+    def span_metadata(self) -> dict:
+        """The scalars the ``sched.round`` span carries as it closes
+        (``slow`` as 0 or 1: the profiler drops an empty string, and the
+        phase is the longest child)."""
+        return {"kind": self.kind, "admitted": self.admitted,
+                "prompt_tokens": self.prompt_tokens,
+                "launches": self.launches, "first_launch": self.first_launch,
+                "routed": self.routed, "routed_late": self.routed_late,
+                "host_wall_us": int(1e6 * self.host_wall_s),
+                "host_cpu_us": int(1e6 * self.host_cpu_s),
+                "slow": int(bool(self.slow))}
+
+    def to_dict(self) -> dict:
+        return {
+            "round": self.round, "t_unix": self.t_wall, "kind": self.kind,
+            "wall_ms": round(1e3 * self.wall_s, 3),
+            "phase_ms": {p: round(1e3 * s, 3)
+                         for p, s in self.phase_s.items()},
+            "other_ms": round(1e3 * self.other_s, 3),
+            "host_wall_ms": round(1e3 * self.host_wall_s, 3),
+            "host_cpu_ms": round(1e3 * self.host_cpu_s, 3),
+            "active": self.active, "admitted": self.admitted,
+            "prompt_tokens": self.prompt_tokens, "launches": self.launches,
+            "first_launch": self.first_launch, "routed": self.routed,
+            "routed_late": self.routed_late, "slow": self.slow,
+            "median_ms": round(1e3 * self.median_s, 3),
+        }
+
+    def line(self) -> str:
+        """The slow-round log line's body."""
+        ms = {p: 1e3 * s for p, s in self.phase_s.items()}
+        return (
+            f"slow round {self.round} kind={self.kind} "
+            f"{1e3 * self.busy_s:.1f} ms (median {1e3 * self.median_s:.1f}): "
+            f"fetch={ms['fetch']:.1f} route={ms['route']:.1f} "
+            f"dispatch={ms['dispatch']:.1f} build={ms['build']:.1f} "
+            f"plan={ms['plan']:.1f} other={1e3 * self.other_s:.1f} "
+            f"host_cpu={1e3 * self.host_cpu_s:.1f} active={self.active} "
+            f"admitted={self.admitted} routed={self.routed} "
+            f"t={self.t_wall:.3f}")
+
+
+class _KindWindow:
+    """The recent rounds of one kind and what a slow one is held to."""
+
+    __slots__ = ("recent", "n", "median_s", "limit_s")
+
+    def __init__(self, history: int) -> None:
+        self.recent: deque[RoundRecord] = deque(maxlen=history)
+        self.n = 0
+        self.median_s = 0.0
+        self.limit_s = float("inf")  # no median yet: nothing is slow
+
+
+class RoundLog:
+    """Where every :class:`RoundRecord` lands: the newest
+    :attr:`CAPACITY` rounds and the newest :attr:`SLOW_CAPACITY` slow ones,
+    written by the scheduler thread without a lock (a reader copies a
+    ring in one C call). :meth:`add` judges a round against the median of
+    the last :attr:`HISTORY` rounds of its kind that launched as many
+    steps (a round that ran a chunk AND a decode step is of kind
+    ``decode`` too, and many times a plain one), which it takes anew every
+    :attr:`MEDIAN_EVERY` such rounds (first after :attr:`MEDIAN_FIRST`),
+    so the test is one comparison a round."""
+
+    CAPACITY = 2048
+    SLOW_CAPACITY = 64
+    HISTORY = 255
+    MEDIAN_EVERY = 64
+    MEDIAN_FIRST = 16
+    LOG_EVERY_S = 1.0
+
+    def __init__(self, slow_counter=None) -> None:
+        self._recent: deque[RoundRecord] = deque(maxlen=self.CAPACITY)
+        self._slow: deque[RoundRecord] = deque(maxlen=self.SLOW_CAPACITY)
+        self._kinds: dict[tuple[str, int], _KindWindow] = {}
+        # ``labels(phase).inc()`` of SLOW_COUNTER on the owner's registry.
+        self._slow_counter = slow_counter
+        self.rounds = 0
+        self.slow_rounds = 0
+        self.slow_seconds = 0.0   # by which slow rounds exceeded the median
+        self.slow_by_phase: dict[str, int] = {}
+        self._logged_at = float("-inf")
+        self._unlogged = 0
+        self._summarised = False
+
+    def add(self, rec: RoundRecord) -> None:
+        kind = rec.kind, rec.launches
+        window = self._kinds.get(kind)
+        if window is None:
+            window = self._kinds[kind] = _KindWindow(self.HISTORY)
+        if rec.busy_s > window.limit_s:
+            self._mark_slow(rec, window)
+        window.recent.append(rec)
+        window.n += 1
+        if window.n == self.MEDIAN_FIRST \
+                or window.n % self.MEDIAN_EVERY == 0:
+            median = statistics.median([r.busy_s for r in window.recent])
+            window.median_s = median
+            window.limit_s = max(SLOW_ROUND_FACTOR * median,
+                                 median + SLOW_ROUND_EXCESS_S)
+        self._recent.append(rec)
+        self.rounds += 1
+
+    def _mark_slow(self, rec: RoundRecord, window: _KindWindow) -> None:
+        """Name the phase that holds most of what the round took over a
+        usual round of its kind, phase by phase (medians taken here, on
+        the rare path)."""
+        def parts(r):
+            return {**r.phase_s, PHASE_OTHER: r.other_s}
+
+        usual = [parts(r) for r in window.recent]
+        over = {phase: s - statistics.median([u[phase] for u in usual])
+                for phase, s in parts(rec).items() if phase != "idle"}
+        rec.slow = max(over, key=over.get)
+        rec.median_s = window.median_s
+        self._slow.append(rec)
+        self.slow_rounds += 1
+        self.slow_seconds += rec.busy_s - window.median_s
+        self.slow_by_phase[rec.slow] = self.slow_by_phase.get(rec.slow, 0) + 1
+        if self._slow_counter is not None:
+            self._slow_counter.labels(rec.slow).inc()
+
+    def report(self, rec: RoundRecord) -> None:
+        """Log a slow round: one WARNING line, at most one a second; the
+        rounds passed over are counted into the next line."""
+        now = time.perf_counter()
+        if now - self._logged_at < self.LOG_EVERY_S:
+            self._unlogged += 1
+            return
+        skipped = (f" (+{self._unlogged} slow rounds not logged)"
+                   if self._unlogged else "")
+        self._logged_at, self._unlogged = now, 0
+        log.warning("%s%s", rec.line(), skipped)
+
+    def report_summary(self) -> None:
+        """The owner's ``stop()``: one WARNING line, once, when any round
+        was slow."""
+        line = self.summary()
+        if line and not self._summarised:
+            self._summarised = True
+            log.warning("%s", line)
+
+    def summary(self) -> str | None:
+        """Rounds, slow rounds by phase, the three slowest the slow ring
+        still holds; None when no round was slow."""
+        if not self.slow_rounds:
+            return None
+        by_phase = " ".join(f"{p}={n}" for p, n in sorted(
+            self.slow_by_phase.items(), key=lambda kv: -kv[1]))
+        slowest = "; ".join(
+            f"round {r.round} kind={r.kind} {1e3 * r.busy_s:.1f} ms in "
+            f"{r.slow}" for r in sorted(
+                self.slow(), key=lambda r: -r.busy_s)[:3])
+        return (f"scheduler rounds {self.rounds}, slow {self.slow_rounds} "
+                f"({by_phase}), {self.slow_seconds:.3f} s over their "
+                f"medians; slowest: {slowest}")
+
+    def recent(self) -> list[RoundRecord]:
+        return list(self._recent)
+
+    def slow(self) -> list[RoundRecord]:
+        return list(self._slow)
+
+    def snapshot(self, slow_only: bool = False) -> dict:
+        out = {"rounds_total": self.rounds, "slow_total": self.slow_rounds,
+               "slow_seconds": round(self.slow_seconds, 6),
+               "slow_by_phase": dict(self.slow_by_phase),
+               "slow": [r.to_dict() for r in self.slow()]}
+        if not slow_only:
+            out["rounds"] = [r.to_dict() for r in self.recent()]
+        return out
+
+    def chrome_trace(self, slow_only: bool = False) -> dict:
+        """Trace-event export: one track, a ``sched.round`` event a round
+        with its phases as children. A record keeps each phase's seconds
+        and not where in the round they lay, so the children are laid end
+        to end from the round's start in :data:`SCHED_PHASES`' order."""
+        events = [{"name": "thread_name", "ph": "M", "pid": 1, "tid": 1,
+                   "args": {"name": "scheduler rounds"}}]
+        for rec in self.slow() if slow_only else self.recent():
+            ts = rec.t_wall * 1e6
+            args = rec.to_dict()
+            del args["phase_ms"]
+            events.append({"name": SPAN_ROUND, "ph": "X", "pid": 1, "tid": 1,
+                           "ts": ts, "dur": rec.wall_s * 1e6, "args": args})
+            for phase, secs in rec.phase_s.items():
+                if secs:
+                    events.append({"name": SPAN_PREFIX + phase, "ph": "X",
+                                   "pid": 1, "tid": 1, "ts": ts,
+                                   "dur": secs * 1e6,
+                                   "args": {"round": rec.round}})
+                    ts += secs * 1e6
+        return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
 def render_debug(store: TraceStore, query: str = "") -> tuple[bytes, str]:
     """Shared ``/debug/requests`` responder: ``(body, content_type)``.
     Plain JSON snapshot by default; ``format=chrome`` in the query string
@@ -292,4 +583,20 @@ def render_debug(store: TraceStore, query: str = "") -> tuple[bytes, str]:
         payload = {"requests": store.find(params["id"][0])}
     else:
         payload = store.snapshot()
+    return json.dumps(payload, indent=1).encode(), "application/json"
+
+
+def render_rounds(rounds: RoundLog, query: str = "") -> tuple[bytes, str]:
+    """``/debug/rounds`` responder, as :func:`render_debug`: the two rings
+    as JSON; ``slow=1`` keeps the slow rounds only; ``format=chrome`` gives
+    the trace-event export."""
+    import json
+    from urllib.parse import parse_qs
+
+    params = parse_qs(query)
+    slow_only = params.get("slow", [""])[0] not in ("", "0")
+    if params.get("format", [""])[0] == "chrome":
+        payload = rounds.chrome_trace(slow_only)
+    else:
+        payload = rounds.snapshot(slow_only)
     return json.dumps(payload, indent=1).encode(), "application/json"

@@ -97,8 +97,11 @@ from kubeflow_tpu.observability.metrics import MetricRegistry
 from kubeflow_tpu.observability.tracing import (
     PHASE_COUNTER,
     SCHED_PHASES,
+    SLOW_COUNTER,
     SPAN_PREFIX,
     SPAN_ROUND,
+    RoundLog,
+    RoundRecord,
     TraceStore,
     host_span,
 )
@@ -932,9 +935,30 @@ class ContinuousDecoder:
             "(device_get), route (tokens to streams, finishes)",
             labels=("phase",))
         self._c_phase = {p: phase_seconds.labels(p) for p in SCHED_PHASES}
+        # One record a round (tracing.RoundRecord), the scheduler thread's
+        # alone: the newest in ``rounds``' rings, served at the model
+        # server's /debug/rounds beside ``trace``; the slow ones counted
+        # by the phase that held them up.
+        self.rounds = RoundLog(self.registry.counter(
+            SLOW_COUNTER,
+            "Scheduler rounds that took over 4x the median of the recent "
+            "rounds of their kind and over it by 25 ms, by the phase "
+            "that held most of the excess (other: under no phase)",
+            labels=("phase",)))
+        self._rec = RoundRecord(0, 0, 0.0, 0.0, 0.0)
+        # Step dispatches enqueued since construction (admission, chunk,
+        # verify, draft, decode: one XLA module execution each): the
+        # ordinal a sched.dispatch span carries as ``launch``, and the
+        # sched.fetch that waits for its result.
+        self._launches = 0
+        # A chunk or a suspension went to the device since the last decode
+        # step was enqueued: the next step's tokens reach their streams
+        # late by it.
+        self._behind = False
         self._ramp_streak = 0  # consecutive admission-only rounds
         # The plain decode step whose tokens are still on the device:
-        # (tokens, emitted mask, time of its dispatch), or None.
+        # (tokens, emitted mask, time of its dispatch, its launch ordinal,
+        # whether it was enqueued behind a chunk or a suspension), or None.
         self._inflight = None
         if self.prefix_cache is not None and self._alloc is not None:
             # Trie evictions must return the entry's refcounted blocks
@@ -1184,6 +1208,7 @@ class ContinuousDecoder:
         for req in queued + self._slot_req:
             if req is not None and not req.done.is_set():
                 self._finish(req, error=err)
+        self.rounds.report_summary()
 
     # ------------------------------------------------------------------
 
@@ -1334,7 +1359,8 @@ class ContinuousDecoder:
         # mid-traffic). The paged twin reads each slot's block-table row
         # (allocated at pop time) instead of scattering into dense rows.
         t_disp = time.perf_counter()
-        with self._phase("dispatch", "admit"), self._state_lock:
+        with self._phase("dispatch", "admit", launch=self._launches + 1), \
+                self._state_lock:
             # The weights epoch this admission's prefill runs under —
             # read inside the same lock scope that passes self.params
             # to the dispatch, so it can never stamp the wrong epoch.
@@ -1361,11 +1387,13 @@ class ContinuousDecoder:
                     jnp.asarray(lengths), jnp.asarray(wants),
                     jnp.asarray(temps), self.top_k, self.eos_id,
                     self._kmesh)
+            launch = self._launch()
+        prompt_tokens = sum(len(req.tokens) for req, _ in pending)
+        self._rec.prompt_tokens += prompt_tokens
         with self._mlock:
             self.prefill_dispatches += 1
             self.admitted += k
-            self.prefill_tokens += sum(len(req.tokens)
-                                       for req, _ in pending)
+            self.prefill_tokens += prompt_tokens
         # Fetch ONLY the fused step's tokens (one small transfer);
         # vocab-wide prefill logits stay on device behind a lazy
         # per-request resolver — an eager [K, V] fetch each admission
@@ -1375,7 +1403,7 @@ class ContinuousDecoder:
         # of this function they were freed after route had woken every
         # stream's reader, and the free lets go of the GIL — a
         # millisecond of this thread under no sched.* span.
-        with self._phase("fetch", "admit"):
+        with self._phase("fetch", "admit", launch=launch):
             tok, emit = jax.device_get((tok, emit))
             self._h_dispatch.labels("admit").observe(
                 time.perf_counter() - t_disp)
@@ -1478,10 +1506,12 @@ class ContinuousDecoder:
             toks = np.zeros((1, s), np.int32)
             toks[0, : len(suffix)] = suffix
         t_disp = time.perf_counter()
-        with self._phase("dispatch", "admit"):
+        with self._phase("dispatch", "admit", launch=self._launches + 1):
             last, tok, emit = self._dispatch_prefix(
                 req, slot, entry, prefix_len, toks)
+            launch = self._launch()
             req.pinned_prefix = entry
+            self._rec.prompt_tokens += len(suffix)
             with self._mlock:
                 self.prefill_dispatches += 1
                 self.admitted += 1
@@ -1489,7 +1519,7 @@ class ContinuousDecoder:
                 self.prefix_tokens_reused += prefix_len
                 self.prefix_suffix_tokens += len(suffix)
                 self.prefill_tokens += len(suffix)
-        with self._phase("fetch", "admit"):
+        with self._phase("fetch", "admit", launch=launch):
             tok, emit = jax.device_get((tok, emit))
             self._h_dispatch.labels("admit").observe(
                 time.perf_counter() - t_disp)
@@ -1617,7 +1647,8 @@ class ContinuousDecoder:
             bs = self.kv_block_size
             restart = False
         t_disp = time.perf_counter()
-        with self._phase("dispatch", "chunk"), self._state_lock:
+        with self._phase("dispatch", "chunk", launch=self._launches + 1), \
+                self._state_lock:
             if first:
                 # First chunk: stamp the weights epoch, CoW the plan's
                 # partially-shared tail block, and map the table row —
@@ -1657,14 +1688,19 @@ class ContinuousDecoder:
                         jnp.int32(slot), jnp.int32(pos),
                         jnp.asarray(toks), jnp.int32(take),
                         self.kv_fused, self._kmesh, ring=self._ring)
+                launch = self._launch()
         if restart:
             self._restart_chunked(req, slot)
             return False
+        self._rec.prompt_tokens += take
         if first and plan is not None and plan[1] % bs:
             with self._mlock:
                 self.kv_cow_copies += 1
         dt = time.perf_counter() - t_disp
         if not final:
+            # No tokens come back: the step enqueued next waits behind
+            # this chunk, and its tokens are late by it.
+            self._behind = True
             req.chunk_pos = pos + take
             with self._mlock:
                 self.prefill_chunks += 1
@@ -1683,7 +1719,7 @@ class ContinuousDecoder:
             self.prefill_dispatches += 1
             self.admitted += 1
             self.prefill_tokens += take
-        with self._phase("fetch", "chunk"):
+        with self._phase("fetch", "chunk", launch=launch):
             tok, emit = jax.device_get((tok, emit))
         self._h_dispatch.labels("admit").observe(dt)
         with self._phase("route", "chunk"):
@@ -2795,7 +2831,7 @@ class ContinuousDecoder:
         EOS parking already happened on device (``_decode_step_body``);
         the host only finishes the request and frees the slot."""
         now = time.perf_counter()
-        emitted_n, ttft_sum, ttft_n = 0, 0.0, 0
+        emitted_n, gapped_n, ttft_sum, ttft_n = 0, 0, 0.0, 0
         tenant_tok: dict[str, int] = {}
         spec = self._sparse
         attended = in_context = dense = sparse = 0
@@ -2828,6 +2864,7 @@ class ContinuousDecoder:
                     req.timeline.event("first_token", round=self._round)
             elif req.last_emit_t is not None:
                 self._h_itl.observe(now - req.last_emit_t)
+                gapped_n += 1
             req.last_emit_t = now
             req.stream.put(tok)
             emitted_n += 1
@@ -2841,6 +2878,7 @@ class ContinuousDecoder:
                 self._slot_req[slot] = None
                 self._active_count -= 1
                 self._finish(req, reason="eos" if hit_eos else "length")
+        self._count_routed(emitted_n, gapped_n)
         with self._mlock:
             self.tokens_emitted += emitted_n
             self.sparse_tokens_attended += attended
@@ -2851,6 +2889,15 @@ class ContinuousDecoder:
             self.ttft_count += ttft_n
             for t, n in tenant_tok.items():
                 self._tenant_served[t] = self._tenant_served.get(t, 0.0) + n
+
+    def _count_routed(self, emitted_n: int, gapped_n: int) -> None:
+        """Book one routed step on the round's record: ``gapped_n`` of its
+        ``emitted_n`` tokens followed an earlier token of their stream,
+        and came late if the route phase under way says so."""
+        rec = self._rec
+        rec.routed += emitted_n
+        if rec.late:
+            rec.routed_late += gapped_n
 
     def _dispatch_looped(self, toks: np.ndarray, emitted: np.ndarray) -> None:
         """:meth:`_dispatch` for a looped stack (bound over it at
@@ -2873,7 +2920,7 @@ class ContinuousDecoder:
         of :func:`_dispatch`. The device already capped each row at its
         budget and truncated at EOS, so the mask is trusted verbatim."""
         now = time.perf_counter()
-        emitted_n, ttft_sum, ttft_n = 0, 0.0, 0
+        emitted_n, gapped_n, ttft_sum, ttft_n = 0, 0, 0.0, 0
         tenant_tok: dict[str, int] = {}
         for slot in range(self.slots):
             req = self._slot_req[slot]
@@ -2902,6 +2949,7 @@ class ContinuousDecoder:
                                           + row_emitted)
                 if req.last_emit_t is not None:
                     self._h_itl.observe(now - req.last_emit_t)
+                    gapped_n += row_emitted
                 req.last_emit_t = now
             hit_eos = self.eos_id is not None and last_tok == self.eos_id
             if hit_eos or len(req.out) >= req.want:
@@ -2911,6 +2959,7 @@ class ContinuousDecoder:
                 self._slot_req[slot] = None
                 self._active_count -= 1
                 self._finish(req, reason="eos" if hit_eos else "length")
+        self._count_routed(emitted_n, gapped_n)
         with self._mlock:
             self.tokens_emitted += emitted_n
             self.ttft_sum += ttft_sum
@@ -2944,18 +2993,19 @@ class ContinuousDecoder:
             return False
         self._h_occupancy.observe(self._active_count)
         t_disp = time.perf_counter()
-        with self._phase("dispatch", "verify"):
+        with self._phase("dispatch", "verify", launch=self._launches + 1):
             with self._state_lock:
                 self._state, outs, emits = verify_chunk(
                     self._state, self.params, self.cfg, jnp.asarray(drafts),
                     jnp.asarray(dlens), self.top_k, self.eos_id,
                     self.kv_fused, self._kmesh)
+            launch = self._launch()
             with self._mlock:
                 self.dispatches += 1
                 self.spec_verify_dispatches += 1
                 self.steps += 2 * steps  # scoring + commit per verify
         self._ramp_streak = 0
-        with self._phase("fetch", "verify"):
+        with self._phase("fetch", "verify", launch=launch):
             outs, emits = jax.device_get((outs, emits))
             self._h_dispatch.labels("verify").observe(
                 time.perf_counter() - t_disp)
@@ -2990,7 +3040,10 @@ class ContinuousDecoder:
                 # consumes one, so the next step's slice starts after it.
                 asks.append((slot, req.tokens + req.out,
                              steps * self._slot_k[slot] + steps - 1))
+        drafted = self._spec.dispatches
         props = self._spec.propose(asks)
+        for _ in range(self._spec.dispatches - drafted):
+            self._launch()   # a draft model's dispatch; n-grams make none
         drafts = np.zeros((steps, self.slots, k_w), np.int32)
         dlens = np.zeros((steps, self.slots), np.int32)
         for slot, ctx, _n in asks:
@@ -3055,24 +3108,52 @@ class ContinuousDecoder:
             self._finish(req, error=err)
 
     @contextlib.contextmanager
-    def _phase(self, phase: str, kind: str):
+    def _phase(self, phase: str, kind: str, **args):
         """One phase of the current scheduler round: a ``sched.<phase>``
-        span on the profiler's clock (a no-op unless a capture is open)
-        and, always, its seconds on the phase counter."""
+        span on the profiler's clock (a no-op unless a capture is open;
+        ``args`` become its arguments beside ``round`` and ``kind``) and,
+        always, its wall seconds on the round's record."""
+        rec = self._rec
+        if phase == "route":
+            rec.late = kind != "decode"
         t0 = time.perf_counter()
-        with host_span(SPAN_PREFIX + phase, round=self._round, kind=kind):
+        with host_span(SPAN_PREFIX + phase, round=self._round, kind=kind,
+                       **args):
             yield
-        self._c_phase[phase].inc(time.perf_counter() - t0)
+        rec.phase_s[phase] += time.perf_counter() - t0
+
+    def _launch(self) -> int:
+        """Count the step dispatch just enqueued; its ordinal."""
+        self._launches += 1
+        self._rec.launched(self._launches)
+        return self._launches
 
     def _run(self) -> None:
+        clocks = time.perf_counter(), time.time(), time.thread_time()
         while True:
             self._round += 1
-            with host_span(SPAN_ROUND, round=self._round,
-                           active=self._active_count) as span:
+            rec = self._rec = RoundRecord(
+                self._round, self._active_count, *clocks)
+            with host_span(SPAN_ROUND, round=rec.round,
+                           active=rec.active) as span:
                 done = self._run_round()
                 if done is None:
                     return
-                span.set_metadata(kind=done[0], admitted=done[1])
+                # The next round starts here: rounds tile the thread's
+                # time, and what follows (the log line of a slow round
+                # with it) is under no phase of the next one. ONE read of
+                # the thread's CPU clock a round: it is a system call, 6
+                # us on a v5e host, where the other two are not.
+                clocks = time.perf_counter(), time.time(), time.thread_time()
+                rec.close(*done, clocks[0], clocks[2])
+                self.rounds.add(rec)
+                if span.is_enabled():
+                    span.set_metadata(**rec.span_metadata())
+            for phase, seconds in rec.phase_s.items():
+                if seconds:
+                    self._c_phase[phase].inc(seconds)
+            if rec.slow:
+                self.rounds.report(rec)
 
     def _plan_round(self):
         """Wait for work, then plan the round under the cv: shed, order,
@@ -3287,6 +3368,7 @@ class ContinuousDecoder:
             # Its freed blocks admit the blocked candidate on
             # the next round.
             self._suspend_stream(suspend_slot)
+            self._behind = True
         if pending:
             # Admission fuses prefill + insert + one decode step
             # into a single dispatch, so a new request's first
@@ -3360,7 +3442,7 @@ class ContinuousDecoder:
             return "chunk" if chunked else "admit"
         if self._spec is not None and self._spec_round():
             return "verify"
-        with self._phase("dispatch", "decode"):
+        with self._phase("dispatch", "decode", launch=self._launches + 1):
             self._h_occupancy.observe(self._active_count)
             t_disp = time.perf_counter()
             with self._state_lock:
@@ -3376,6 +3458,8 @@ class ContinuousDecoder:
                         self.top_k, self.eos_id, self.kv_fused,
                         self._kmesh,
                     )
+            launch = self._launch()
+            late, self._behind = self._behind, False
             with self._mlock:
                 self.steps += self.chunk_size
                 self.dispatches += 1
@@ -3385,7 +3469,8 @@ class ContinuousDecoder:
         # part of a round runs beside the device's, not between two
         # steps. A finish is seen a step late; that step emits nothing
         # for the row, which the device has parked.
-        last, self._inflight = self._inflight, (toks, emitted, t_disp)
+        last, self._inflight = self._inflight, (toks, emitted, t_disp,
+                                                launch, late)
         if last is not None:
             self._deliver(*last)
         if self._active_count == 0 or self._spec is not None:
@@ -3394,13 +3479,17 @@ class ContinuousDecoder:
             self._deliver_inflight()
         return "decode"
 
-    def _deliver(self, toks, emitted, t_disp: float) -> None:
-        """Fetch one plain decode dispatch's tokens and route them."""
-        with self._phase("fetch", "decode"):
+    def _deliver(self, toks, emitted, t_disp: float, launch: int,
+                 late: bool) -> None:
+        """Fetch one plain decode dispatch's tokens and route them.
+        ``launch`` is the dispatch's ordinal; ``late``, whether it was
+        enqueued behind a chunk or a suspension."""
+        with self._phase("fetch", "decode", launch=launch):
             toks, emitted = jax.device_get((toks, emitted))
             self._h_dispatch.labels("decode").observe(
                 time.perf_counter() - t_disp)
         with self._phase("route", "decode"):
+            self._rec.late = late
             if self.chunk_size > 1:
                 self._ramp_streak = 0
                 for k in range(self.chunk_size):
@@ -3503,6 +3592,8 @@ class ContinuousDecoder:
                 "warm_failed_shapes": self.warm_failed_shapes,
                 "warm_seconds": self.warm_seconds,
                 "warming": self.warming,
+                "slow_rounds": self.rounds.slow_rounds,
+                "slow_round_seconds": self.rounds.slow_seconds,
             }
         # The weights epoch swaps under the state lock; its own scope
         # (never nested with the other snapshot locks) keeps the read

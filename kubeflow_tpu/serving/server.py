@@ -27,6 +27,7 @@ from kubeflow_tpu.observability.tracing import (
     REQUEST_ID_HEADER,
     gen_request_id,
     render_debug,
+    render_rounds,
 )
 from kubeflow_tpu.serving.batcher import DynamicBatcher
 from kubeflow_tpu.serving.continuous import PromptTooLong
@@ -712,6 +713,17 @@ class ModelServer:
                     else:
                         body, ctype = render_debug(
                             server._decoder.trace,
+                            self.path.partition("?")[2])
+                        self._send(200, body.decode(), content_type=ctype)
+                elif self.path.partition("?")[0] == "/debug/rounds":
+                    # The scheduler's own record, a round each: the newest
+                    # rounds and the newest slow ones (?slow=1 for those
+                    # alone; ?format=chrome for a trace-event file).
+                    if server._decoder is None:
+                        self._send(200, {"rounds": [], "slow": []})
+                    else:
+                        body, ctype = render_rounds(
+                            server._decoder.rounds,
                             self.path.partition("?")[2])
                         self._send(200, body.decode(), content_type=ctype)
                 elif self.path.startswith("/v1/models/"):

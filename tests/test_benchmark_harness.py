@@ -8,3 +8,4 @@ from benchmarks.tests.test_harness import *  # noqa: F401,F403
 from benchmarks.tests.test_spans import *  # noqa: F401,F403
 from benchmarks.tests.test_sessions import *  # noqa: F401,F403
 from benchmarks.tests.test_looped import *  # noqa: F401,F403
+from benchmarks.tests.test_rounds import *  # noqa: F401,F403

@@ -546,9 +546,9 @@ def test_plain_decode_steps_run_one_ahead_of_their_tokens(model):
     ahead = []
     deliver = d._deliver
 
-    def spy(toks, emitted, t_disp):
+    def spy(*step):
         ahead.append((d.dispatches, d._inflight is not None))
-        deliver(toks, emitted, t_disp)
+        deliver(*step)
 
     d._deliver = spy
     try:

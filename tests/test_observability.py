@@ -318,6 +318,116 @@ def test_decoder_metrics_expose_histogram_quantiles(model):
         d.stop()
 
 
+def test_a_stalled_fetch_is_one_slow_round_logged_counted_and_served(
+        monkeypatch, caplog):
+    """A decoder whose ``fetch`` sleeps 0.3 s once: the round lands in the
+    slow ring with ``fetch`` as the phase that held it, is logged once at
+    WARNING after it ended, counted on ``/monitoring`` and served at
+    ``/debug/rounds?slow=1``; ``stop()`` sums the rounds up in one line."""
+    import time
+
+    from kubeflow_tpu.observability import tracing
+    from kubeflow_tpu.serving.engine import EngineConfig
+    from kubeflow_tpu.serving.server import ModelServer
+
+    server = ModelServer(EngineConfig(model="lm-test-tiny", batch_size=2,
+                                      max_seq_len=16, max_new_tokens=48,
+                                      decode_mode="continuous"), port=0)
+    server.start()
+    base = f"http://127.0.0.1:{server.port}"
+    d = server.decoder
+    try:
+        d.generate([1, 2, 3], 40, timeout=120)   # decode rounds get a median
+        real, stalls = jax.device_get, []
+
+        def stalling(tree):
+            if threading.current_thread() is d._thread and not stalls:
+                stalls.append(d._round)
+                time.sleep(0.3)
+            return real(tree)
+
+        stream = d.submit([1, 2, 3], 30)
+        next(stream.tokens(timeout=60))
+        with caplog.at_level("WARNING", logger=tracing.log.name):
+            monkeypatch.setattr(jax, "device_get", stalling)
+            stream.result(timeout=60)
+            monkeypatch.setattr(jax, "device_get", real)
+            # (A loaded machine may hold up another round: the stalled one
+            # is the one that took 0.3 s.)
+            stalled = [r for r in d.rounds.slow() if r.wall_s >= 0.3]
+            rec, = stalled
+            assert rec.slow == "fetch" and rec.round == stalls[0]
+            assert rec.phase_s["fetch"] >= 0.3 > rec.host_wall_s >= 0
+            assert rec.median_s < 0.1 and rec.kind == "decode"
+            lines = [r.getMessage() for r in caplog.records
+                     if r.getMessage().startswith(f"slow round {rec.round} ")]
+            assert len(lines) == 1 and "kind=decode" in lines[0]
+            assert all(r.levelname == "WARNING" for r in caplog.records)
+            m = d.metrics()
+            assert m["slow_rounds"] == len(d.rounds.slow()) >= 1
+            assert m["slow_round_seconds"] >= 0.25
+            with urllib.request.urlopen(
+                    base + "/monitoring/prometheus/metrics") as r:
+                text = r.read().decode()
+            assert lint(text) == []
+            in_fetch = sum(1 for r in d.rounds.slow() if r.slow == "fetch")
+            assert f'{tracing.SLOW_COUNTER}{{phase="fetch"}} {in_fetch}' \
+                in text
+            with urllib.request.urlopen(base + "/debug/rounds?slow=1") as r:
+                served = json.loads(r.read())
+            assert "rounds" not in served
+            assert served["slow_total"] == m["slow_rounds"]
+            one, = [x for x in served["slow"] if x["round"] == rec.round]
+            assert one["slow"] == "fetch" and one["phase_ms"]["fetch"] >= 300
+            with urllib.request.urlopen(base + "/debug/rounds") as r:
+                everything = json.loads(r.read())
+            assert len(everything["rounds"]) == d.rounds.rounds
+            with urllib.request.urlopen(
+                    base + "/debug/rounds?slow=1&format=chrome") as r:
+                chrome = json.loads(r.read())
+            events = [e for e in chrome["traceEvents"] if e["ph"] == "X"
+                      and e["args"]["round"] == rec.round]
+            assert events[0]["name"] == tracing.SPAN_ROUND
+            assert {e["name"] for e in events[1:]} >= {"sched.fetch"}
+            assert sum(e["dur"] for e in events[1:]) <= events[0]["dur"] + 1
+            caplog.clear()
+            server.stop()
+            summary, = [r.getMessage() for r in caplog.records]
+            assert summary.startswith("scheduler rounds ")
+            assert f"round {rec.round} kind=decode" in summary
+    finally:
+        server.stop()
+
+
+def test_a_busy_thread_beside_the_scheduler_shows_as_time_off_the_cpu(model):
+    """``host_wall_s - host_cpu_s`` is the time the scheduler thread had
+    work (the round less its ``fetch`` and ``idle``) and was not running.
+    With another Python thread spinning on the GIL beside it some of every
+    few rounds is that; no amount is asserted, only the sum's sign and that
+    a round's CPU seconds lie between 0 and the round's own."""
+    spec, params = model
+    d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
+                          max_new_tokens=64)
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(2000))
+
+    spinner = threading.Thread(target=spin, daemon=True)
+    try:
+        d.generate([1, 2, 3], 8, timeout=60)
+        spinner.start()
+        d.generate([1, 2, 3], 64, timeout=120)
+        stop.set()
+        recs = d.rounds.recent()
+        assert all(0 <= r.host_cpu_s <= r.wall_s + 1e-5 for r in recs)
+        assert sum(r.host_wall_s - r.host_cpu_s for r in recs) > 0
+    finally:
+        stop.set()
+        d.stop()
+
+
 def test_default_latency_buckets_are_log_spaced():
     b = DEFAULT_LATENCY_BUCKETS
     assert b[0] == pytest.approx(1e-4) and b[-1] == pytest.approx(1e2)

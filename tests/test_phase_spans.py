@@ -230,6 +230,226 @@ def test_round_kinds_show_the_ramp_streak_cap(model, tmp_path):
         d.stop()
 
 
+def _by_round(sched):
+    """``{round: {span name: [arguments, in start order]}}``."""
+    out = {}
+    for name, _, _, args in sched:
+        out.setdefault(args["round"], {}).setdefault(name, []).append(args)
+    return out
+
+
+DISPATCH = tracing.SPAN_PREFIX + "dispatch"
+FETCH = tracing.SPAN_PREFIX + "fetch"
+
+
+def test_every_dispatch_carries_its_launch_and_none_is_missing(model,
+                                                               tmp_path):
+    """Admit, chunk and decode rounds of a decoder built inside the
+    capture: the ``launch`` ordinals of its ``sched.dispatch`` spans run 1,
+    2, 3 … in start order, a ``sched.fetch`` names the launch whose result
+    it waits for, and a plain round's is the launch before its own
+    dispatch's (plain steps run one ahead)."""
+    spec, params = model
+    made = []
+
+    def work():
+        d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
+                              max_new_tokens=16, kv_layout="paged",
+                              kv_block_size=8, prefill_chunk_tokens=8,
+                              max_prompt_len=40)
+        made.append(d)
+        short = d.submit([1, 2, 3], 16)
+        next(short.tokens(timeout=120))          # live beside the chunks
+        long_req = d.submit(list(range(1, 29)), 3)   # 28 tokens: 4 chunks
+        assert len(long_req.result(timeout=120)["tokens"]) == 3
+        assert len(short.result(timeout=120)["tokens"]) == 16
+
+    try:
+        sched = _profile(tmp_path, work)
+        d, = made
+        dispatches = [args for name, _, _, args in sched if name == DISPATCH]
+        assert [a["launch"] for a in dispatches] == list(
+            range(1, len(dispatches) + 1))
+        assert len(dispatches) == d._launches
+        assert {a["kind"] for a in dispatches} == {"admit", "chunk", "decode"}
+        launched = {a["launch"] for a in dispatches}
+        fetches = [args for name, _, _, args in sched if name == FETCH]
+        assert fetches and all(a["launch"] in launched for a in fetches)
+        plain = [spans_ for spans_ in _by_round(sched).values()
+                 if [a["kind"] for a in spans_.get(DISPATCH, [])] == ["decode"]
+                 and len(spans_.get(FETCH, [])) == 1
+                 and tracing.SPAN_PREFIX + "build" not in spans_]
+        assert plain
+        for spans_ in plain:
+            assert spans_[FETCH][0]["launch"] \
+                == spans_[DISPATCH][0]["launch"] - 1
+        # The span's closing arguments are the record's (the capture may
+        # end before the last round's span does).
+        closed = {n: spans_[tracing.SPAN_ROUND][0]
+                  for n, spans_ in _by_round(sched).items()
+                  if tracing.SPAN_ROUND in spans_}
+        assert len(closed) >= len(d.rounds.recent()) - 1
+        for rec in d.rounds.recent():
+            if rec.round in closed:
+                args = closed[rec.round]
+                assert {k: args[k] for k in rec.span_metadata()} \
+                    == rec.span_metadata()
+        chunk_rounds = [r for r in d.rounds.recent() if r.kind == "chunk"
+                        or (r.kind == "decode" and r.prompt_tokens)]
+        assert sum(r.prompt_tokens for r in d.rounds.recent()) \
+            == d.metrics()["prefill_tokens"] == 3 + 28
+        assert chunk_rounds
+    finally:
+        for d in made:
+            d.stop()
+
+
+def _settled(d):
+    """The decoder's records once the round that served the last token has
+    closed (a result is handed back from inside its ``route``)."""
+    deadline = time.time() + 10
+    while time.time() < deadline and sum(
+            r.routed for r in d.rounds.recent()) \
+            < d.metrics()["tokens_emitted"]:
+        time.sleep(0.001)
+    return d.rounds.recent()
+
+
+def test_round_records_count_what_was_routed_and_what_came_late(model):
+    spec, params = model
+    d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
+                          max_new_tokens=48)
+    try:
+        d.generate([1, 2, 3], 20, timeout=60)        # plain rounds only
+        plain = _settled(d)
+        assert sum(r.routed for r in plain) == d.metrics()["tokens_emitted"]
+        assert all(r.routed_late == 0 for r in plain)
+        assert [r.round for r in plain] == list(range(1, len(plain) + 1))
+        first = d.submit([1, 2, 3], 48)
+        next(first.tokens(timeout=60))
+        second = d.submit([4, 5], 4)                 # admitted beside it
+        second.result(timeout=60)
+        first.result(timeout=60)
+        recs = _settled(d)
+        assert sum(r.routed for r in recs) == d.metrics()["tokens_emitted"]
+        beside, = [r for r in recs if r.admitted and r.active]
+        # The live row's token waited for the prefill; the new row's first
+        # token has no gap to stretch.
+        assert beside.routed_late == 1 and beside.kind == "admit"
+        assert beside.routed >= 2 and beside.prompt_tokens == 2
+        assert sum(r.routed_late for r in recs) == 1
+        for r in recs:
+            # One thread's CPU seconds never exceed the round's own (two
+            # clocks, read a fraction of a microsecond apart).
+            assert 0 <= r.host_cpu_s <= r.wall_s + 1e-5
+            assert 0 <= r.host_wall_s <= r.wall_s
+            assert r.launches in (0, 1) and r.other_s >= 0
+            assert abs(sum(r.phase_s.values()) + r.other_s - r.wall_s) < 1e-9
+        launches = [r.first_launch for r in recs if r.launches]
+        assert launches == list(range(1, len(launches) + 1))
+        # Rounds tile the thread's time: one starts where the last ended.
+        for a, b in zip(recs, recs[1:]):
+            assert abs(a.t_wall + a.wall_s - b.t_wall) < 5e-3
+    finally:
+        d.stop()
+
+
+def test_the_ring_holds_the_newest_rounds_and_drops_the_oldest():
+    rounds = tracing.RoundLog()
+    for n in range(1, rounds.CAPACITY + 101):
+        rec = tracing.RoundRecord(n, 0, 0.0, 0.0, 0.0)
+        rec.close("decode", 0, 0.001, 0.0)
+        rounds.add(rec)
+    kept = [r.round for r in rounds.recent()]
+    assert kept == list(range(101, rounds.CAPACITY + 101))
+    assert rounds.rounds == rounds.CAPACITY + 100 and rounds.CAPACITY == 2048
+    assert rounds.slow() == [] and rounds.summary() is None
+
+
+def _rounds_of(kind, seconds, **phases):
+    """A ``RoundLog`` fed ``seconds`` as rounds of ``kind``, the time in
+    ``fetch`` but for what ``phases`` names for the last round."""
+    rounds = tracing.RoundLog()
+    for n, wall in enumerate(seconds, start=1):
+        rec = tracing.RoundRecord(n, 1, 0.0, 100.0 + n, 0.0)
+        last = n == len(seconds)
+        for phase, s in (phases if last else {}).items():
+            rec.phase_s[phase] = s
+        rec.phase_s["fetch"] = wall - sum(rec.phase_s.values())
+        rec.close(kind, 0, wall, 0.0)
+        rounds.add(rec)
+    return rounds
+
+
+@pytest.mark.parametrize("last, slow", [
+    (0.0199, ""),          # 4 x the median, but not 25 ms over it
+    (0.031, "route"),      # both
+    (0.029, ""),           # under 25 ms over
+])
+def test_a_round_is_slow_by_both_rules_at_once(last, slow):
+    rounds = _rounds_of("decode", [0.005] * 64 + [last], route=last - 0.004)
+    assert [r.slow for r in rounds.slow()] == ([slow] if slow else [])
+    assert rounds.slow_rounds == (1 if slow else 0)
+    if slow:
+        rec, = rounds.slow()
+        assert rec.median_s == pytest.approx(0.005)
+        assert rounds.slow_seconds == pytest.approx(last - 0.005)
+        assert "slow round 65 kind=decode 31.0 ms (median 5.0)" in rec.line()
+        assert "in route" in rounds.summary()
+
+
+def test_a_slow_round_is_judged_against_its_own_kind_and_after_16():
+    # 40 ms is a usual admission and a slow decode round.
+    rounds = _rounds_of("admit", [0.040] * 20)
+    assert rounds.slow_rounds == 0
+    for n in range(64):
+        rec = tracing.RoundRecord(100 + n, 1, 0.0, 0.0, 0.0)
+        rec.phase_s["fetch"] = 0.005
+        rec.close("decode", 0, 0.005, 0.0)
+        rounds.add(rec)
+    rec = tracing.RoundRecord(200, 1, 0.0, 0.0, 0.0)
+    rec.phase_s["idle"] = 1.0               # waiting for work is not slow
+    rec.close("decode", 0, 1.005, 0.0)
+    rounds.add(rec)
+    assert rounds.slow_rounds == 0
+    rec = tracing.RoundRecord(201, 1, 0.0, 0.0, 0.0)
+    rec.close("decode", 0, 0.040, 0.0)           # under no phase at all
+    rounds.add(rec)
+    assert [r.slow for r in rounds.slow()] == [tracing.PHASE_OTHER]
+    # A round that ran a chunk and a decode step is of kind decode too:
+    # it is held to the rounds that launched as much, not to plain ones.
+    for n in range(17):
+        rec = tracing.RoundRecord(300 + n, 1, 0.0, 0.0, 0.0)
+        rec.launched(2 * n), rec.launched(2 * n + 1)
+        rec.close("decode", 0, 0.170, 0.0)
+        rounds.add(rec)
+    assert rounds.slow_rounds == 1
+    # Before its 16th round a kind has no median, and nothing is slow.
+    assert _rounds_of("verify", [0.001] * 15 + [5.0]).slow_rounds == 0
+    assert _rounds_of("verify", [0.001] * 16 + [5.0]).slow_rounds == 1
+
+
+def test_slow_rounds_are_logged_at_most_once_a_second(caplog):
+    rounds = _rounds_of("decode", [0.005] * 64)
+    with caplog.at_level("WARNING", logger=tracing.log.name):
+        for n in range(3):
+            rec = tracing.RoundRecord(70 + n, 1, 0.0, 0.0, 0.0)
+            rec.phase_s["fetch"] = 0.2
+            rec.close("decode", 0, 0.2, 0.0)
+            rounds.add(rec)
+            rounds.report(rec)
+        assert len(caplog.records) == 1
+        rounds._logged_at -= rounds.LOG_EVERY_S
+        rounds.report(rec)
+        rounds.report_summary()
+        rounds.report_summary()              # once
+    lines = [r.getMessage() for r in caplog.records]
+    assert len(lines) == 3
+    assert lines[0].startswith("slow round 70 kind=decode 200.0 ms")
+    assert lines[1].endswith("(+2 slow rounds not logged)")
+    assert lines[2].startswith("scheduler rounds 67, slow 3 (fetch=3)")
+
+
 def test_a_256_token_request_drops_no_timeline_event(model):
     spec, params = model
     d = ContinuousDecoder(params, spec.config, slots=2, prefill_len=16,
