@@ -62,6 +62,7 @@ from kubeflow_tpu.models.transformer import (
     mixer_qkv,
     moe_ffn,
     post_norm,
+    project,
     sparse_span,
 )
 
@@ -165,9 +166,9 @@ def _qkv_rope(x, layer, cfg, rope_bt):
     b, s, _d = x.shape
     hd = cfg.head_dim
     cos, sin = rope_bt
-    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, s, cfg.n_heads, hd)
-    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
-    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, s, cfg.n_kv_heads, hd)
+    q = project(x, layer, "q", cfg.dtype).reshape(b, s, cfg.n_heads, hd)
+    k = project(x, layer, "k", cfg.dtype).reshape(b, s, cfg.n_kv_heads, hd)
+    v = project(x, layer, "v", cfg.dtype).reshape(b, s, cfg.n_kv_heads, hd)
     return _rope(q, cos, sin), _rope(k, cos, sin), v
 
 
@@ -408,15 +409,45 @@ def _layer_of(store, layer):
         store)
 
 
-def _store_write(store, layer, table, cols, vals):
+_DENSE_WRITE = lax.ScatterDimensionNumbers(
+    update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
+    scatter_dims_to_operand_dims=(0, 1, 2))
+
+
+def _dense_write_index(cols):
+    """The ``[B, S, 3]`` scatter index of a dense write at ``[0, row,
+    cols[row]]``. :func:`_dense_write` adds the layer, so a caller builds
+    the rest once, outside the layer loop, where an op runs once a step
+    and not once a layer application (192 a step of a looped stack)."""
+    b, s = cols.shape
+    rows = jnp.broadcast_to(jnp.arange(b, dtype=jnp.int32)[:, None], (b, s))
+    return jnp.stack([jnp.zeros_like(rows), rows, cols.astype(jnp.int32)],
+                     -1)
+
+
+def _dense_write(store, layer, index, vals):
+    """Write ``vals`` [B, S, Hkv, hd] into the dense cache at ``index``
+    (:func:`_dense_write_index`) of layer ``layer``: one in-place scatter
+    whose index needs no negative-index normalisation (none is negative);
+    out-of-bounds positions are dropped."""
+    return lax.scatter(
+        store, index + jnp.asarray([1, 0, 0], jnp.int32) * layer,
+        vals.astype(store.dtype), _DENSE_WRITE, indices_are_sorted=True,
+        unique_indices=True, mode=lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def _store_write(store, layer, table, cols, vals, index=None):
     """Write ``vals`` [B, S, Hkv, hd] at ``[layer, row, cols[row]]`` of
     the WHOLE K/V store, in place: the dense cache ``[L, B, T, Hkv, hd]``
     (``table`` None) or the paged pool through ``table``. Out-of-bounds
     cols (a retired row parked at ``total``, a span's spill past the
-    cache tail) are dropped by scatter semantics — they write nowhere."""
+    cache tail) are dropped by scatter semantics — they write nowhere.
+    ``index``: the dense write's :func:`_dense_write_index` of ``cols``,
+    where a caller built it once outside its layer loop."""
     if table is None:
-        rows = jnp.arange(vals.shape[0])[:, None]
-        return store.at[layer, rows, cols].set(vals)
+        if index is None:
+            index = _dense_write_index(cols)
+        return _dense_write(store, layer, index, vals)
     return _pool_write(store, layer, table, cols, vals)
 
 
@@ -432,7 +463,7 @@ def _store_rows(store, layer, table):
 @scope(SCOPE_ATTN)
 def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
                       valid, token_valid, table=None, fused=False,
-                      mesh=None):
+                      mesh=None, write_index=None):
     """Single-token attention of layer ``li`` (traced: the layer loop's
     index) where row ``b`` writes cache slot ``pos_b[b]`` — the
     continuous-batching variant of :func:`_cached_attention` (rows at
@@ -443,8 +474,9 @@ def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
     ``[li, row, pos_b[row]]`` and the attention reads ``store[li]`` where
     it lies (a dynamic index XLA fuses into its consumer) — no layer is
     sliced out and written back, which is what a cache scanned as
-    ``xs``/``ys`` costs (:func:`_layer_loop`). Returns
-    (out, k_store, v_store).
+    ``xs``/``ys`` costs (:func:`_layer_loop`). A caller's layer loop
+    hands the dense write its ``write_index`` (:func:`_dense_write_index`),
+    built once outside the loop. Returns (out, k_store, v_store).
 
     Where the dense cache's kernel compiles (a TPU, ``head_dim`` a
     multiple of 128; ops/attention.py:dense_decode_attention) the read
@@ -469,8 +501,8 @@ def _ragged_attention(x, layer, cfg, rope_bt, k_store, v_store, li, pos_b,
     local KV heads."""
     b, s, _d = x.shape
     q, k, v = _qkv_rope(x, layer, cfg, rope_bt)
-    k_store = _store_write(k_store, li, table, pos_b[:, None], k)
-    v_store = _store_write(v_store, li, table, pos_b[:, None], v)
+    k_store = _store_write(k_store, li, table, pos_b[:, None], k, write_index)
+    v_store = _store_write(v_store, li, table, pos_b[:, None], v, write_index)
     if fused:
         # The decode step's validity mask is exactly "positions
         # <= pos_b" (the just-written token included), which is the
@@ -853,11 +885,14 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
     rope_bt = (cos_t[pos_b[:, None]], sin_t[pos_b[:, None]])
     x = _embed(params, tok, cfg)[:, None]
     valid = jnp.arange(total)[None, :] <= pos_b[:, None]
+    write_index = (_dense_write_index(pos_b[:, None]) if table is None
+                   else None)
 
     def attend(h, attn, k_store, v_store, li):
         return _ragged_attention(h, attn, cfg, rope_bt, k_store, v_store, li,
                                  pos_b, valid, token_valid, table=table,
-                                 fused=fused, mesh=mesh)
+                                 fused=fused, mesh=mesh,
+                                 write_index=write_index)
 
     logits, k_new, v_new = _layer_loop(params, cfg, x, k_cache0, v_cache0,
                                        attend, token_valid[:, None])

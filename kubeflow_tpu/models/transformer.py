@@ -470,6 +470,10 @@ def partition_rules(cfg: TransformerConfig) -> list[PartitionRule]:
     ldim = AXIS_PIPELINE if cfg.pipeline_stages > 1 else None
     rules = [
         PartitionRule(r"embed/kernel", P(AXIS_TENSOR, AXIS_FSDP)),
+        # A serving tree's stacked q/k/v, held transposed [L, N, D]
+        # (serving_params): column-parallel on their middle axis. Ahead of
+        # the rule below, whose search would match these paths too.
+        PartitionRule(r"attn/w[qkv]_t$", P(ldim, AXIS_TENSOR, AXIS_FSDP)),
         PartitionRule(r"attn/w[qkv]", P(ldim, AXIS_FSDP, AXIS_TENSOR)),
         PartitionRule(r"attn/wo", P(ldim, AXIS_TENSOR, AXIS_FSDP)),
     ]
@@ -515,22 +519,47 @@ def cast_param(w, dtype):
         return w.astype(dtype)
 
 
+def project(x, attn, name: str, dtype):
+    """``x`` [..., D] through the attention's ``w<name>`` (q, k or v). A
+    serving tree holds the stacked matrix transposed as ``w<name>_t``,
+    [N, D] a layer (:func:`serving_params`), and the dot contracts the
+    minor axes of both, so a layer's slice is the operand as stored; any
+    other tree (training's, a mixer layer's) holds ``w<name>`` [D, N]."""
+    held = attn.get(f"w{name}_t")
+    if held is None:
+        return x @ cast_param(attn[f"w{name}"], dtype)
+    # The product is a value of its own, [..., N]: XLA then reads a layer
+    # of the stack inside the dot's fusion, as it does for wo and the
+    # MLP. With the caller's split into heads fused into the dot, XLA:TPU
+    # slices the layer out first, an op of its own (a layer application's
+    # three, outside the `attn` scope; described v5e compile, PR 39).
+    return lax.optimization_barrier(lax.dot_general(
+        x, cast_param(held, dtype), (((x.ndim - 1,), (1,)), ((), ()))))
+
+
 # The leaves cast_param is applied to at cfg.dtype. Norm gains are used
 # in float32 by rms_norm and the MoE router is cast to float32: not here.
 _COMPUTE_DTYPE_LEAF = re.compile(
     r"^(embed/kernel|lm_head/kernel|layers/attn/w[qkvo]"
     r"|layers/mlp/(gate|up|down)"
     r"|layers/\d+/mixer/w[qkvog]|layers/\d+/mlp/(gate|up|down))$")
+# The stacked q/k/v that a layer loop slices inside its scan. Held as
+# [L, D, N], XLA relays each to [L, N, D] ({1,2,0}) for the dot, a whole
+# stack every step; a serving tree holds them so (PERF.md, PR 39).
+_STACKED_QKV = re.compile(r"^layers/attn/w[qkv]$")
 
 
-def serving_params(params, cfg: TransformerConfig):
+def serving_params(params, cfg: TransformerConfig, *, transpose_qkv=True):
     """``params`` as a serving replica holds them: every leaf the forward
     casts to ``cfg.dtype`` is at ``cfg.dtype`` already, so no dispatch
     casts a weight again (cast_param on such a leaf emits no op). Same
     rounding as inside the step, so tokens and logits are bit for bit
-    what the float32 tree gives. A leaf already at its dtype comes back
-    as it is. Cast leaf by leaf, each float32 leaf let go as soon as its
-    copy exists: a caller that hands over its only reference (the
+    what the float32 tree gives. The stacked q/k/v are held transposed,
+    ``w?_t`` [L, N, D], read by :func:`project`; ``transpose_qkv=False``
+    keeps the checkpoint's names and layout (the engine's tree until a
+    decoder takes it: what a caller reads it by). A serving tree comes
+    back as the same leaves. Leaf by leaf, each original let go as soon
+    as its copy exists: a caller that hands over its only reference (the
     argument built in the call) pays one leaf of transient, not a second
     tree."""
     dtype = jnp.dtype(cfg.dtype)
@@ -539,10 +568,18 @@ def serving_params(params, cfg: TransformerConfig):
     paths = [path_str(kp) for kp, _ in flat]
     leaves = [leaf for _, leaf in flat]
     del flat
+    moved = []
     for i, path in enumerate(paths):
         if _COMPUTE_DTYPE_LEAF.match(path) and leaves[i].dtype != dtype:
             leaves[i] = jax.block_until_ready(cast_param(leaves[i], dtype))
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+        if transpose_qkv and _STACKED_QKV.match(path):
+            leaves[i] = jax.block_until_ready(leaves[i].swapaxes(1, 2))
+            moved.append(path.rsplit("/", 1)[1])
+    params = jax.tree_util.tree_unflatten(treedef, leaves)
+    for name in moved:
+        attn = params["layers"]["attn"]
+        attn[f"{name}_t"] = attn.pop(name)
+    return params
 
 
 def _constrain(x, mesh, spec):
@@ -556,9 +593,9 @@ def _attention(x, layer, cfg: TransformerConfig, rope, mesh):
     b, t, d = x.shape
     hd = cfg.head_dim
     cos, sin = rope
-    q = (x @ cast_param(layer["wq"], cfg.dtype)).reshape(b, t, cfg.n_heads, hd)
-    k = (x @ cast_param(layer["wk"], cfg.dtype)).reshape(b, t, cfg.n_kv_heads, hd)
-    v = (x @ cast_param(layer["wv"], cfg.dtype)).reshape(b, t, cfg.n_kv_heads, hd)
+    q = project(x, layer, "q", cfg.dtype).reshape(b, t, cfg.n_heads, hd)
+    k = project(x, layer, "k", cfg.dtype).reshape(b, t, cfg.n_kv_heads, hd)
+    v = project(x, layer, "v", cfg.dtype).reshape(b, t, cfg.n_kv_heads, hd)
     # Inert unless the policy names them ("llm_qkv"): saving post-rope
     # q/k/v spares the backward from re-running rms_norm + the three
     # projections + rope just to rebuild the flash kernel's residuals.

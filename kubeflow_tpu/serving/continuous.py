@@ -913,6 +913,17 @@ class ContinuousDecoder:
             "serving_weights_bytes",
             "Bytes of the installed serving parameter tree")
         self._g_weights_bytes.set(_weights_footprint(self.params)[0])
+        # 1 when the stacked q/k/v are held in the layout the decode dot
+        # reads (serving_params), 0 for a tree that has none (a mixer
+        # model's per-layer trees): read from the tree, which a push
+        # cannot change.
+        layers = self.params["layers"]
+        self.qkv_held_transposed = int(isinstance(layers, dict)
+                                       and "wq_t" in layers["attn"])
+        self.registry.gauge(
+            "serving_qkv_held_transposed",
+            "1 when the stacked q/k/v are held transposed for the "
+            "decode dot").set(self.qkv_held_transposed)
         self._c_weight_pushes = self.registry.counter(
             "serving_weight_pushes_total",
             "Live weight swaps installed by update_weights")
@@ -2539,11 +2550,13 @@ class ContinuousDecoder:
         if version is not None and int(version) <= cur_version:
             return cur_version
         # Shape/dtype contract against the serving tree (tree.map
-        # raises on a structure mismatch). A pushed leaf lands at the
-        # dtype the serving tree holds it in, so a float32 learner's
-        # push installs bf16 matrices: a host array is cast on the host
-        # (no float32 copy reaches the device), a device array on the
-        # device (the float32 one stays the caller's).
+        # raises on a structure mismatch), after the push is made one
+        # (serving_params: a learner's tree has the checkpoint's names
+        # and layout). A pushed leaf lands at the dtype the serving tree
+        # holds it in, so a float32 learner's push installs bf16
+        # matrices: a host array is cast on the host (no float32 copy
+        # reaches the device), a device array on the device (the
+        # float32 one stays the caller's).
         def _fit(n, o):
             if tuple(getattr(n, "shape", ())) != tuple(o.shape):
                 raise ValueError(
@@ -2552,7 +2565,8 @@ class ContinuousDecoder:
             n = np.asarray(n) if not hasattr(n, "dtype") else n
             return n.astype(o.dtype) if n.dtype != o.dtype else n
 
-        params = jax.tree.map(_fit, params, cur_params)
+        params = jax.tree.map(_fit, serving_params(params, self.cfg),
+                              cur_params)
         # Double buffer: place outside every lock. The old buffers
         # keep serving dispatches while the host→device copy streams.
         if self.mesh is not None:
@@ -2571,8 +2585,8 @@ class ContinuousDecoder:
                 raise ValueError(
                     "draft_params given but no draft-model proposer is "
                     "configured (draft_mode='model:<name>')")
-            draft_new = jax.device_put(
-                jax.tree.map(_fit, draft_params, spec.params))
+            draft_new = jax.device_put(jax.tree.map(
+                _fit, serving_params(draft_params, spec.cfg), spec.params))
             jax.block_until_ready(draft_new)
         t_swap = time.perf_counter()
         with self._state_lock:
@@ -3600,6 +3614,7 @@ class ContinuousDecoder:
         # consistent without coupling the lock hierarchies.
         with self._state_lock:
             snap["weights_version"] = self.weights_version
+            snap["qkv_held_transposed"] = self.qkv_held_transposed
             snap["weights_bytes"], snap["weights_dtype"] = \
                 _weights_footprint(self.params)
         # Allocator / trie stats live under the prefix lock — taken in a

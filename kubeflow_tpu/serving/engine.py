@@ -348,25 +348,30 @@ class InferenceEngine:
         (``transformer.serving_params``): a float32 init or checkpoint
         is cast here, once, before the tree moves through the host (half
         the bytes), and no float32 copy is kept; a donor's ``:pull``
-        already carries the held dtype. Other families' trees pass as
-        they are."""
+        already carries the held dtype. It keeps the checkpoint's names
+        and layout until a decoder takes it (``ModelServer.decoder``
+        transposes the stacked q/k/v then): a caller that swaps the
+        engine's tree before that reads it by them. Other families'
+        trees pass as they are."""
         cfg = self.model.config
         if self.model.family == "transformer":
             from kubeflow_tpu.models.transformer import (
                 serving_params as held,
             )
         else:
-            def held(params, cfg):
+            def held(params, cfg, **_):
                 return params
         if self.cfg.weight_peers:
             params = self._pull_params_from_peers()
             if params is not None:
-                return self._normalize_placement(held(params, cfg))
+                return self._normalize_placement(
+                    held(params, cfg, transpose_qkv=False))
         if not self.cfg.checkpoint_dir:
             # Built in the call: the helper is the float32 tree's only
             # holder and lets each leaf go as its copy exists.
             return self._normalize_placement(
-                held(self.model.init(jax.random.PRNGKey(0), cfg), cfg))
+                held(self.model.init(jax.random.PRNGKey(0), cfg), cfg,
+                     transpose_qkv=False))
         from kubeflow_tpu.train import checkpoint as ckpt_lib
         from kubeflow_tpu.train.optimizers import OptimizerConfig
         from kubeflow_tpu.train.trainer import init_state
@@ -382,7 +387,29 @@ class InferenceEngine:
                 f"no checkpoint under {self.cfg.checkpoint_dir}"
             )
         self.weight_pull_source = "checkpoint"
-        return self._normalize_placement(held(restored[0].params, cfg))
+        return self._normalize_placement(
+            held(restored[0].params, cfg, transpose_qkv=False))
+
+    def relay_for_decoder(self):
+        """The engine's tree as a decoder holds it, kept as the engine's
+        own so that the two hold one tree: the stacked q/k/v transposed
+        (``transformer.serving_params``), made before the decoder
+        allocates its K/V. The helper is handed the only reference, so
+        each checkpoint-layout leaf goes as its transpose exists: one
+        leaf of transient, not a second stack. Under the predict lock,
+        which no predict reads the tree outside of."""
+        from kubeflow_tpu.models.transformer import serving_params
+
+        with self._lock:
+            self.params = serving_params(self._hand_over_params(),
+                                         self.model.config)
+            return self.params
+
+    def _hand_over_params(self):
+        """``self.params`` with the attribute cleared, so that the callee
+        this is passed to holds the tree's only reference."""
+        params, self.params = self.params, None
+        return params
 
     # ------------------------------------------------------------------
 

@@ -127,10 +127,15 @@ class ModelServer:
         self.warm_error: str | None = None
 
     @property
+    def _generates(self) -> bool:
+        """Whether generation goes to a ContinuousDecoder here."""
+        return (self.engine.model.family == "transformer"
+                and self.engine.cfg.max_new_tokens > 0
+                and self.engine.cfg.decode_mode == "continuous")
+
+    @property
     def decoder(self):
-        if (self.engine.model.family != "transformer"
-                or self.engine.cfg.max_new_tokens <= 0
-                or self.engine.cfg.decode_mode != "continuous"):
+        if not self._generates:
             return None
         with self._decoder_lock:
             if self._decoder is None:
@@ -155,7 +160,8 @@ class ModelServer:
                           if self.engine.cfg.kv_directory_size > 0
                           else None)
                 self._decoder = ContinuousDecoder(
-                    self.engine.params, self.engine.model.config,
+                    self.engine.relay_for_decoder(),
+                    self.engine.model.config,
                     slots=self.engine.cfg.batch_size,
                     prefill_len=self.engine.cfg.max_seq_len,
                     max_new_tokens=self.engine.cfg.max_new_tokens,
@@ -907,27 +913,34 @@ class ModelServer:
     def warm(self) -> None:
         """Boot warm path, run AFTER the HTTP port binds so ``/healthz``
         answers ``warming`` (route-excluded, not dead) for the whole
-        birth instead of connection-refusing: engine warmup (compiles
-        the predict executable), then — when the flash-crowd surface is
-        configured (``compile_cache_dir``/``weight_peers``) — an eager
-        decoder build + dispatch-set warm so the replica joins the
-        fleet with nothing left to compile. Publishes the per-phase
-        cold-start breakdown and flips ``warming`` off. Raises when the
-        predict compile fails or the decoder reports a dispatch shape
-        that did not warm: a replica that cannot compile its own
-        dispatch set must not join the fleet looking healthy."""
+        birth instead of connection-refusing: the engine's tree relaid
+        as the decoder will hold it; when the flash-crowd surface is
+        configured (``compile_cache_dir``/``weight_peers``) an eager
+        decoder build; then engine warmup (compiles the predict
+        executable, for the tree it will keep serving, however late the
+        decoder is built), then the decoder's dispatch-set warm, so the
+        replica joins the fleet with nothing left to compile. Publishes
+        the per-phase cold-start breakdown and flips ``warming`` off.
+        Raises when the predict compile fails or the decoder reports a
+        dispatch shape that did not warm: a replica that cannot compile
+        its own dispatch set must not join the fleet looking healthy."""
         t0 = time.perf_counter()
+        eager = (self.engine.cfg.compile_cache_dir
+                 or self.engine.cfg.weight_peers)
+        # The relay first: the predict executable compiles for the tree
+        # the decoder will share, eager or not.
+        if self._generates:
+            self.engine.relay_for_decoder()
+        decoder = self.decoder if eager else None
         self.engine.warmup()
-        if self.engine.cfg.compile_cache_dir or self.engine.cfg.weight_peers:
-            decoder = self.decoder
-            if decoder is not None:
-                decoder.warming = True
-                report = decoder.warm()
-                if report["failed"]:
-                    raise RuntimeError(
-                        f"decoder warm failed for {report['failed']} "
-                        f"dispatch shape(s) {report['failed_shapes']}: "
-                        f"{report['first_error']}")
+        if decoder is not None:
+            decoder.warming = True
+            report = decoder.warm()
+            if report["failed"]:
+                raise RuntimeError(
+                    f"decoder warm failed for {report['failed']} "
+                    f"dispatch shape(s) {report['failed_shapes']}: "
+                    f"{report['first_error']}")
         self.engine.cold_start["compile"] = time.perf_counter() - t0
         self.engine.cold_start["first_token"] = (time.perf_counter()
                                                  - self._t_boot)

@@ -88,16 +88,31 @@ def flatten_params(params) -> dict[str, np.ndarray]:
     return leaves
 
 
+def _transposed_name(path: str) -> str:
+    """The name of ``path``'s leaf in the other layout: a serving tree
+    holds the stacked q/k/v transposed under ``<name>_t``
+    (``transformer.serving_params``), a learner's or a checkpoint's
+    under ``<name>``."""
+    return path[:-2] if path.endswith("_t") else path + "_t"
+
+
 def unflatten_params(leaves: dict[str, np.ndarray], reference):
     """Rebuild a pytree shaped like ``reference`` from a ``path ->
-    array`` map. Raises ``ValueError`` when the push does not cover the
-    reference's leaves exactly — a partial tree must never install."""
+    array`` map. A leaf sent in the other layout (a learner's ``wq``
+    into a serving tree's ``wq_t``, a donor's ``wq_t`` into a
+    newborn's ``wq``) lands with its last two axes swapped. Raises
+    ``ValueError`` when the push does not cover the reference's leaves
+    exactly — a partial tree must never install."""
     import jax
 
     from kubeflow_tpu.parallel.sharding import path_str
 
     flat, treedef = jax.tree_util.tree_flatten_with_path(reference)
     want = [path_str(kp) for kp, _ in flat]
+    leaves = dict(leaves)
+    for p in want:
+        if p not in leaves and _transposed_name(p) in leaves:
+            leaves[p] = np.swapaxes(leaves.pop(_transposed_name(p)), -1, -2)
     missing = [p for p in want if p not in leaves]
     extra = sorted(set(leaves) - set(want))
     if missing or extra:

@@ -65,8 +65,9 @@ def _flat(params) -> dict:
 
 
 def _held(params):
-    """A float32 tree as a replica holds it (cast once, at install)."""
-    return serving_params(params, SPEC.config)
+    """A float32 tree as an engine holds it (cast once, at install; the
+    checkpoint's names until a decoder takes it)."""
+    return serving_params(params, SPEC.config, transpose_qkv=False)
 
 
 def _trees_equal(a, b) -> bool:

@@ -124,6 +124,8 @@ def test_the_decoder_counts_state_and_selection(served, params):
         int(np.prod(a.shape)) * a.dtype.itemsize
         for a in jax.tree.leaves((held["lin_state"], held["ckeys"])))
     assert m["rows_dense"] > 0 and m["rows_sparse"] > 0
+    # Per-layer mixer trees: no stacked q/k/v to hold transposed.
+    assert m["qkv_held_transposed"] == 0
     assert m["rows_dense"] + m["rows_sparse"] == m["tokens_emitted"]
     assert 0 < m["sparse_tokens_attended"] < m["sparse_tokens_in_context"]
     assert m["prefill_chunks"] > 0

@@ -3,7 +3,10 @@ exactly the leaves ``cast_param`` is applied to, once, where a tree becomes a
 serving tree (engine load, decoder construction, the draft model), a pushed
 or pulled tree lands at the held dtype, and no decode or admission dispatch
 converts a parameter again. Same rounding in another place, so every
-comparison here is exact."""
+comparison here is exact. It also holds the stacked q/k/v transposed,
+``w?_t`` [L, N, D], the layout the decode dot reads (a mixer model's
+per-layer trees keep their names); the engine keeps the checkpoint's names
+until a decoder takes its tree."""
 
 from __future__ import annotations
 
@@ -29,7 +32,9 @@ CONFIGS = {
     "moe": transformer.config("moe-test-tiny"),
     "tied": transformer.config("lm-test-tiny", tie_embeddings=True),
     "float32": transformer.config("lm-test-tiny", dtype=jnp.float32),
+    "hybrid": transformer.config("sala-test-tiny"),
 }
+QKV = ("wq", "wk", "wv")
 CFG = CONFIGS["dense"]
 PROMPTS = [[3 + (j % 23) for j in range(12)], [9, 8, 7, 6, 5], [40, 41]]
 GEN = 12
@@ -46,8 +51,24 @@ def _paths(tree) -> dict:
 def _is_matrix(path: str) -> bool:
     """A leaf the forward casts to cfg.dtype (the router and the norm
     gains are used in float32)."""
-    leaf = path.rsplit("/", 1)[-1]
-    return leaf in ("kernel", "wq", "wk", "wv", "wo", "gate", "up", "down")
+    leaf = path.rsplit("/", 1)[-1].removesuffix("_t")
+    return leaf in ("kernel", "wq", "wk", "wv", "wo", "wg", "gate", "up",
+                    "down")
+
+
+def _objects(tree) -> dict:
+    return {path_str(kp): leaf
+            for kp, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _as_held(tree) -> dict:
+    """``tree``'s leaves under a serving tree's names and layout."""
+    out = {}
+    for path, leaf in _paths(tree).items():
+        if path.rsplit("/", 1)[-1] in QKV and path.startswith("layers/attn/"):
+            path, leaf = path + "_t", np.swapaxes(leaf, 1, 2)
+        out[path] = leaf
+    return out
 
 
 def _tokens(params, cfg=CFG, **kw):
@@ -70,19 +91,32 @@ def test_serving_params_casts_the_leaves_cast_param_does(name):
     cfg = CONFIGS[name]
     params = _init(cfg)
     held = serving_params(params, cfg)
-    assert jax.tree.structure(held) == jax.tree.structure(params)
-    was, now = _paths(params), _paths(held)
+    was, now = _as_held(params), _paths(held)
+    assert now.keys() == was.keys()
     assert ("lm_head/kernel" in was) == (name != "tied")
     assert ("layers/mlp/router" in was) == (name == "moe")
+    # The stacked q/k/v under their new names only, [L, N, D]; a mixer
+    # model's per-layer trees keep theirs.
+    assert not {f"layers/attn/{w}" for w in QKV} & now.keys()
+    stacked = name != "hybrid"
+    for w, width in zip(QKV, (cfg.n_heads, cfg.n_kv_heads, cfg.n_kv_heads)):
+        assert (f"layers/attn/{w}_t" in now) == stacked
+        if stacked:
+            assert now[f"layers/attn/{w}_t"].shape == (
+                cfg.n_layers, width * cfg.head_dim, cfg.d_model)
+        else:
+            assert f"layers/0/mixer/{w}" in now
     for path, leaf in now.items():
         want = jnp.dtype(cfg.dtype) if _is_matrix(path) else jnp.float32
         assert leaf.dtype == want, path
         # The rounding the step itself applied: nothing else moved.
         np.testing.assert_array_equal(
             leaf, np.asarray(jnp.asarray(was[path]).astype(want)), path)
-    if name == "float32":
-        assert all(a is b for a, b in zip(jax.tree.leaves(held),
-                                          jax.tree.leaves(params)))
+    if name in ("float32", "hybrid"):
+        # Nothing to cast: only the transposed q/k/v are new objects.
+        flat = _objects(params)
+        assert all(leaf is flat[path] for path, leaf in
+                   _objects(held).items() if not path.endswith("_t"))
     # A tree cast earlier passes through: the same leaf objects back.
     again = serving_params(held, cfg)
     assert all(a is b for a, b in zip(jax.tree.leaves(again),
@@ -204,6 +238,23 @@ def test_generate_logits_and_tokens_are_bit_identical(name):
                                   np.asarray(held_tokens))
 
 
+@pytest.mark.parametrize("name", ["dense", "moe", "tied"])
+def test_apply_gives_the_float32_trees_logits_on_its_serving_tree(name):
+    """The engine's predict path (``transformer.apply``) reads either
+    layout: the checkpoint's through ``@``, the serving tree's through
+    the dot over both minor axes."""
+    cfg = CONFIGS[name]
+    params = _init(cfg, seed=4)
+    tokens = jnp.asarray([[5, 6, 7, 8, 9, 10], [11, 12, 13, 1, 2, 3]],
+                         jnp.int32)
+    want = transformer.apply(params, tokens, cfg).astype(jnp.float32)
+    got = transformer.apply(serving_params(params, cfg), tokens,
+                            cfg).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(np.argmax(got, -1), np.argmax(want, -1))
+
+
 @pytest.mark.parametrize("layout", [{}, {"kv_layout": "paged",
                                          "kv_block_size": 4}],
                          ids=["dense_kv", "paged_kv"])
@@ -227,6 +278,8 @@ def test_decoder_holds_no_float32_matrix_and_reports_its_bytes():
         assert m["weights_bytes"] == want
         assert m["weights_dtype"] == "bfloat16"
         assert f"serving_weights_bytes {want}" in d.registry.render()
+        assert m["qkv_held_transposed"] == 1
+        assert "serving_qkv_held_transposed 1" in d.registry.render()
     finally:
         d.stop()
 
@@ -270,6 +323,10 @@ def test_float32_push_lands_at_the_held_dtype(where):
         before = d.metrics()
         assert d.update_weights(pushed) == 1
         after = d.metrics()
+        # The learner's tree has the checkpoint's names; it lands in the
+        # serving tree's.
+        assert _paths(d.params).keys() == _as_held(pushed).keys()
+        assert after["qkv_held_transposed"] == 1
         for path, leaf in _paths(d.params).items():
             assert leaf.dtype == (jnp.bfloat16 if _is_matrix(path)
                                   else jnp.float32), path
@@ -295,8 +352,9 @@ def _engine_cfg(**kw):
 
 
 def _assert_held(params, source):
-    """``params`` is ``source`` (float32) as a replica holds it."""
-    want = _paths(serving_params(source, CFG))
+    """``params`` is ``source`` (float32) as an engine holds it: cast, in
+    the checkpoint's names and layout."""
+    want = _paths(serving_params(source, CFG, transpose_qkv=False))
     got = _paths(params)
     assert got.keys() == want.keys()
     for path in want:
@@ -375,9 +433,12 @@ def test_no_float32_matrix_is_alive_once_the_decoder_is_built(monkeypatch):
     cfg = transformer.config("lm-test-tiny", d_model=72, d_ff=136,
                              vocab_size=264, n_heads=4, n_kv_heads=2)
     monkeypatch.setitem(transformer.PRESETS, "lm-test-odd", cfg)
-    shapes = {leaf.shape for kp, leaf in jax.tree_util.tree_flatten_with_path(
-        jax.eval_shape(lambda: _init(cfg)))[0] if _is_matrix(path_str(kp))}
-    assert len(shapes) >= 5
+    # The matrices as the checkpoint and as the decoder hold them (wk and
+    # wv transposed are shapes of their own).
+    shapes = {leaf.shape for tree in (_init(cfg), serving_params(
+        _init(cfg), cfg)) for kp, leaf in jax.tree_util.tree_flatten_with_path(
+        tree)[0] if _is_matrix(path_str(kp))}
+    assert len(shapes) >= 6
 
     def float32_matrices():
         gc.collect()
@@ -417,3 +478,65 @@ def test_no_float32_matrix_is_alive_once_the_decoder_is_built(monkeypatch):
         assert float32_matrices() == []
     finally:
         decoder.stop()
+
+
+def test_the_relay_holds_one_stack_of_transient(monkeypatch):
+    """``relay_for_decoder`` hands ``serving_params`` the engine's only
+    reference: when a stacked q/k/v is transposed, the ones transposed
+    before it are gone in the checkpoint's layout, so one stack of
+    transient is alive beside the tree, never a second wq, wk and wv."""
+    cfg = transformer.config("lm-test-tiny", d_model=80, d_ff=152,
+                             vocab_size=280, n_heads=4, n_kv_heads=2)
+    monkeypatch.setitem(transformer.PRESETS, "lm-test-relay", cfg)
+    server = ModelServer(
+        EngineConfig(model="lm-test-relay", batch_size=2, max_seq_len=16,
+                     max_new_tokens=4), port=0, grpc_port=None)
+    attn = server.engine.params["layers"]["attn"]
+    # Every layout of a q/k/v stack (wo shares wq's shape).
+    shapes = {attn[w].shape for w in QKV} | {
+        attn[w].swapaxes(1, 2).shape for w in QKV}
+
+    def stacks():
+        gc.collect()
+        return sum(a.dtype == jnp.bfloat16 and a.shape in shapes
+                   for a in jax.live_arrays())
+
+    before = stacks()
+    alive = []
+    ready = jax.block_until_ready
+
+    def spy(x):
+        alive.append(stacks())
+        return ready(x)
+
+    del attn
+    monkeypatch.setattr(jax, "block_until_ready", spy)
+    server.engine.relay_for_decoder()
+    monkeypatch.setattr(jax, "block_until_ready", ready)
+    assert alive == [before + 1] * len(QKV)
+    assert stacks() == before
+    assert {f"{w}_t" for w in QKV} <= server.engine.params["layers"][
+        "attn"].keys()
+
+
+def test_predict_compiles_once_when_the_decoder_comes_after_warm():
+    """``warm`` relays the engine's tree before predict compiles, so a
+    decoder built on first use (no flash-crowd surface) leaves predict's
+    executable and the tree's leaves as they were."""
+    server = ModelServer(_engine_cfg(), port=0, grpc_port=None,
+                         batch_timeout_ms=2)
+    server.start()
+    try:
+        engine = server.engine
+        assert server._decoder is None
+        assert {f"{w}_t" for w in QKV} <= engine.params["layers"][
+            "attn"].keys()
+        held = jax.tree.leaves(engine.params)
+        compiled = engine._predict._cache_size()
+        decoder = server.decoder
+        for tree in (engine.params, decoder.params):
+            assert all(a is b for a, b in zip(jax.tree.leaves(tree), held))
+        engine.predict_batch(engine._example_instances(1))
+        assert engine._predict._cache_size() == compiled
+    finally:
+        server.stop()

@@ -80,6 +80,9 @@ def greedy_by_tp(tiny_tp):
         try:
             toks = _probe(d)
             m = d.metrics()
+            # A layer's shard of the transposed q as the mesh placed it.
+            m["wq_t_shard"] = d.params["layers"]["attn"][
+                "wq_t"].addressable_shards[0].data.shape
         finally:
             d.stop()
         out[tp] = (toks, m)
@@ -92,10 +95,14 @@ def greedy_by_tp(tiny_tp):
 
 
 def test_greedy_byte_identity_across_meshes(greedy_by_tp):
-    t1, _ = greedy_by_tp[1]
+    t1, m1 = greedy_by_tp[1]
+    layers, n, d = m1["wq_t_shard"]
     for tp in (2, 4):
-        toks, _ = greedy_by_tp[tp]
+        toks, m = greedy_by_tp[tp]
         assert toks == t1, f"tp={tp} diverged from single-chip"
+        # The held-transposed q [L, N, D] is column-parallel on N, the
+        # axis the rule moved to (partition_rules: attn/w[qkv]_t).
+        assert m["wq_t_shard"] == (layers, n // tp, d)
 
 
 def test_prefix_sharing_and_cow_exercised_under_tp(greedy_by_tp):

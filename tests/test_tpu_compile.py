@@ -124,9 +124,50 @@ def test_the_block_selection_sorts_down_the_sublanes_on_v5e(
 # for its tiles).
 DECODE_STEP_TEMP_MB = 64
 # `reason-decode`'s: Ouro-2.6B whole, 192 cache layers of 4 rows of 1,280.
-# Its step's temporaries are three whole-stack transposed copies of wq, wk
-# and wv, 1,208.9 MB (PERF.md, PR 36); the dense read's kernel adds none.
-OURO_STEP_TEMP_MB = 1210
+# Its step's temporaries were three whole-stack transposed copies of wq, wk
+# and wv, 1,208.9 MB (PERF.md, PR 36), until the serving tree held them in
+# the layout the dot reads: 0.71 MB (PR 39); the dense read adds none.
+OURO_STEP_TEMP_MB = 4
+
+
+def _mistral_serve():
+    from kubeflow_tpu.models import transformer
+
+    return transformer.TransformerConfig(
+        vocab_size=32768, d_model=4096, n_layers=6, n_heads=32, n_kv_heads=8,
+        d_ff=14336, max_seq_len=768, rope_theta=1e6, dtype=jnp.bfloat16)
+
+
+def _ouro_serve():
+    from kubeflow_tpu.models import transformer
+
+    return transformer.config("ouro-2.6b", dtype=jnp.bfloat16,
+                              max_seq_len=1280)
+
+
+def _serving_tree(cfg, one_chip):
+    from kubeflow_tpu.models import transformer
+
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: transformer.serving_params(
+            transformer.init(jax.random.PRNGKey(0), cfg), cfg)))
+
+
+def _relaid_qkv(text, cfg):
+    """Lines that copy a q/k/v matrix (a whole stack or a layer's slice,
+    either orientation) or make one in another layout than row-major:
+    the stack relaid to ``{1,2,0}`` every step, or its slice after."""
+    d = cfg.d_model
+    widths = {n * cfg.head_dim for n in (cfg.n_heads, cfg.n_kv_heads)}
+    dims = "|".join(f"{a},{b}" for n in widths for a, b in ((d, n), (n, d)))
+    shape = re.compile(r"= bf16\[\d+,(?:" + dims + r")\]\{([\d,]+)")
+    found = []
+    for line in text.splitlines():
+        m = shape.search(line)
+        if m and (m.group(1) != "2,1,0" or " copy(" in line):
+            found.append(line.strip()[:120])
+    return found
 
 
 @pytest.mark.parametrize("layout", ["dense", "dense-ouro-2.6b", "paged",
@@ -137,13 +178,10 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
     from kubeflow_tpu.models import decode, transformer
     from kubeflow_tpu.ops import attention
 
-    cfg = transformer.TransformerConfig(
-        vocab_size=32768, d_model=4096, n_layers=6, n_heads=32, n_kv_heads=8,
-        d_ff=14336, max_seq_len=768, rope_theta=1e6, dtype=jnp.bfloat16)
+    cfg = _mistral_serve()
     slots, total, most_temp_mb = 32, 768, DECODE_STEP_TEMP_MB
     if layout == "dense-ouro-2.6b":
-        cfg = transformer.config("ouro-2.6b", dtype=jnp.bfloat16,
-                                 max_seq_len=1280)
+        cfg = _ouro_serve()
         slots, total, most_temp_mb = 4, 1280, OURO_STEP_TEMP_MB
     if layout.startswith("dense"):
         # The process's backend is the CPU, whatever is compiled for: the
@@ -155,9 +193,7 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                            sharding=one_chip), tree)
 
-    params = described(jax.eval_shape(
-        lambda: transformer.serving_params(
-            transformer.init(jax.random.PRNGKey(0), cfg), cfg)))
+    params = _serving_tree(cfg, one_chip)
     if layout.startswith("dense"):
         state = jax.eval_shape(
             lambda: decode.init_decode_state(cfg, slots, total))
@@ -174,6 +210,9 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
     copies = [line.strip()[:120] for line in text.splitlines()
               if re.search(re.escape(whole) + r"\S* copy\(", line)]
     assert not copies, copies
+    # The stacked q/k/v are read as held: no copy of a stack, no relaid
+    # slice (PR 39).
+    assert not _relaid_qkv(text, cfg), _relaid_qkv(text, cfg)
     temp_mb = compiled.memory_analysis().temp_size_in_bytes / 1e6
     assert temp_mb < most_temp_mb, temp_mb
     # The dense read is the length-bounded kernel, handed both stores whole
@@ -184,3 +223,26 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
     for line in calls:
         layouts = line[line.index("operand_layout_constraints="):]
         assert layouts[:layouts.index("}}")].count(whole) == 2, layouts[:300]
+
+
+# An admission of one 256-token prompt, as `decode.prefill` runs it: the
+# most temporaries in MB. Ouro's were 1,813.7 with the three whole-stack
+# copies of wq, wk and wv; without them 1,008.3 (PR 39).
+PREFILL_TEMP_MB = {"mistral-7b": None, "ouro-2.6b": 1100}
+
+
+@pytest.mark.parametrize("model", sorted(PREFILL_TEMP_MB))
+def test_the_prefill_relays_no_qkv_on_v5e(one_chip, no_compile_cache, arg,
+                                          model):
+    from kubeflow_tpu.models import decode
+
+    cfg, total = ((_ouro_serve(), 1280) if model == "ouro-2.6b"
+                  else (_mistral_serve(), 768))
+    compiled = decode.prefill.lower(
+        _serving_tree(cfg, one_chip), arg((1, 256), jnp.int32),
+        arg((1,), jnp.int32), cfg, total_len=total).compile()
+    text = compiled.as_text()
+    assert not _relaid_qkv(text, cfg), _relaid_qkv(text, cfg)
+    if PREFILL_TEMP_MB[model] is not None:
+        temp_mb = compiled.memory_analysis().temp_size_in_bytes / 1e6
+        assert temp_mb < PREFILL_TEMP_MB[model], temp_mb

@@ -348,6 +348,25 @@ def test_unflatten_refuses_partial_or_extra():
         weights_mod.unflatten_params(extra, P1)
 
 
+@pytest.mark.parametrize("direction", ["learner_to_server",
+                                       "donor_to_newborn"])
+def test_unflatten_lands_a_leaf_sent_in_the_other_layout(direction):
+    """A learner's ``wq`` [L, D, N] lands in a serving tree's ``wq_t``
+    [L, N, D], and a donor's ``wq_t`` in a newborn's ``wq``, transposed."""
+    from kubeflow_tpu.models.transformer import serving_params
+
+    held = serving_params(P1, SPEC.config)
+    plain = serving_params(P1, SPEC.config, transpose_qkv=False)
+    sent, into = ((plain, held) if direction == "learner_to_server"
+                  else (held, plain))
+    rebuilt = weights_mod.flatten_params(weights_mod.unflatten_params(
+        weights_mod.flatten_params(sent), into))
+    want = weights_mod.flatten_params(into)
+    assert rebuilt.keys() == want.keys()
+    for path, leaf in want.items():
+        np.testing.assert_array_equal(rebuilt[path], leaf, path)
+
+
 def test_unpack_chunk_rejects_garbage():
     with pytest.raises(ValueError):
         weights_mod.unpack_chunk({"version": 99})
