@@ -26,6 +26,7 @@ from kubeflow_tpu.observability.tracing import (
     SCOPE_DECODE,
     SCOPE_EMBED,
     SCOPE_HEAD,
+    SCOPE_LATENT_ATTN,
     SCOPE_MLP,
     SCOPE_PREFILL,
     SCOPE_SAMPLE,
@@ -56,13 +57,17 @@ from kubeflow_tpu.models.transformer import (
     close_pass,
     final_hidden,
     head_kernel,
+    latent_ffn,
     lightning_span,
     lightning_token,
     mixer_out,
     mixer_qkv,
+    mla_expanded,
+    mla_inputs,
     moe_ffn,
     post_norm,
     project,
+    rotate_half,
     sparse_span,
 )
 
@@ -81,6 +86,11 @@ def init_cache(cfg: TransformerConfig, batch: int, total_len: int):
             "mixer_types: the dense KV cache (and the lockstep generate "
             "built on it) holds K and V for every layer and nothing else; "
             "a model with recurrent state serves on kv_layout='paged'")
+    if cfg.kv_lora_rank:
+        # One row a token and layer, the latent and the rotary key that
+        # every head reads (``v`` holds nothing).
+        return {"k": jnp.zeros((cfg.cache_layers, batch, total_len,
+                                cfg.latent_width), cfg.dtype), "v": None}
     shape = (cfg.cache_layers, batch, total_len, cfg.n_kv_heads,
              cfg.head_dim)
     return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
@@ -151,12 +161,59 @@ def _cached_attention(x, layer, cfg, rope_bt, k_cache, v_cache, pos, valid,
     return out @ cast_param(layer["wo"], cfg.dtype), k_cache, v_cache
 
 
-def _rope(x, cos, sin):
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    c, s = cos[:, :, None, :], sin[:, :, None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x1 * s + x2 * c], axis=-1
-    ).astype(x.dtype)
+@scope(SCOPE_ATTN)
+def _latent_cached_attention(x, attn, cfg, rope_bt, store, pos, valid, li):
+    """The expanded latent attention of cache layer ``li`` for x [B, S, D]
+    at slots pos..pos+S: the block's rows written into the WHOLE latent
+    store [L, B, T, W] in place, every head's K/V expanded from the rows
+    the block can see (those before ``pos + S`` where ``pos`` is a Python
+    int, as at every admission; the whole row where it is traced), masked
+    by ``valid`` [B, T] and causality. Returns (out, store, None)."""
+    s = x.shape[1]
+    q_nope, q_rope, row = mla_inputs(x, attn, cfg, rope_bt)
+    store = lax.dynamic_update_slice(store, row[None].astype(store.dtype),
+                                     (li, 0, pos, 0))
+    rows = _layer_of(store, li)
+    if isinstance(pos, int):
+        rows, valid = rows[:, :pos + s], valid[:, :pos + s]
+    j_idx = jnp.arange(rows.shape[1])[None, None, :]
+    i_idx = pos + jnp.arange(s)[None, :, None]
+    mask = (j_idx <= i_idx) & valid[:, None, :]
+    return mla_expanded(q_nope, q_rope, rows, mask, attn, cfg), store, None
+
+
+@scope(SCOPE_ATTN)
+def _latent_attention(x, attn, cfg, rope_bt, store, li, pos_b, token_valid,
+                      write_index):
+    """Single-token latent attention of cache layer ``li`` where row ``b``
+    writes slot ``pos_b[b]``, in the absorbed form: the row's latent and
+    rotary key written into the whole store [L, B, T, W] in place, each
+    head's query taken into latent space (q_nope through its ``w_uk``) and
+    beside the rotary query, ONE read of the store (every head a product
+    over the same rows, ops/attention.py:dense_decode_attention, bounded
+    by what each emitting row holds), the latent context out through each
+    head's ``w_uv``. The same function as :func:`_latent_cached_attention`
+    with the up-projections moved across the products. Returns
+    (out, store, None)."""
+    b = x.shape[0]
+    h, rank = cfg.n_heads, cfg.kv_lora_rank
+    dtype = cfg.dtype
+    q_nope, q_rope, row = mla_inputs(x, attn, cfg, rope_bt)
+    store = _dense_write(store, li, write_index, row)
+    with scope(SCOPE_LATENT_ATTN):
+        q_lat = jnp.einsum("bhn,hnr->bhr", q_nope[:, 0],
+                           cast_param(attn["w_uk"], dtype))
+        q = jnp.concatenate(
+            [q_lat.astype(dtype), q_rope[:, 0],
+             jnp.zeros((b, h, store.shape[-1] - rank - cfg.qk_rope_head_dim),
+                       dtype)], axis=-1)
+        o_lat = dense_decode_attention(
+            q, store, None, li, jnp.where(token_valid, pos_b + 1, 0),
+            n_kv_heads=1, v_dim=rank,
+            sm_scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)
+        o = jnp.einsum("bhr,hrv->bhv", o_lat, cast_param(attn["w_uv"], dtype))
+    o = o.reshape(b, 1, h * cfg.v_head_dim).astype(dtype)
+    return o @ cast_param(attn["wo"], dtype), store, None
 
 
 def _qkv_rope(x, layer, cfg, rope_bt):
@@ -169,7 +226,7 @@ def _qkv_rope(x, layer, cfg, rope_bt):
     q = project(x, layer, "q", cfg.dtype).reshape(b, s, cfg.n_heads, hd)
     k = project(x, layer, "k", cfg.dtype).reshape(b, s, cfg.n_kv_heads, hd)
     v = project(x, layer, "v", cfg.dtype).reshape(b, s, cfg.n_kv_heads, hd)
-    return _rope(q, cos, sin), _rope(k, cos, sin), v
+    return rotate_half(q, cos, sin), rotate_half(k, cos, sin), v
 
 
 def _embed(params, tokens, cfg):
@@ -200,10 +257,20 @@ def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
     ``positions`` [B, S] → (logits [B, S, V], new cache). ``token_valid``
     ([B, S]) marks real (non-pad) tokens in THIS block — MoE routing must
     not let ragged-prefill padding claim expert capacity."""
-    cos_t, sin_t = rotary_frequencies(cfg.head_dim, cache["k"].shape[2],
+    cos_t, sin_t = rotary_frequencies(cfg.rope_dim, cache["k"].shape[2],
                                       theta=cfg.rope_theta)
     rope_bt = (cos_t[positions], sin_t[positions])
     x = _embed(params, tokens, cfg)
+    if cfg.kv_lora_rank:
+        # The expanded form (every head's K/V from the latent) over the
+        # rows the block can see, the store riding the layer loop.
+        def attend(h, attn, store, _none, li):
+            return _latent_cached_attention(h, attn, cfg, rope_bt, store,
+                                            pos, valid, li)
+
+        logits, store, _, _ = _layer_loop(params, cfg, x, cache["k"], None,
+                                          attend, token_valid)
+        return logits, {"k": store, "v": None}
     if cfg.n_passes > 1:
         # A looped stack cannot scan a cache of n_passes * n_layers
         # entries beside n_layers layers of parameters: the cache rides
@@ -212,8 +279,8 @@ def forward_cached(params, tokens, cfg: TransformerConfig, cache, pos,
             return _cached_attention(h, attn, cfg, rope_bt, k_all, v_all,
                                      pos, valid, li)
 
-        logits, k_new, v_new = _layer_loop(params, cfg, x, cache["k"],
-                                           cache["v"], attend, token_valid)
+        logits, k_new, v_new, _ = _layer_loop(params, cfg, x, cache["k"],
+                                              cache["v"], attend, token_valid)
         return logits, {"k": k_new, "v": v_new}
 
     def layer_fn(x, layer_and_cache):
@@ -412,6 +479,11 @@ def _layer_of(store, layer):
 _DENSE_WRITE = lax.ScatterDimensionNumbers(
     update_window_dims=(2, 3), inserted_window_dims=(0, 1, 2),
     scatter_dims_to_operand_dims=(0, 1, 2))
+# The same into a latent store [L, B, T, W]: a position's row is one
+# vector, not one a head.
+_LATENT_WRITE = lax.ScatterDimensionNumbers(
+    update_window_dims=(2,), inserted_window_dims=(0, 1, 2),
+    scatter_dims_to_operand_dims=(0, 1, 2))
 
 
 def _dense_write_index(cols):
@@ -429,11 +501,14 @@ def _dense_write(store, layer, index, vals):
     """Write ``vals`` [B, S, Hkv, hd] into the dense cache at ``index``
     (:func:`_dense_write_index`) of layer ``layer``: one in-place scatter
     whose index needs no negative-index normalisation (none is negative);
-    out-of-bounds positions are dropped."""
+    out-of-bounds positions are dropped. A latent store's ``vals`` are
+    [B, S, W]."""
     return lax.scatter(
         store, index + jnp.asarray([1, 0, 0], jnp.int32) * layer,
-        vals.astype(store.dtype), _DENSE_WRITE, indices_are_sorted=True,
-        unique_indices=True, mode=lax.GatherScatterMode.FILL_OR_DROP)
+        vals.astype(store.dtype),
+        _DENSE_WRITE if store.ndim == 5 else _LATENT_WRITE,
+        indices_are_sorted=True, unique_indices=True,
+        mode=lax.GatherScatterMode.FILL_OR_DROP)
 
 
 def _store_write(store, layer, table, cols, vals, index=None):
@@ -554,8 +629,12 @@ def init_decode_state(cfg: TransformerConfig, slots: int, total_len: int,
                       seed: int = 0):
     """Persistent server decode state: ``slots`` in-flight rows over a shared
     fixed-shape KV cache. ``length`` is each row's next write slot (== tokens
-    held so far); inactive rows are parked with ``active`` False."""
-    return {
+    held so far); inactive rows are parked with ``active`` False. A model
+    with the dropless expert layer also counts, on the device, the tokens
+    each (expert layer, expert) took and the experts each expert layer
+    touched, summed over the decode steps (``moe_routed`` [layers, E],
+    ``moe_touched`` [layers])."""
+    state = {
         "cache": init_cache(cfg, slots, total_len),
         "length": jnp.zeros((slots,), jnp.int32),
         "remaining": jnp.zeros((slots,), jnp.int32),
@@ -564,6 +643,11 @@ def init_decode_state(cfg: TransformerConfig, slots: int, total_len: int,
         "last_logits": jnp.zeros((slots, cfg.vocab_size), jnp.float32),
         "key": jax.random.PRNGKey(seed),
     }
+    if cfg.kv_lora_rank:
+        layers = cfg.n_layers - cfg.first_k_dense
+        state["moe_routed"] = jnp.zeros((layers, cfg.n_experts), jnp.int32)
+        state["moe_touched"] = jnp.zeros((layers,), jnp.int32)
+    return state
 
 
 @functools.partial(jax.jit, donate_argnames=("state",))
@@ -607,11 +691,14 @@ def _admit_rows_body(state, params, cfg: TransformerConfig, slots,
     )[:, 0]
 
     def rows(store, new):
+        if new is None:  # a latent cache's empty side
+            return None
         if new.shape[2] == store.shape[2]:
             return store.at[:, slots].set(new)
         return store.at[:, slots, :new.shape[2]].set(new)
 
     return {
+        **state,
         "cache": {
             "k": rows(state["cache"]["k"], cache["k"]),
             "v": rows(state["cache"]["v"], cache["v"]),
@@ -837,7 +924,12 @@ def _layer_loop(params, cfg: TransformerConfig, x, k_store, v_store, attend,
     writing and reading cache layers ``u * n_layers + layer``, each pass
     closed by the final norm whose output is the next one's input. One
     pass is the inner scan alone. Returns (logits [B, S, V], k_store,
-    v_store)."""
+    v_store, routed): ``routed`` [expert layers, E], the tokens each
+    expert took, from a model with the dropless expert layer, else None.
+
+    A latent model (``cfg.kv_lora_rank``) carries its latent store as
+    ``k_store`` (``v_store`` None) and runs its leading dense layers one
+    by one ahead of the scan over its expert layers."""
 
     def layer_fn(carry, layer_and_index):
         x, k_store, v_store = carry
@@ -847,24 +939,46 @@ def _layer_loop(params, cfg: TransformerConfig, x, k_store, v_store, attend,
                                         li)
         x = x + post_norm(attn, layer, "ln_attn_post", cfg)
         h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
+        if cfg.kv_lora_rank:
+            mlp, expert_layer = layer["mlp"], None
+            if "router" in mlp:
+                mlp, expert_layer = {**mlp, **experts}, li - cfg.first_k_dense
+            y, routed = latent_ffn(h, mlp, cfg, token_valid, expert_layer)
+            return (x + y, k_store, v_store), routed
         x = x + post_norm(_ffn(h, layer["mlp"], cfg, token_valid), layer,
                           "ln_mlp_post", cfg)
         return (x, k_store, v_store), None
 
-    def one_pass(carry, cache_layers):
-        (x, k_store, v_store), _ = lax.scan(
-            layer_fn, carry, (params["layers"], cache_layers))
-        return close_pass(x, params, cfg), k_store, v_store
+    # A latent model's expert stacks stay out of the scanned inputs: each
+    # layer reads its experts where they lie in the whole stacks
+    # (transformer._grouped), as the attention reads the K/V store.
+    scanned, experts = params["layers"], {}
+    if cfg.kv_lora_rank:
+        mlp = dict(scanned["mlp"])
+        experts = {name: mlp.pop(name) for name in ("gate", "up", "down")}
+        scanned = {**scanned, "mlp": mlp}
 
-    layers = jnp.arange(cfg.n_layers)
+    def one_pass(carry, cache_layers):
+        (x, k_store, v_store), routed = lax.scan(
+            layer_fn, carry, (scanned, cache_layers))
+        return (close_pass(x, params, cfg), k_store, v_store), routed
+
     carry = (x, k_store, v_store)
+    # A latent model's leading dense layers, ahead of the scan over its
+    # expert layers (cache layers first_k_dense and on).
+    for i in range(cfg.first_k_dense):
+        carry, _ = layer_fn(carry, (jax.tree.map(
+            lambda w: w[i], params["dense_layers"]), i))
+    layers = (jnp.arange(cfg.first_k_dense, cfg.n_layers)
+              if cfg.first_k_dense else jnp.arange(cfg.n_layers))
+    routed = None
     if cfg.n_passes == 1:
-        x, k_store, v_store = one_pass(carry, layers)
+        (x, k_store, v_store), routed = one_pass(carry, layers)
     else:
         (x, k_store, v_store), _ = lax.scan(
-            lambda c, u: (one_pass(c, u * cfg.n_layers + layers), None),
+            lambda c, u: (one_pass(c, u * cfg.n_layers + layers)[0], None),
             carry, jnp.arange(cfg.n_passes))
-    return _head(params, x, cfg), k_store, v_store
+    return _head(params, x, cfg), k_store, v_store, routed
 
 
 def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
@@ -877,10 +991,11 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
     ``xs``/``ys`` (that form copies the whole cache every step). With
     ``table`` the caches are the paged block pool read/written through
     the block table (``fused`` swaps the gathered read for the
-    block-walking attention kernel). Returns (logits [B, V], k, v)."""
+    block-walking attention kernel). Returns (logits [B, V], k, v, the
+    tokens each expert took or None: :func:`_layer_loop`)."""
     total = (k_cache0.shape[2] if table is None
              else table.shape[1] * _kv_arr(k_cache0).shape[2])
-    cos_t, sin_t = rotary_frequencies(cfg.head_dim, total,
+    cos_t, sin_t = rotary_frequencies(cfg.rope_dim, total,
                                       theta=cfg.rope_theta)
     rope_bt = (cos_t[pos_b[:, None]], sin_t[pos_b[:, None]])
     x = _embed(params, tok, cfg)[:, None]
@@ -889,14 +1004,17 @@ def _single_token_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
                    else None)
 
     def attend(h, attn, k_store, v_store, li):
+        if cfg.kv_lora_rank:
+            return _latent_attention(h, attn, cfg, rope_bt, k_store, li,
+                                     pos_b, token_valid, write_index)
         return _ragged_attention(h, attn, cfg, rope_bt, k_store, v_store, li,
                                  pos_b, valid, token_valid, table=table,
                                  fused=fused, mesh=mesh,
                                  write_index=write_index)
 
-    logits, k_new, v_new = _layer_loop(params, cfg, x, k_cache0, v_cache0,
-                                       attend, token_valid[:, None])
-    return logits[:, 0], k_new, v_new
+    logits, k_new, v_new, routed = _layer_loop(
+        params, cfg, x, k_cache0, v_cache0, attend, token_valid[:, None])
+    return logits[:, 0], k_new, v_new, routed
 
 
 def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
@@ -920,7 +1038,7 @@ def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
             state = {**state, **held}
             k_new, v_new = state["pool"]["k"], state["pool"]["v"]
         else:
-            logits, k_new, v_new = _single_token_forward(
+            logits, k_new, v_new, routed = _single_token_forward(
                 params, cfg, k0, v0, tok, p_b, emit, table=table,
                 fused=fused, mesh=mesh,
             )
@@ -943,6 +1061,10 @@ def _decode_step_body(state, params, cfg: TransformerConfig, top_k: int,
                                      state["last_logits"]),
             "key": key,
         }
+        if "moe_routed" in state:
+            new_state["moe_routed"] = state["moe_routed"] + routed
+            new_state["moe_touched"] = state["moe_touched"] + jnp.sum(
+                routed > 0, axis=-1, dtype=jnp.int32)
     return _with_kv(new_state, k_new, v_new), tok, emit
 
 
@@ -1083,7 +1205,7 @@ def _block_forward(params, cfg: TransformerConfig, k_cache0, v_cache0,
                                ring=ring)
 
     return _layer_loop(params, cfg, x, k_cache0, v_cache0, attend,
-                       token_valid)
+                       token_valid)[:3]
 
 
 def _target_probs(logits, temperature, top_k: int):
@@ -1190,7 +1312,7 @@ def _verify_step_body(state, params, cfg: TransformerConfig, draft,
     # by EOS above still run the pass; their write lands inside the row
     # but the row's length is parked at ``total`` so it is never read.
     commit_pos = p_b + n_eff
-    logits2, k2, v2 = _single_token_forward(
+    logits2, k2, v2, _ = _single_token_forward(
         params, cfg, k1, v1, commit, commit_pos, emit0, table=table,
         fused=fused, mesh=mesh,
     )
@@ -1342,6 +1464,10 @@ def init_paged_state(cfg: TransformerConfig, slots: int, num_blocks: int,
     if cfg.mixer_types:
         return _init_hybrid_state(cfg, slots, num_blocks, block_size,
                                   max_blocks_per_seq, seed, kv_dtype)
+    if cfg.kv_lora_rank:
+        raise ValueError(
+            "latent attention: the block pool holds K and V a head; a "
+            "latent cache serves on kv_layout='dense'")
     shape = (cfg.cache_layers, num_blocks, block_size, cfg.n_kv_heads,
              cfg.head_dim)
     if kv_dtype == "int8":
@@ -1882,9 +2008,12 @@ def max_admit_rows(cfg: TransformerConfig) -> int | None:
     one row a dispatch. An admission's scratch cache is as many rows of
     ``cfg.cache_layers`` layers; a looped stack's is ``n_passes`` times a
     plain one's (2 GB a row of 1,280 tokens at Ouro-2.6B's sizes): one
-    row a dispatch too."""
+    row a dispatch too. A latent model's admission expands every head's
+    K/V and scores the whole prompt, [H, T0, T0] float32 a row (537 MB a
+    row of 2,048 at Kanana-2-30B-A3B's 32 heads) beside weights and a
+    store that fill 11.6 of 16 GB: one row a dispatch."""
     sparse = cfg.mixer_types and cfg.layers_of(MIXER_SPARSE)
-    return 1 if sparse or cfg.n_passes > 1 else None
+    return 1 if sparse or cfg.n_passes > 1 or cfg.kv_lora_rank else None
 
 
 # ---------------------------------------------------------------------------

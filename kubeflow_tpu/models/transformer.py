@@ -38,6 +38,8 @@ from kubeflow_tpu.observability.tracing import (
     SCOPE_LINEAR_ATTN,
     SCOPE_LOOP_NORM,
     SCOPE_MLP,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTE,
     SCOPE_POST_NORM,
     SCOPE_SPARSE_ATTN,
     SCOPE_SPARSE_SELECT,
@@ -177,8 +179,36 @@ class TransformerConfig:
     # Sandwich norms: a second RMSNorm after each sublayer, inside the
     # residual branch (``x + norm(attn(norm(x)))``).
     post_norms: bool = False
+    # Latent attention (DeepSeek-V3's MLA, no query compression) where
+    # ``kv_lora_rank`` > 0: a head's query is ``qk_nope_head_dim`` +
+    # ``qk_rope_head_dim`` wide; its key and value come from ONE normed
+    # latent of ``kv_lora_rank`` a token through per-head up-projections
+    # (key ``qk_nope_head_dim`` + the rotary key, value ``v_head_dim``),
+    # and the rotary key is one for all heads. A cache holds the latent
+    # and the rotary key alone (``latent_width``); a decode step reads
+    # them with the up-projections absorbed into the query and the output.
+    # The rotary part rotates half-split pairs.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A latent model's experts (DeepSeek-V3's noaux_tc router, one group;
+    # every other model with ``n_experts`` has the GShard layer, moe_ffn):
+    # sigmoid scores, the top ``expert_top_k`` by score plus a selection
+    # bias held beside the router (the bias selects, it never weighs), the
+    # chosen scores normed and times ``routed_scaling``; every (token,
+    # choice) is computed, none dropped (moe_dropless), beside one MLP of
+    # ``n_shared_experts`` x ``d_expert`` that every token takes. The
+    # first ``first_k_dense`` layers have the dense ``d_ff`` MLP instead;
+    # a routed expert is ``d_expert`` wide.
+    routed_scaling: float = 1.0
+    n_shared_experts: int = 0
+    d_expert: int = 0
+    first_k_dense: int = 0
 
     def __post_init__(self):
+        if self.kv_lora_rank:
+            self._check_latent()
         if self.n_passes < 1:
             raise ValueError(f"n_passes must be >= 1, got {self.n_passes}")
         if self.exit_threshold < 1.0:
@@ -209,9 +239,45 @@ class TransformerConfig:
                              "pipeline_stages")
         self.sparse_spec  # validates the selection's sizes
 
+    def _check_latent(self):
+        """Latent attention and the dropless sigmoid-routed experts come
+        as one block (the tree :func:`_init_latent` draws): served on one
+        chip, by the plain stacked loop."""
+        if not (self.qk_nope_head_dim and self.qk_rope_head_dim
+                and self.v_head_dim and self.n_experts and self.d_expert):
+            raise ValueError(
+                "latent attention (kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim, v_head_dim) and sigmoid-routed experts "
+                "(n_experts, d_expert) come together: set all of them")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even (rotary pairs)")
+        if not 0 <= self.first_k_dense < self.n_layers:
+            raise ValueError(f"first_k_dense {self.first_k_dense} must leave "
+                             f"an expert layer of {self.n_layers}")
+        if (self.mixer_types or self.n_passes > 1 or self.post_norms
+                or self.context_parallel or self.pipeline_stages):
+            raise ValueError(
+                "latent attention composes with the plain stacked block on "
+                "one chip: no mixer_types, n_passes, post_norms, "
+                "context_parallel or pipeline_stages")
+
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """The width the rotary tables are made for: a latent model's
+        rotary part, every other model's whole head."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """A latent cache's row a token and layer: the latent and the
+        rotary key, padded with zeros to whole 128-lane tiles (a TPU
+        lays the row out so whatever its width; the padding is never
+        read as data)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     @property
     def cache_layers(self) -> int:
@@ -336,6 +402,32 @@ PRESETS: dict[str, TransformerConfig] = {
         d_ff=128, max_seq_len=128, rope_theta=1e6, norm_eps=1e-6,
         remat=False, n_passes=4, post_norms=True,
     ),
+    # Kanana-2-30B-A3B as published (kakaocorp/kanana-2-30b-a3b-instruct-
+    # 2601 config.json, model_type deepseek_v3): latent attention over a
+    # 512 + 64 cache, no query compression; a dense first layer, then 47
+    # layers of 128 sigmoid-routed experts (6 a token, routed_scaling
+    # 2.448) beside a shared MLP of 2 x 768. Serving only, on the dense
+    # layout; its 30.7 B parameters want 8 chips (docs/serving.md says
+    # what refuses it).
+    "kanana-2-30b-a3b": TransformerConfig(
+        vocab_size=128_256, d_model=2048, n_layers=48, n_heads=32,
+        n_kv_heads=32, d_ff=6144, max_seq_len=32_768, rope_theta=1e6,
+        norm_eps=1e-6, remat=False, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, n_experts=128, expert_top_k=6,
+        routed_scaling=2.448, n_shared_experts=2, d_expert=768,
+        first_k_dense=1,
+    ),
+    # The same block at toy widths (a dense layer and two expert layers of
+    # 8 experts, 2 a token, one shared; latent 32 + rotary 16): what the
+    # CPU tests serve.
+    "kanana-test-tiny": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        d_ff=128, max_seq_len=128, rope_theta=1e6, norm_eps=1e-6,
+        remat=False, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=16, n_experts=8, expert_top_k=2,
+        routed_scaling=2.448, n_shared_experts=1, d_expert=32,
+        first_k_dense=1,
+    ),
 }
 
 
@@ -357,6 +449,8 @@ def init(key, cfg: TransformerConfig):
     :func:`serving_params` would give, one leaf of float32 at a time."""
     if cfg.mixer_types:
         return _init_hybrid(key, cfg)
+    if cfg.kv_lora_rank:
+        return _init_latent(key, cfg)
     d, f = cfg.d_model, cfg.d_ff
     hd = cfg.head_dim
     # NOTE: split count must stay 8 — changing it would silently reshuffle
@@ -460,6 +554,76 @@ def _init_hybrid(key, cfg: TransformerConfig):
     return params
 
 
+def _init_latent(key, cfg: TransformerConfig):
+    """The tree of a latent-attention model: ``dense_layers`` (the first
+    ``first_k_dense``, a dense MLP) and ``layers`` (the expert layers),
+    each a stacked tree. A layer's ``attn``: ``wq`` [D, H*(nope+rope)],
+    ``wkv_a`` [D, rank+rope] (the latent and the rotary key), ``kv_norm``
+    [rank], the up-projections ``w_uk`` [H, nope, rank] and ``w_uv``
+    [H, rank, v] (a head's key and value from the latent), ``wo``
+    [H*v, D]. An expert layer's ``mlp``: ``router`` [D, E] and ``bias``
+    [E] float32, the experts' ``gate``/``up`` [E, D, d_expert] and
+    ``down`` [E, d_expert, D], the shared ``shared_gate``/``shared_up``
+    [D, n_shared*d_expert] and ``shared_down``. Matrices are drawn at
+    cfg.dtype a layer's leaf at a time (a float32 tree of this size would
+    not leave a 16 GB chip room, as with ``mixer_types``)."""
+    d, h, rank = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    e, fe = cfg.n_experts, cfg.d_expert
+    fs = cfg.n_shared_experts * fe
+    attn_shapes = {"wq": ((d, h * (nope + rope)), d),
+                   "wkv_a": ((d, rank + rope), d),
+                   "w_uk": ((h, nope, rank), nope),
+                   "w_uv": ((h, rank, v), rank),
+                   "wo": ((h * v, d), h * v)}
+    dense_mlp = {"gate": ((d, cfg.d_ff), d), "up": ((d, cfg.d_ff), d),
+                 "down": ((cfg.d_ff, d), cfg.d_ff)}
+    expert_mlp = {"gate": ((e, d, fe), d), "up": ((e, d, fe), d),
+                  "down": ((e, fe, d), fe), "shared_gate": ((d, fs), d),
+                  "shared_up": ((d, fs), d), "shared_down": ((fs, d), fs)}
+
+    def matrix(layers, i, shape_fan):
+        shape, fan_in = shape_fan
+        return jnp.stack([
+            (jax.random.normal(jax.random.fold_in(
+                jax.random.fold_in(key, 1000 + layer), i), shape,
+                jnp.float32) * fan_in ** -0.5).astype(cfg.dtype)
+            for layer in layers])
+
+    def stack(layers, mlp_shapes):
+        n = len(layers)
+        mlp = {name: matrix(layers, 10 + i, shape)
+               for i, (name, shape) in enumerate(mlp_shapes.items())}
+        if mlp_shapes is expert_mlp:
+            mlp["router"] = jnp.stack([
+                jax.random.normal(jax.random.fold_in(jax.random.fold_in(
+                    key, 1000 + layer), 9), (d, e), jnp.float32) * d ** -0.5
+                for layer in layers])
+            mlp["bias"] = jnp.zeros((n, e), jnp.float32)
+        attn = {name: matrix(layers, i, shape)
+                for i, (name, shape) in enumerate(attn_shapes.items())}
+        attn["kv_norm"] = jnp.ones((n, rank), jnp.float32)
+        return {"attn": attn, "mlp": mlp,
+                "ln_attn": jnp.ones((n, d), jnp.float32),
+                "ln_mlp": jnp.ones((n, d), jnp.float32)}
+
+    keys = jax.random.split(key, 2)
+    k = cfg.first_k_dense
+    params = {
+        "embed": {"kernel": (jax.random.normal(
+            keys[0], (cfg.vocab_size, d), jnp.float32) * d ** -0.5
+        ).astype(cfg.dtype)},
+        "dense_layers": stack(range(k), dense_mlp),
+        "layers": stack(range(k, cfg.n_layers), expert_mlp),
+        "final_norm": jnp.ones((d,), jnp.float32),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"kernel": (jax.random.normal(
+            keys[1], (d, cfg.vocab_size), jnp.float32) * d ** -0.5
+        ).astype(cfg.dtype)}
+    return params
+
+
 def partition_rules(cfg: TransformerConfig) -> list[PartitionRule]:
     """DP×FSDP×TP(×EP) layout. Stacked layer weights carry a leading L dim
     (never sharded). Megatron pairing: column-parallel in (wq/wk/wv/gate/up),
@@ -542,7 +706,9 @@ def project(x, attn, name: str, dtype):
 _COMPUTE_DTYPE_LEAF = re.compile(
     r"^(embed/kernel|lm_head/kernel|layers/attn/w[qkvo]"
     r"|layers/mlp/(gate|up|down)"
-    r"|layers/\d+/mixer/w[qkvog]|layers/\d+/mlp/(gate|up|down))$")
+    r"|layers/\d+/mixer/w[qkvog]|layers/\d+/mlp/(gate|up|down)"
+    r"|(dense_)?layers/attn/(wq|wkv_a|w_uk|w_uv|wo)"
+    r"|(dense_)?layers/mlp/(shared_)?(gate|up|down))$")
 # The stacked q/k/v that a layer loop slices inside its scan. Held as
 # [L, D, N], XLA relays each to [L, N, D] ({1,2,0}) for the dot, a whole
 # stack every step; a serving tree holds them so (PERF.md, PR 39).
@@ -718,6 +884,169 @@ def moe_ffn(x, mlp, cfg: TransformerConfig, token_valid=None):
         mean_prob = jnp.mean(probs, axis=0)
     aux = e * jnp.sum(top1_frac * mean_prob)
     return y.reshape(b, t, d).astype(x.dtype), aux
+
+
+def _grouped(xs, w, sizes, layer):
+    """``lax.ragged_dot`` of rows ``xs`` grouped by ``sizes`` [E] against
+    experts ``w`` [E, ...]; with ``layer`` (traced), ``w`` is the WHOLE
+    stack [L, E, ...] of every expert layer, read as L x E groups of which
+    only the layer's hold rows: a slice of the stack would be a copy of
+    the layer's experts (the product is a kernel that takes its operand
+    whole), as large as what the product reads."""
+    if layer is None:
+        return lax.ragged_dot(xs, w, sizes)
+    n_layers, e = w.shape[:2]
+    every = lax.dynamic_update_slice(
+        jnp.zeros((n_layers * e,), sizes.dtype), sizes, (layer * e,))
+    return lax.ragged_dot(xs, w.reshape(n_layers * e, *w.shape[2:]), every)
+
+
+def moe_dropless(x, mlp, cfg: TransformerConfig, token_valid=None,
+                 layer=None):
+    """The expert layer of a latent model (``kv_lora_rank``) on x [..., D]:
+    ``(y [..., D], routed [E] int32)``, ``routed`` the tokens each expert
+    took (of those ``token_valid`` marks: a pad token is routed and
+    computed like any, counted by none; with no capacity it displaces
+    nobody).
+
+    Router in float32: s = sigmoid(x W_r); the top ``expert_top_k`` by
+    s + bias; weights ``routed_scaling`` x s over their sum (the bias
+    selects, it never weighs). Every (token, choice) pair is computed:
+    the pairs are sorted by expert and each expert's group goes through
+    its matrices as one grouped product (``lax.ragged_dot``: a touched
+    expert's weights are read once a call, however many tokens it took),
+    then each token's pairs are gathered back and weighed. The shared
+    MLP takes every token. With ``layer`` the experts' matrices in
+    ``mlp`` are the stacks of every expert layer (:func:`_grouped`)."""
+    shape, d = x.shape, x.shape[-1]
+    e, k = cfg.n_experts, cfg.expert_top_k
+    xf = x.reshape(-1, d)
+    n = xf.shape[0]
+    dtype = cfg.dtype
+    with scope(SCOPE_MOE_ROUTE):
+        scores = jax.nn.sigmoid(lax.dot(
+            xf.astype(jnp.float32), cast_param(mlp["router"], jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(scores + mlp["bias"], k)  # [n, k]
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        weight = cfg.routed_scaling * picked / jnp.sum(picked, -1,
+                                                       keepdims=True)
+        flat = chosen.reshape(n * k)
+        order = jnp.argsort(flat)
+        sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+        valid = (jnp.ones((n,), jnp.int32) if token_valid is None
+                 else token_valid.reshape(n).astype(jnp.int32))
+        routed = jnp.bincount(flat, weights=jnp.repeat(valid, k),
+                              length=e).astype(jnp.int32)
+    with scope(SCOPE_MOE_EXPERTS):
+        xs = xf.astype(dtype)[order // k]  # [n*k, D], grouped by expert
+        gate = _grouped(xs, cast_param(mlp["gate"], dtype), sizes, layer)
+        up = _grouped(xs, cast_param(mlp["up"], dtype), sizes, layer)
+        out = _grouped((jax.nn.silu(gate) * up).astype(dtype),
+                       cast_param(mlp["down"], dtype), sizes, layer)
+        pairs = out[jnp.argsort(order)].reshape(n, k, d)
+        y = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32), weight)
+        sg = xf @ cast_param(mlp["shared_gate"], dtype)
+        su = xf @ cast_param(mlp["shared_up"], dtype)
+        y = y + (jax.nn.silu(sg) * su) @ cast_param(mlp["shared_down"], dtype)
+    return y.reshape(shape).astype(x.dtype), routed
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (cfg.kv_lora_rank)
+# ---------------------------------------------------------------------------
+#
+# The pieces models/decode.py calls against its latent store, and the
+# cache-free forward. A cache row is [c | k_rope | 0], ``latent_width``
+# wide: the normed latent, the rotary key, zeros to the tile.
+
+
+def rotate_half(x, cos, sin):
+    """Half-split rotary on x [B, S, H, r] at (cos, sin) [B, S, r/2]
+    gathered per row at each token's position: the pairs (x[..., i],
+    x[..., i + r/2]). The decode paths' rotary, every model's."""
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    c, s = cos[:, :, None, :], sin[:, :, None, :]
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x1 * s + x2 * c], axis=-1
+    ).astype(x.dtype)
+
+
+def mla_inputs(x, attn, cfg: TransformerConfig, rope_bt):
+    """x [B, S, D] with rotary tables ``rope_bt`` (cos, sin) [B, S, r/2]
+    at each token's position → (q_nope [B, S, H, nope], q_rope
+    [B, S, H, rope] rotated, the cache row [B, S, latent_width])."""
+    b, s, _ = x.shape
+    h, nope, rank = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    rope, dtype = cfg.qk_rope_head_dim, cfg.dtype
+    cos, sin = rope_bt
+    q = project(x, attn, "q", dtype).reshape(b, s, h, nope + rope)
+    kv = x @ cast_param(attn["wkv_a"], dtype)
+    c = rms_norm(kv[..., :rank], attn["kv_norm"], eps=cfg.norm_eps)
+    k_rope = rotate_half(kv[..., None, rank:], cos, sin)[:, :, 0]
+    row = jnp.concatenate(
+        [c.astype(dtype), k_rope.astype(dtype),
+         jnp.zeros((b, s, cfg.latent_width - rank - rope), dtype)], axis=-1)
+    return q[..., :nope], rotate_half(q[..., nope:], cos, sin), row
+
+
+def mla_expanded(q_nope, q_rope, rows, mask, attn, cfg: TransformerConfig):
+    """Latent attention as published: every head's key [k_nope | k_rope]
+    and value expanded from ``rows`` [B, T, latent_width]; queries q_nope
+    [B, S, H, nope], q_rope [B, S, H, rope]; ``mask`` [B, S, T]. Returns
+    [B, S, D] (after ``wo``)."""
+    b, s, h, _ = q_nope.shape
+    rank, rope, dtype = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.dtype
+    c, k_rope = rows[..., :rank], rows[..., rank:rank + rope]
+    k_nope = jnp.einsum("btr,hnr->bthn", c, cast_param(attn["w_uk"], dtype))
+    v = jnp.einsum("btr,hrv->bthv", c, cast_param(attn["w_uv"], dtype))
+    scores = (jnp.einsum("bshn,bthn->bhst", q_nope.astype(jnp.float32),
+                         k_nope.astype(jnp.float32))
+              + jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
+                           k_rope.astype(jnp.float32))
+              ) * (cfg.qk_nope_head_dim + rope) ** -0.5
+    scores = jnp.where(mask[:, None], scores, -1e30)
+    p = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    o = jnp.einsum("bhst,bthv->bshv", p, v).reshape(b, s, h * cfg.v_head_dim)
+    return o.astype(dtype) @ cast_param(attn["wo"], dtype)
+
+
+def latent_ffn(h, mlp, cfg: TransformerConfig, token_valid=None,
+               layer=None):
+    """A latent model's layer MLP: the expert layer where the tree has a
+    router, the dense SwiGLU of a leading layer; ``(y, routed or
+    None)``. ``layer``: as :func:`moe_dropless` takes it."""
+    if "router" in mlp:
+        with scope(SCOPE_MLP):
+            return moe_dropless(h, mlp, cfg, token_valid, layer)
+    return _mlp(h, mlp, cfg), None
+
+
+def _latent_layers(params, x, cfg: TransformerConfig):
+    """The cache-free forward of a latent model on x [B, T, D], whole
+    sequences from position 0: the leading dense layers, then a scan over
+    the expert layers."""
+    b, t, _ = x.shape
+    cos, sin = rotary_frequencies(cfg.qk_rope_head_dim, t,
+                                  theta=cfg.rope_theta)
+    rope_bt = (jnp.broadcast_to(cos[None], (b, *cos.shape)),
+               jnp.broadcast_to(sin[None], (b, *sin.shape)))
+    mask = jnp.broadcast_to(jnp.tril(jnp.ones((t, t), bool))[None],
+                            (b, t, t))
+
+    def layer_fn(x, layer):
+        h = rms_norm(x, layer["ln_attn"], eps=cfg.norm_eps)
+        with scope(SCOPE_ATTN):
+            q_nope, q_rope, rows = mla_inputs(h, layer["attn"], cfg, rope_bt)
+            x = x + mla_expanded(q_nope, q_rope, rows, mask, layer["attn"],
+                                 cfg)
+        h = rms_norm(x, layer["ln_mlp"], eps=cfg.norm_eps)
+        return x + latent_ffn(h, layer["mlp"], cfg)[0], None
+
+    for i in range(cfg.first_k_dense):
+        x, _ = layer_fn(x, jax.tree.map(lambda w: w[i],
+                                        params["dense_layers"]))
+    return lax.scan(layer_fn, x, params["layers"])[0]
 
 
 # ---------------------------------------------------------------------------
@@ -935,6 +1264,10 @@ def hidden_states(params, tokens, cfg: TransformerConfig, *, mesh=None):
     if cfg.mixer_types:
         x = _embed_lookup(params["embed"]["kernel"], tokens, cfg, mesh)
         x = _hybrid_layers(params, x * cfg.embed_scale, cfg)
+        return final_hidden(x, params, cfg), jnp.zeros((), jnp.float32)
+    if cfg.kv_lora_rank:
+        x = _embed_lookup(params["embed"]["kernel"], tokens, cfg, mesh)
+        x = _latent_layers(params, x, cfg)
         return final_hidden(x, params, cfg), jnp.zeros((), jnp.float32)
     t = tokens.shape[1]
     rope = rotary_frequencies(cfg.head_dim, t, theta=cfg.rope_theta)

@@ -83,6 +83,16 @@ MIXER_SCOPES = (SCOPE_LINEAR_ATTN, SCOPE_SPARSE_SELECT, SCOPE_SPARSE_ATTN)
 SCOPE_LOOP_NORM = "loop_norm"
 SCOPE_POST_NORM = "post_norm"
 LOOP_SCOPES = (SCOPE_LOOP_NORM, SCOPE_POST_NORM)
+# Latent attention and the dropless expert layer (models/transformer.py:
+# ``kv_lora_rank``, which brings both): inside ``attn`` of a
+# decode step, the absorbed projections and the read of the latent store;
+# inside ``mlp``, the router (scores, top-k, the sort into groups and their
+# sizes) and the experts (the grouped products, the shared MLP, the
+# weighted sum). A tuple of their own for the same reason.
+SCOPE_LATENT_ATTN = "latent_attn"
+SCOPE_MOE_ROUTE = "moe_route"
+SCOPE_MOE_EXPERTS = "moe_experts"
+LATENT_MOE_SCOPES = (SCOPE_LATENT_ATTN, SCOPE_MOE_ROUTE, SCOPE_MOE_EXPERTS)
 
 # Host spans of the scheduler thread (serving/continuous.py:_run): one
 # ``sched.round`` per pass of the loop, its children named by phase.

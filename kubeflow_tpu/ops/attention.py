@@ -675,6 +675,11 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *,
 # Positions a chunk of the kernel's inner loop copies and attends (timings
 # of the alternatives on a v5e: PERF.md, PR 37).
 _DENSE_CHUNK = 128
+# The same for a latent store, whose position is one row of 1,280 bytes
+# (Kanana-2-30B-A3B's) where Mistral-7B's is 2,048 of K and 2,048 of V: a
+# copy of 327,680 bytes, about one side of a dense chunk at Mistral's
+# sizes (not measured against other sizes).
+_LATENT_CHUNK = 256
 
 
 def _dense_kernel_unsupported(head_dim: int, dtype) -> str | None:
@@ -695,13 +700,21 @@ def dense_decode_implementation(head_dim: int, dtype) -> str:
     return "xla" if _dense_kernel_unsupported(head_dim, dtype) else "pallas"
 
 
-def _dense_decode_xla(qg, k_store, v_store, li, lengths):
+def _dense_decode_xla(qg, k_store, v_store, li, lengths, sm_scale=None,
+                      v_dim=None):
     """The layer's rows sliced out whole and masked: qg [B, Hkv, G, hd]
-    → [B, Hkv, G, hd] in q's dtype."""
+    → [B, Hkv, G, hd] in q's dtype. A latent store (``v_store`` None) is
+    one KV head whose value is the row's first ``v_dim`` columns."""
     k = lax.dynamic_index_in_dim(k_store, li, 0, keepdims=False)
-    v = lax.dynamic_index_in_dim(v_store, li, 0, keepdims=False)
+    if v_store is None:
+        k = k[:, :, None]
+        v = k[..., :v_dim]
+    else:
+        v = lax.dynamic_index_in_dim(v_store, li, 0, keepdims=False)
+    if sm_scale is None:
+        sm_scale = qg.shape[-1] ** -0.5
     scores = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * qg.shape[-1] ** -0.5
+                        k.astype(jnp.float32)) * sm_scale
     seen = (jnp.arange(k.shape[1])[None] < lengths[:, None])[:, None, None]
     top = jnp.max(jnp.where(seen, scores, _NEG_INF), axis=-1, keepdims=True)
     e = jnp.where(seen, jnp.exp(scores - top), 0.0)
@@ -710,9 +723,12 @@ def _dense_decode_xla(qg, k_store, v_store, li, lengths):
                       preferred_element_type=jnp.float32).astype(qg.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "v_dim",
+                                             "sm_scale"))
 def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
-                         chunk: int = _DENSE_CHUNK, interpret: bool = False):
+                         chunk: int = _DENSE_CHUNK, interpret: bool = False,
+                         v_dim: int | None = None,
+                         sm_scale: float | None = None):
     """The kernel of :func:`dense_decode_attention`. q [B, Hq, hd]; the
     stores whole, in HBM; ``li`` (traced) and ``lengths`` [B] are
     scalar-prefetched. Jitted on its own so that the kernel is traced once
@@ -736,18 +752,31 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
     element once, as a product a head would, and the scores of a chunk
     are ``Hq·Hkv·chunk`` floats. Scores, running maximum, normaliser and
     accumulator are float32; K, V and the probabilities enter the MXU at
-    the store's dtype."""
+    the store's dtype.
+
+    A latent store (``v_store`` None: :func:`dense_decode_attention`) is
+    ``[cache layers, B, T, W]``, one row a position that every head reads
+    as its key whole and as its value by its first ``v_dim`` columns: one
+    copy a chunk, no head columns to mask, ``sm_scale`` the caller's."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, hd = q.shape
-    t, hkv = k_store.shape[2], k_store.shape[3]
+    latent = v_store is None
+    t, hkv = k_store.shape[2], 1 if latent else k_store.shape[3]
     group = hq // hkv
     tc = min(chunk, t)
-    sm_scale = hd ** -0.5
+    dv = v_dim if latent else hd
+    if sm_scale is None:
+        sm_scale = hd ** -0.5
 
-    def kernel(li_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
-               sems, nxt_ref):
+    def kernel(li_ref, len_ref, q_ref, *refs):
+        if latent:
+            k_hbm, o_ref, k_buf, sems, nxt_ref = refs
+            pairs = ((k_hbm, k_buf),)
+        else:
+            k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, nxt_ref = refs
+            pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
         # nxt_ref[0]: the buffer the next chunk to compute lands in;
         # nxt_ref[1]: whether the row before has started this row's first
         # chunk.
@@ -764,8 +793,7 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
             return [pltpu.make_async_copy(
                 hbm.at[layer, r, pl.ds(first(c), tc)], vmem.at[buf],
                 sems.at[s, buf])
-                for s, (hbm, vmem) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf)))]
+                for s, (hbm, vmem) in enumerate(pairs)]
 
         def start(r, c, buf):
             for copy in copies(r, c, buf):
@@ -801,21 +829,28 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
                 nxt_ref[1] = 1
 
             nxt_ref[0] = other
-            k_copy, v_copy = copies(row, c, buf)
+            k_copy, *v_copy = copies(row, c, buf)
             k_copy.wait()
             k = k_buf[buf].reshape(tc * hkv, hd)
             s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-            pos = first(c) + col // hkv
-            seen = (col % hkv == head) & (pos >= c * tc) & (pos < length)
+            if latent:
+                pos = first(c) + col
+                seen = (pos >= c * tc) & (pos < length)
+            else:
+                head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+                pos = first(c) + col // hkv
+                seen = (col % hkv == head) & (pos >= c * tc) & (pos < length)
             s = jnp.where(seen, s * sm_scale, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             e = jnp.where(seen, jnp.exp(s - m_new), 0.0)
             fade = jnp.exp(m - m_new)
-            v_copy.wait()
-            v = v_buf[buf].reshape(tc * hkv, hd)
+            if latent:
+                v = k[:, :dv]
+            else:
+                v_copy[0].wait()
+                v = v_buf[buf].reshape(tc * hkv, hd)
             acc = acc * fade + jnp.dot(e.astype(v.dtype), v,
                                        preferred_element_type=jnp.float32)
             return m_new, l * fade + e.sum(axis=-1, keepdims=True), acc
@@ -824,37 +859,47 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
             0, chunks, body,
             (jnp.full((hq, 1), _NEG_INF, jnp.float32),
              jnp.zeros((hq, 1), jnp.float32),
-             jnp.zeros((hq, hd), jnp.float32)))
+             jnp.zeros((hq, dv), jnp.float32)))
         o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
     q_spec = pl.BlockSpec((None, hq, hd), lambda row, *_: (row, 0, 0))
+    if latent:
+        stores = (k_store,)
+        o_spec = pl.BlockSpec((None, hq, dv), lambda row, *_: (row, 0, 0))
+        buffers = [pltpu.VMEM((2, tc, hd), k_store.dtype)]
+    else:
+        stores = (k_store, v_store)
+        o_spec = q_spec
+        buffers = [pltpu.VMEM((2, tc, hkv, hd), k_store.dtype),
+                   pltpu.VMEM((2, tc, hkv, hd), v_store.dtype)]
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
-            in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=q_spec,
-            scratch_shapes=[
-                pltpu.VMEM((2, tc, hkv, hd), k_store.dtype),
-                pltpu.VMEM((2, tc, hkv, hd), v_store.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
+                                 for _ in stores],
+            out_specs=o_spec,
+            scratch_shapes=buffers + [
+                pltpu.SemaphoreType.DMA((len(stores), 2)),
                 pltpu.SMEM((2,), jnp.int32),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="dense_decode_attention",
+        name="latent_decode_attention" if latent
+        else "dense_decode_attention",
     )(jnp.reshape(li, (1,)).astype(jnp.int32),
-      jnp.clip(lengths, 0, t).astype(jnp.int32), q, k_store, v_store)
+      jnp.clip(lengths, 0, t).astype(jnp.int32), q, *stores)
 
 
 def dense_decode_attention(q, k_store, v_store, li, lengths, *,
                            n_kv_heads: int,
                            implementation: str | None = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           v_dim: int | None = None,
+                           sm_scale: float | None = None):
     """Single-token attention over cache layer ``li`` of the dense K/V
     store, each row over the positions it holds.
 
@@ -869,8 +914,17 @@ def dense_decode_attention(q, k_store, v_store, li, lengths, *,
     "pallas" or "xla". An explicit "pallas" that cannot be compiled for
     this backend or shape raises (``interpret=True`` runs it in the
     interpreter anywhere). The kernel reads ``lengths[b]`` positions a row
-    in place; the XLA path slices the layer out whole and masks."""
+    in place; the XLA path slices the layer out whole and masks.
+
+    A latent store (latent attention's cache, models/decode.py): ``v_store``
+    None and ``k_store`` ``[cache layers, B, T, W]``, one row a position
+    shared by every head. q [B, Hq, W] holds each head's query over the
+    whole row (the absorbed latent query, the rotary query, zeros where the
+    row is padding); a head's value is the row's first ``v_dim`` columns,
+    so the result is [B, Hq, v_dim]. ``sm_scale`` is the model's (the
+    query's width is not its scale here); ``n_kv_heads`` is 1."""
     b, hq, hd = q.shape
+    latent = v_store is None
     if hq % n_kv_heads:
         raise ValueError(
             f"query heads {hq} not a multiple of kv heads {n_kv_heads}")
@@ -880,9 +934,16 @@ def dense_decode_attention(q, k_store, v_store, li, lengths, *,
         if why := _dense_kernel_unsupported(hd, k_store.dtype):
             raise ValueError(
                 f"dense_decode_attention(implementation='pallas'): {why}")
+    if implementation == "pallas" and latent:
+        return _dense_decode_pallas(q, k_store, None, li, lengths,
+                                    chunk=_LATENT_CHUNK, interpret=interpret,
+                                    v_dim=v_dim, sm_scale=sm_scale)
     if implementation == "pallas":
         return _dense_decode_pallas(q, k_store, v_store, li, lengths,
                                     interpret=interpret)
+    if implementation == "xla" and latent:
+        return _dense_decode_xla(q[:, None], k_store, None, li, lengths,
+                                 sm_scale, v_dim)[:, 0]
     if implementation == "xla":
         return _dense_decode_xla(
             q.reshape(b, n_kv_heads, hq // n_kv_heads, hd), k_store, v_store,

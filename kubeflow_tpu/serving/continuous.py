@@ -336,6 +336,39 @@ def _check_looped(**using) -> None:
                 "stack that runs several times a token")
 
 
+def _check_latent(**using) -> None:
+    """A latent-attention model holds one latent row a token and layer,
+    which every head reads, where every other model holds K and V a head.
+    Everything named here moves, shares, shards or quantizes K/V by head,
+    or has never been run over a latent cache, and would serve such a
+    model wrongly or not at all, so each is refused by name at
+    construction (ROADMAP Queue 2, M1 and M3, is what would lift them)."""
+    refused = {
+        "kv_layout": "the paged KV layout (kv_layout='paged': the block "
+                     "pool holds K and V a head)",
+        "prefix_cache_slots": "the prefix cache (prefix_cache_slots: its "
+                              "pool holds K and V a head)",
+        "speculative_k": "speculative decoding (speculative_k: the verify "
+                         "pass reads K and V a head)",
+        "kv_dtype": "int8 KV (kv_dtype='int8')",
+        "kv_fused": "the fused block-table kernel (kv_fused)",
+        "tp_shards": "tensor parallelism (tp_shards > 1: the store shards "
+                     "by KV head, and a latent row has none)",
+        "cp_shards": "context parallelism (cp_shards > 1)",
+        "pp_stages": "pipeline parallelism (pp_stages > 1)",
+        "host_kv_bytes": "stream suspension to the host tier "
+                         "(host_kv_bytes)",
+        "role": "the prefill/decode handoff (role: export_blocks / "
+                "import_blocks carry pool blocks)",
+        "kv_directory": "the fleet KV economy (kv_directory / cold_store)",
+    }
+    for option, on in using.items():
+        if on:
+            raise ValueError(
+                f"kv_lora_rank: {refused[option]} is not supported for a "
+                "model with latent attention")
+
+
 def _weights_footprint(params) -> tuple[int, str]:
     """(bytes of every leaf of a serving tree, dtype of its matrices)."""
     nbytes = sum(int(leaf.nbytes) for leaf in jax.tree.leaves(params))
@@ -400,6 +433,16 @@ class ContinuousDecoder:
         if cfg.mixer_types:
             _check_hybrid(
                 kv_layout=kv_layout != "paged",
+                prefix_cache_slots=prefix_cache_slots > 0,
+                speculative_k=speculative_k > 0, kv_dtype=kv_dtype == "int8",
+                kv_fused=kv_fused, tp_shards=self.tp_shards > 1,
+                cp_shards=self.cp_shards > 1, pp_stages=self.pp_stages > 1,
+                host_kv_bytes=host_kv_bytes > 0, role=bool(role),
+                kv_directory=(kv_directory is not None
+                              or cold_store is not None))
+        if cfg.kv_lora_rank:
+            _check_latent(
+                kv_layout=kv_layout != "dense",
                 prefix_cache_slots=prefix_cache_slots > 0,
                 speculative_k=speculative_k > 0, kv_dtype=kv_dtype == "int8",
                 kv_fused=kv_fused, tp_shards=self.tp_shards > 1,
@@ -608,7 +651,8 @@ class ContinuousDecoder:
         self.kv_bytes_per_token = kv_bytes_per_token(
             cfg.cache_layers, cfg.n_kv_heads, cfg.head_dim,
             jnp.dtype(cfg.dtype).itemsize, kv_dtype,
-            tp_shards=self.tp_shards)
+            tp_shards=self.tp_shards,
+            latent=cfg.kv_lora_rank + cfg.qk_rope_head_dim)
         if kv_layout == "paged":
             self.kv_block_size = max(1, int(kv_block_size))
             if self.total_len % self.kv_block_size:
@@ -661,11 +705,13 @@ class ContinuousDecoder:
         # the K/V lies in a block pool): the kernel that stops at each
         # row's length, or the whole-row XLA read (off the TPU, heads not
         # lane-aligned, and under a mesh, where the kernel could not be
-        # partitioned).
+        # partitioned). A latent store's row is its "head".
         cache = self._state.get("cache")
         self.dense_attn_impl = (
             "" if cache is None else "xla" if self.mesh is not None
-            else dense_decode_implementation(cfg.head_dim, cache["k"].dtype))
+            else dense_decode_implementation(
+                cfg.latent_width if cfg.kv_lora_rank else cfg.head_dim,
+                cache["k"].dtype))
         self._admit_rows = max_admit_rows(cfg)
         if self.mesh is not None:
             # KV payload onto the mesh, head-sharded (and layer-sharded
@@ -818,13 +864,13 @@ class ContinuousDecoder:
         self.sparse_tokens_in_context = 0  # tokens the rows held
         self.rows_dense = 0                # row-steps at or under dense_len
         self.rows_sparse = 0               # row-steps over it
-        # Looped-stack counter (zero unless n_passes > 1): per emitted
-        # token the length of its row, i.e. the tokens each of the step's
-        # cache layers attended, from the lengths the host has. Such a
-        # model routes tokens through _dispatch_looped; a plain model's
-        # round runs no statement of this.
+        # Looped-stack and latent-attention counter (zero otherwise): per
+        # emitted token the length of its row, i.e. the tokens each of the
+        # step's cache layers attended, from the lengths the host has.
+        # Such a model routes tokens through _dispatch_looped; a plain
+        # model's round runs no statement of this.
         self.kv_tokens_attended = 0
-        if cfg.n_passes > 1:
+        if cfg.n_passes > 1 or cfg.kv_lora_rank:
             self._dispatch = self._dispatch_looped
         self.kv_blocks_peak = 0      # high-water blocks_in_use
         self.peak_in_flight = 0      # high-water concurrent requests
@@ -2914,8 +2960,9 @@ class ContinuousDecoder:
             rec.routed_late += gapped_n
 
     def _dispatch_looped(self, toks: np.ndarray, emitted: np.ndarray) -> None:
-        """:meth:`_dispatch` for a looped stack (bound over it at
-        construction where ``n_passes`` > 1): counts, per emitted token,
+        """:meth:`_dispatch` for a looped stack or a latent-attention model
+        (bound over it at construction where ``n_passes`` > 1 or
+        ``kv_lora_rank``): counts, per emitted token,
         the tokens its row held when the step that emitted it read the
         row, then routes as every model does. Plain decode steps and
         admissions' steps come through here; a verify round's tokens
@@ -3617,6 +3664,18 @@ class ContinuousDecoder:
             snap["qkv_held_transposed"] = self.qkv_held_transposed
             snap["weights_bytes"], snap["weights_dtype"] = \
                 _weights_footprint(self.params)
+            counts = None
+            if self.cfg.kv_lora_rank and self._state is not None:
+                # The device's own counts (the dropless expert layer),
+                # copied on the device behind the step in flight: the next
+                # step donates the state, its copy outlives it.
+                counts = (jnp.copy(self._state["moe_routed"]),
+                          jnp.copy(self._state["moe_touched"]))
+        if counts is not None:
+            # Fetched here and only here (no round asks), outside the lock.
+            routed, touched = jax.device_get(counts)
+            snap["moe_routed"] = routed.tolist()
+            snap["moe_touched"] = touched.tolist()
         # Allocator / trie stats live under the prefix lock — taken in a
         # SEPARATE scope (never nested with the metrics lock) so the two
         # subsystems can't deadlock against each other.

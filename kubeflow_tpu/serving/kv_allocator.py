@@ -36,7 +36,7 @@ KV_SCALE_BYTES = 4
 
 def kv_bytes_per_token(cache_layers: int, n_kv_heads: int, head_dim: int,
                        fp_bytes: int, kv_dtype: str = "fp",
-                       tp_shards: int = 1) -> int:
+                       tp_shards: int = 1, latent: int = 0) -> int:
     """HBM bytes one resident K+V position costs, PER CHIP, in the paged
     pool or a dense row alike.
 
@@ -59,7 +59,19 @@ def kv_bytes_per_token(cache_layers: int, n_kv_heads: int, head_dim: int,
     real per-chip HBM — a tp=4 replica whose gauges reported the
     host-global (summed) bytes would look 4x fuller than any of its
     chips actually is, and the autoscaler and gateway spill would
-    misread the pool."""
+    misread the pool.
+
+    ``latent``: a latent-attention model's cache holds no K and V a head
+    but one row a token and layer that every head reads, ``latent``
+    values wide (the latent and the rotary key: Kanana-2-30B-A3B's 512 +
+    64 = 1,152 bytes a layer at bf16); the heads and ``head_dim`` do not
+    enter, and the zeros that pad the row to whole tiles are not a cost
+    the model has."""
+    if latent:
+        if kv_dtype not in ("", "fp") or tp_shards != 1:
+            raise ValueError("a latent cache is held at the model dtype "
+                             "on one chip")
+        return cache_layers * latent * fp_bytes
     if tp_shards < 1:
         raise ValueError(f"tp_shards must be >= 1, got {tp_shards}")
     if n_kv_heads % tp_shards:
