@@ -810,6 +810,43 @@ def child_kernels() -> int:
               f"{d_hkv}]", 3e-2, lambda: dense("pallas"),
               lambda: dense("xla"))
 
+    # --- the grouped expert MLP at Kanana-2-30B-A3B's widths: a decode
+    # step's 64 rows × 6 of 128 experts of 2048 × 768, sorted by expert,
+    # through layer 1 of two-layer stacks, against every expert applied to
+    # every pair and each pair's own kept (XLA's ragged-dot kernel, the
+    # program's other path, does not compile at "highest" precision).
+    from kubeflow_tpu.ops.grouped_experts import grouped_expert_mlp
+
+    e_n, e_d, e_f = 128, 2048, 768
+    gw, uw = (jax.random.normal(k_, (2, e_n, e_d, e_f)).astype(jnp.bfloat16)
+              * e_d ** -0.5 for k_ in (kk, kv))
+    dw = jax.random.normal(kq, (2, e_n, e_f, e_d)).astype(
+        jnp.bfloat16) * e_f ** -0.5
+    chosen = jnp.asarray(np.stack([rng.choice(e_n, 6, replace=False)
+                                   for _ in range(64)]).reshape(-1))
+    e_sizes = jnp.bincount(chosen, length=e_n).astype(jnp.int32)
+    e_xs = jax.random.normal(key, (64, e_d)).astype(jnp.bfloat16)[
+        jnp.argsort(chosen) // 6]
+
+    def every_expert(xs, own_expert, g_, u_, d_):
+        def own(spec, h, w):
+            every = jnp.einsum(spec, h, w[1].astype(jnp.float32))
+            return jnp.take_along_axis(every, own_expert[:, None, None],
+                                       axis=1)[:, 0]
+        x = xs.astype(jnp.float32)
+        h = jax.nn.silu(own("pd,edf->pef", x, g_)) * own("pd,edf->pef", x,
+                                                          u_)
+        return own("pf,efd->ped", h.astype(jnp.bfloat16).astype(jnp.float32),
+                   d_)
+
+    def experts(impl):
+        if impl == "pallas":
+            return jax.jit(lambda xs, sizes, g_, u_, d_: grouped_expert_mlp(
+                xs, sizes, 1, g_, u_, d_))(e_xs, e_sizes, gw, uw, dw)
+        return jax.jit(every_expert)(e_xs, jnp.sort(chosen), gw, uw, dw)
+    check(f"grouped_expert_mlp[bf16, {e_n} experts of {e_d} x {e_f}]", 3e-2,
+          lambda: experts("pallas"), lambda: experts("xla"))
+
     # --- rms_norm, forward and (custom-VJP) backward, training shape.
     x = jax.random.normal(kq, (8, SEQ_LEN, d)).astype(jnp.bfloat16)
     w = 1.0 + 0.1 * jax.random.normal(kk, (d,), jnp.float32)

@@ -46,6 +46,10 @@ from kubeflow_tpu.observability.tracing import (
     scope,
 )
 from kubeflow_tpu.ops import flash_attention, rms_norm
+from kubeflow_tpu.ops.grouped_experts import (
+    grouped_expert_mlp,
+    grouped_experts_implementation,
+)
 from kubeflow_tpu.ops.linear_attention import (
     lightning_chunked,
     lightning_slopes,
@@ -917,7 +921,14 @@ def moe_dropless(x, mlp, cfg: TransformerConfig, token_valid=None,
     expert's weights are read once a call, however many tokens it took),
     then each token's pairs are gathered back and weighed. The shared
     MLP takes every token. With ``layer`` the experts' matrices in
-    ``mlp`` are the stacks of every expert layer (:func:`_grouped`)."""
+    ``mlp`` are the stacks of every expert layer (:func:`_grouped`).
+
+    Where ``ops/grouped_experts.py:grouped_experts_implementation`` says
+    so (a TPU, bf16 experts, at most a row tile of pairs an expert: a
+    decode step, not an admission) the three products, the SiLU and the
+    gate are ONE kernel (``grouped_expert_mlp``) that reads each touched
+    expert once out of the stacks where they lie. A latent model is
+    served under no mesh (``serving/continuous.py:_check_latent``)."""
     shape, d = x.shape, x.shape[-1]
     e, k = cfg.n_experts, cfg.expert_top_k
     xf = x.reshape(-1, d)
@@ -940,10 +951,19 @@ def moe_dropless(x, mlp, cfg: TransformerConfig, token_valid=None,
                               length=e).astype(jnp.int32)
     with scope(SCOPE_MOE_EXPERTS):
         xs = xf.astype(dtype)[order // k]  # [n*k, D], grouped by expert
-        gate = _grouped(xs, cast_param(mlp["gate"], dtype), sizes, layer)
-        up = _grouped(xs, cast_param(mlp["up"], dtype), sizes, layer)
-        out = _grouped((jax.nn.silu(gate) * up).astype(dtype),
-                       cast_param(mlp["down"], dtype), sizes, layer)
+        if grouped_experts_implementation(n * k, e, d, cfg.d_expert,
+                                          dtype) == "pallas":
+            stacks = [cast_param(mlp[name], dtype)
+                      for name in ("gate", "up", "down")]
+            if layer is None:
+                stacks = [w[None] for w in stacks]
+            out = grouped_expert_mlp(xs, sizes, 0 if layer is None else layer,
+                                     *stacks)
+        else:
+            gate = _grouped(xs, cast_param(mlp["gate"], dtype), sizes, layer)
+            up = _grouped(xs, cast_param(mlp["up"], dtype), sizes, layer)
+            out = _grouped((jax.nn.silu(gate) * up).astype(dtype),
+                           cast_param(mlp["down"], dtype), sizes, layer)
         pairs = out[jnp.argsort(order)].reshape(n, k, d)
         y = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32), weight)
         sg = xf @ cast_param(mlp["shared_gate"], dtype)

@@ -106,6 +106,7 @@ from kubeflow_tpu.observability.tracing import (
     host_span,
 )
 from kubeflow_tpu.ops.attention import dense_decode_implementation
+from kubeflow_tpu.ops.grouped_experts import grouped_experts_implementation
 from kubeflow_tpu.ops.sparse_attention import decode_implementation
 from kubeflow_tpu.serving.affinity import (
     DEFAULT_AFFINITY_TOKENS,
@@ -712,6 +713,15 @@ class ContinuousDecoder:
             else dense_decode_implementation(
                 cfg.latent_width if cfg.kv_lora_rank else cfg.head_dim,
                 cache["k"].dtype))
+        # What a decode step's routed experts run on ("" for a model
+        # without the dropless expert layer): the grouped-MLP kernel, or
+        # XLA's three grouped products (off the TPU, a float32 tree, a
+        # mesh). An admission's prefill asks again at its own pair count.
+        self.moe_experts_impl = (
+            grouped_experts_implementation(
+                slots * cfg.expert_top_k, cfg.n_experts, cfg.d_model,
+                cfg.d_expert, cfg.dtype, self.mesh)
+            if cfg.kv_lora_rank and cfg.n_experts else "")
         self._admit_rows = max_admit_rows(cfg)
         if self.mesh is not None:
             # KV payload onto the mesh, head-sharded (and layer-sharded
@@ -3610,6 +3620,7 @@ class ContinuousDecoder:
                 "state_bytes": self.state_bytes,
                 "sparse_attn_impl": self.sparse_attn_impl,
                 "dense_attn_impl": self.dense_attn_impl,
+                "moe_experts_impl": self.moe_experts_impl,
                 "sparse_tokens_attended": self.sparse_tokens_attended,
                 "sparse_tokens_in_context": self.sparse_tokens_in_context,
                 "rows_dense": self.rows_dense,

@@ -578,6 +578,8 @@ class ModelServer:
                                 int(d["sparse_attn_impl"] == "pallas"),
                             "serving_dense_attn_pallas":
                                 int(d["dense_attn_impl"] == "pallas"),
+                            "serving_moe_experts_pallas":
+                                int(d["moe_experts_impl"] == "pallas"),
                             "serving_sparse_tokens_attended_total":
                                 d["sparse_tokens_attended"],
                             "serving_sparse_tokens_in_context_total":

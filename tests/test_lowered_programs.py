@@ -33,6 +33,13 @@ EXPECTED = {
     "sala-test-tiny.decode_step.paged": "e62c77040eba50d4",
     "ouro-test-tiny.decode_step.dense": "0e9e95ef7e9615c7",
     "moe-test-tiny.apply": "eecf1f4ca7e85b23",
+    # The latent model's programs as they were before its decode step ran
+    # the routed experts through a kernel on the TPU: off the TPU the
+    # expert layer is the three grouped products, as it was.
+    "kanana-test-tiny.decode_step.dense": "f670ebcd4e107ef1",
+    "kanana-test-tiny.decode_chunk.dense": "5224a6a29cb14321",
+    "kanana-test-tiny.admit_rows_and_step.dense": "014613f0bc5909f4",
+    "kanana-test-tiny.apply": "93afef88b556ed82",
     "lm-test-tiny.train_grad": "bcee49537f0b9081",
 }
 

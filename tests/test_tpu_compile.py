@@ -225,6 +225,64 @@ def test_the_decode_step_holds_no_second_cache_on_v5e(one_chip,
         assert layouts[:layouts.index("}}")].count(whole) == 2, layouts[:300]
 
 
+# `kanana-test-tiny` at widths the grouped expert-MLP kernel takes: 32
+# experts of 128 x 256 (a layer's three matrices 6.3 MB), 8 slots of 2
+# choices a step, the latent store's row one tile of 128. The step's
+# temporaries are 1.3 MB.
+KANANA_TINY = {"d_model": 128, "d_expert": 256, "n_experts": 32,
+               "dtype": jnp.bfloat16}
+
+
+def test_the_latent_decode_step_runs_its_experts_in_one_kernel_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """One `grouped_expert_mlp` custom call (the expert layers' scan body)
+    handed the three stacks whole; no ragged dot; nothing the size of a
+    layer's experts among the temporaries."""
+    from kubeflow_tpu.models import decode, transformer
+    from kubeflow_tpu.ops import attention, grouped_experts
+
+    cfg = transformer.config("kanana-test-tiny", **KANANA_TINY)
+    for module in (attention, grouped_experts):
+        monkeypatch.setattr(module, "not_tpu", lambda: None)
+    params = _serving_tree(cfg, one_chip)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: decode.init_decode_state(cfg, 8, 64)))
+    compiled = decode.decode_step.lower(state, params, cfg).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if re.search(r"%grouped_expert_mlp[.\d]* = .* custom-call\(",
+                          line)]
+    assert len(calls) == 1, calls
+    stack = params["layers"]["mlp"]["gate"]
+    whole = "bf16[" + ",".join(map(str, stack.shape)) + "]"
+    layouts = calls[0][calls[0].index("operand_layout_constraints="):]
+    assert layouts[:layouts.index("}}")].count(whole) == 2, layouts[:400]
+    assert "ragged" not in text
+    layer_experts = 3 * 2 * stack.shape[1] * stack.shape[2] * stack.shape[3]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_experts, (temp, layer_experts)
+
+
+def test_the_grouped_expert_kernel_compiles_at_kanana_widths_on_v5e(
+        arg, no_compile_cache):
+    """`moe-decode`'s call: 384 sorted pairs through layer 2 of the
+    `[5, 128, 2048, 768]` stacks, each visit's three whole matrices (9.4 MB)
+    in two VMEM buffers; the stacks go in whole, nothing of a layer's
+    experts is made."""
+    from kubeflow_tpu.ops.grouped_experts import grouped_expert_mlp
+
+    stack, down = (5, 128, 2048, 768), (5, 128, 768, 2048)
+    compiled = jax.jit(grouped_expert_mlp).lower(
+        arg((384, 2048), jnp.bfloat16), arg((128,), jnp.int32),
+        arg((), jnp.int32), arg(stack, jnp.bfloat16),
+        arg(stack, jnp.bfloat16), arg(down, jnp.bfloat16)).compile()
+    calls = re.findall(r"%grouped_expert_mlp[.\d]* = .* custom-call\(",
+                       compiled.as_text())
+    assert len(calls) == 1, calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # An admission of one 256-token prompt, as `decode.prefill` runs it: the
 # most temporaries in MB. Ouro's were 1,813.7 with the three whole-stack
 # copies of wq, wk and wv; without them 1,008.3 (PR 39).
