@@ -810,6 +810,28 @@ def child_kernels() -> int:
               f"{d_hkv}]", 3e-2, lambda: dense("pallas"),
               lambda: dense("xla"))
 
+    # --- its latent form at Kanana-2-30B-A3B's widths (its own kernel,
+    # latent_decode_attention): a row of 640 (a latent of 512, a rotary
+    # key of 64, 64 zeros) read whole as the key of 32 heads and by its
+    # first 512 as their value, at the model's scale; rows that hold
+    # nothing, one position, either side of 256 (two copies of 128), 2,048,
+    # 3,500 and all 8,192.
+    l_len = jnp.asarray([0, 1, 255, 256, 257, 2048, 3500, 8192], jnp.int32)
+    l_col = (jnp.arange(640) < 576).astype(jnp.bfloat16)
+    lq = (4 * jax.random.normal(kq, (len(l_len), 32, 640))).astype(
+        jnp.bfloat16) * l_col
+    l_store = jax.random.normal(kk, (3, len(l_len), 8192, 640)).astype(
+        jnp.bfloat16) * l_col
+
+    def latent(impl):
+        return jax.jit(
+            lambda q_, s_, li, n: dense_decode_attention(
+                q_, s_, None, li, n, n_kv_heads=1, v_dim=512,
+                sm_scale=192 ** -0.5, implementation=impl))(
+            lq, l_store, jnp.int32(1), l_len)
+    check("latent_decode_attention[bf16 store, 32 heads over a row of 640]",
+          3e-2, lambda: latent("pallas"), lambda: latent("xla"))
+
     # --- the grouped expert MLP at Kanana-2-30B-A3B's widths: a decode
     # step's 64 rows × 6 of 128 experts of 2048 × 768, sorted by expert,
     # through layer 1 of two-layer stacks, against every expert applied to

@@ -675,11 +675,20 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, *,
 # Positions a chunk of the kernel's inner loop copies and attends (timings
 # of the alternatives on a v5e: PERF.md, PR 37).
 _DENSE_CHUNK = 128
-# The same for a latent store, whose position is one row of 1,280 bytes
-# (Kanana-2-30B-A3B's) where Mistral-7B's is 2,048 of K and 2,048 of V: a
-# copy of 327,680 bytes, about one side of a dense chunk at Mistral's
-# sizes (not measured against other sizes).
-_LATENT_CHUNK = 256
+# The latent kernel's (a position is one row of 1,280 bytes, Kanana-2-30B-
+# A3B's), timed alone on a v5e at that model's decode step: six layer
+# calls over 64 rows of 1,100-3,500 positions (PERF.md §6). At 256
+# positions a chunk its two products took longer than its copies (2.02 ms
+# a step against 1.92; about 0.35 us a chunk whatever its size), so a
+# chunk is 512 (products 1.36 ms; 1,024 came within 1 % of 512 whole).
+# Copies move 128 positions each and stop at the part that holds a row's
+# last position (a chunk copied whole reads up to 511 positions past it:
+# 8 % more bytes); three buffers keep two chunks' copies in flight (one in
+# flight: 650 GB/s, two: 690). 1.77 ms a step where one kernel for both
+# forms took 2.73; the copies alone 1.76.
+_LATENT_CHUNK = 512
+_LATENT_PART = 128
+_LATENT_BUFFERS = 3
 
 
 def _dense_kernel_unsupported(head_dim: int, dtype) -> str | None:
@@ -723,12 +732,9 @@ def _dense_decode_xla(qg, k_store, v_store, li, lengths, sm_scale=None,
                       preferred_element_type=jnp.float32).astype(qg.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "v_dim",
-                                             "sm_scale"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
-                         chunk: int = _DENSE_CHUNK, interpret: bool = False,
-                         v_dim: int | None = None,
-                         sm_scale: float | None = None):
+                         chunk: int = _DENSE_CHUNK, interpret: bool = False):
     """The kernel of :func:`dense_decode_attention`. q [B, Hq, hd]; the
     stores whole, in HBM; ``li`` (traced) and ``lengths`` [B] are
     scalar-prefetched. Jitted on its own so that the kernel is traced once
@@ -752,31 +758,19 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
     element once, as a product a head would, and the scores of a chunk
     are ``Hq·Hkv·chunk`` floats. Scores, running maximum, normaliser and
     accumulator are float32; K, V and the probabilities enter the MXU at
-    the store's dtype.
-
-    A latent store (``v_store`` None: :func:`dense_decode_attention`) is
-    ``[cache layers, B, T, W]``, one row a position that every head reads
-    as its key whole and as its value by its first ``v_dim`` columns: one
-    copy a chunk, no head columns to mask, ``sm_scale`` the caller's."""
+    the store's dtype."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, hd = q.shape
-    latent = v_store is None
-    t, hkv = k_store.shape[2], 1 if latent else k_store.shape[3]
+    t, hkv = k_store.shape[2], k_store.shape[3]
     group = hq // hkv
     tc = min(chunk, t)
-    dv = v_dim if latent else hd
-    if sm_scale is None:
-        sm_scale = hd ** -0.5
+    sm_scale = hd ** -0.5
 
-    def kernel(li_ref, len_ref, q_ref, *refs):
-        if latent:
-            k_hbm, o_ref, k_buf, sems, nxt_ref = refs
-            pairs = ((k_hbm, k_buf),)
-        else:
-            k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, nxt_ref = refs
-            pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
+    def kernel(li_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+               sems, nxt_ref):
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
         # nxt_ref[0]: the buffer the next chunk to compute lands in;
         # nxt_ref[1]: whether the row before has started this row's first
         # chunk.
@@ -829,28 +823,21 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
                 nxt_ref[1] = 1
 
             nxt_ref[0] = other
-            k_copy, *v_copy = copies(row, c, buf)
+            k_copy, v_copy = copies(row, c, buf)
             k_copy.wait()
             k = k_buf[buf].reshape(tc * hkv, hd)
             s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
             col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            if latent:
-                pos = first(c) + col
-                seen = (pos >= c * tc) & (pos < length)
-            else:
-                head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-                pos = first(c) + col // hkv
-                seen = (col % hkv == head) & (pos >= c * tc) & (pos < length)
+            head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+            pos = first(c) + col // hkv
+            seen = (col % hkv == head) & (pos >= c * tc) & (pos < length)
             s = jnp.where(seen, s * sm_scale, _NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             e = jnp.where(seen, jnp.exp(s - m_new), 0.0)
             fade = jnp.exp(m - m_new)
-            if latent:
-                v = k[:, :dv]
-            else:
-                v_copy[0].wait()
-                v = v_buf[buf].reshape(tc * hkv, hd)
+            v_copy.wait()
+            v = v_buf[buf].reshape(tc * hkv, hd)
             acc = acc * fade + jnp.dot(e.astype(v.dtype), v,
                                        preferred_element_type=jnp.float32)
             return m_new, l * fade + e.sum(axis=-1, keepdims=True), acc
@@ -859,39 +846,172 @@ def _dense_decode_pallas(q, k_store, v_store, li, lengths, *,
             0, chunks, body,
             (jnp.full((hq, 1), _NEG_INF, jnp.float32),
              jnp.zeros((hq, 1), jnp.float32),
-             jnp.zeros((hq, dv), jnp.float32)))
+             jnp.zeros((hq, hd), jnp.float32)))
         o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
     q_spec = pl.BlockSpec((None, hq, hd), lambda row, *_: (row, 0, 0))
-    if latent:
-        stores = (k_store,)
-        o_spec = pl.BlockSpec((None, hq, dv), lambda row, *_: (row, 0, 0))
-        buffers = [pltpu.VMEM((2, tc, hd), k_store.dtype)]
-    else:
-        stores = (k_store, v_store)
-        o_spec = q_spec
-        buffers = [pltpu.VMEM((2, tc, hkv, hd), k_store.dtype),
-                   pltpu.VMEM((2, tc, hkv, hd), v_store.dtype)]
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, hd), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
-            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
-                                 for _ in stores],
-            out_specs=o_spec,
-            scratch_shapes=buffers + [
-                pltpu.SemaphoreType.DMA((len(stores), 2)),
+            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((2, tc, hkv, hd), k_store.dtype),
+                pltpu.VMEM((2, tc, hkv, hd), v_store.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((2,), jnp.int32),
             ]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="latent_decode_attention" if latent
-        else "dense_decode_attention",
+        name="dense_decode_attention",
     )(jnp.reshape(li, (1,)).astype(jnp.int32),
-      jnp.clip(lengths, 0, t).astype(jnp.int32), q, *stores)
+      jnp.clip(lengths, 0, t).astype(jnp.int32), q, k_store, v_store)
+
+
+@functools.partial(jax.jit, static_argnames=("v_dim", "sm_scale",
+                                             "interpret"))
+def _latent_decode_pallas(q, store, li, lengths, *, v_dim: int,
+                          sm_scale: float, interpret: bool = False):
+    """The kernel of :func:`dense_decode_attention` over a latent store.
+    q [B, Hq, W]; the store ``[cache layers, B, T, W]`` whole, in HBM, one
+    row a position that every head reads as its key whole and as its value
+    by its first ``v_dim`` columns; ``li`` (traced) and ``lengths`` [B]
+    are scalar-prefetched. Returns [B, Hq, v_dim]. Jitted on its own, as
+    :func:`_dense_decode_pallas` is and for the same reason.
+
+    Grid (B,), one row a grid step, in order; a step's inner loop attends
+    ``_LATENT_CHUNK`` positions at a time, all heads one product a side
+    (scores ``[Hq, W] · [chunk, W]ᵀ``, which the MXU takes with K latched
+    transposed, no relayout; values ``[Hq, chunk] · [chunk, v_dim]`` out
+    of the same tile). Copies run ahead of the products as ONE stream
+    across the grid: the (row, chunk) pairs that hold positions, in order,
+    each into the next of ``_LATENT_BUFFERS`` VMEM buffers, so that the
+    copies of the next ``_LATENT_BUFFERS - 1`` chunks, the next rows'
+    included, are in flight while a chunk is computed. A chunk is copied
+    in parts of ``_LATENT_PART`` positions and only as far as the part
+    that holds the row's last position; what the rest of its buffer holds
+    (zeros, or an earlier chunk's rows) is masked. A row of length 0
+    copies and computes nothing and writes zeros. Scores, running maximum,
+    normaliser and accumulator are float32; K, V and the probabilities
+    enter the MXU at the store's dtype."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, hq, w = q.shape
+    t = store.shape[2]
+    tc = min(_LATENT_CHUNK, t)
+    part = _LATENT_PART if tc % _LATENT_PART == 0 else tc
+    nbuf = _LATENT_BUFFERS
+
+    def kernel(li_ref, len_ref, q_ref, store_hbm, o_ref, buf, sems,
+               stream):
+        # stream[0], stream[1]: chunks whose copies were started, waited
+        # for (the count across the grid names the buffer); stream[2],
+        # stream[3]: the row and chunk the next copy to start reads.
+        row = pl.program_id(0)
+        layer, length = li_ref[0], len_ref[row]
+
+        def first(c):
+            # A last chunk that would pass the store's end starts earlier,
+            # and attends only what the chunk before it did not.
+            return jnp.minimum(c * tc, t - tc)
+
+        def each_part(r, c, slot, act):
+            # The parts of chunk c of row r that hold its positions
+            # c*tc .. length-1.
+            lo = first(c)
+
+            def one(j, carry):
+                act(pltpu.make_async_copy(
+                    store_hbm.at[layer, r, pl.ds(lo + j * part, part)],
+                    buf.at[slot, pl.ds(j * part, part)], sems.at[slot]))
+                return carry
+            lax.fori_loop((c * tc - lo) // part,
+                          pl.cdiv(jnp.minimum(len_ref[r], lo + tc) - lo, part),
+                          one, 0)
+
+        def live_from(r):
+            return lax.while_loop(
+                lambda x: (x < b) & (len_ref[jnp.minimum(x, b - 1)] == 0),
+                lambda x: x + 1, r)
+
+        def start_next():
+            r, c = stream[2], stream[3]
+
+            @pl.when(r < b)
+            def _start():
+                each_part(r, c, stream[0] % nbuf, lambda copy: copy.start())
+                stream[0] = stream[0] + 1
+                stream[3] = c + 1
+
+                @pl.when(c + 1 == pl.cdiv(len_ref[r], tc))
+                def _row_done():
+                    stream[2] = live_from(r + 1)
+                    stream[3] = 0
+
+        @pl.when(row == 0)
+        def _first_row():
+            # A part left uncopied must hold finite numbers: its
+            # probabilities are 0, and 0 · NaN is not.
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+            stream[0] = 0
+            stream[1] = 0
+            stream[2] = live_from(0)
+            stream[3] = 0
+            for _ in range(nbuf - 1):
+                start_next()
+
+        qh = q_ref[...]
+
+        def body(c, carry):
+            m, l, acc = carry
+            slot = stream[1] % nbuf
+            start_next()
+            stream[1] = stream[1] + 1
+            each_part(row, c, slot, lambda copy: copy.wait())
+            k = buf[slot]
+            s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            pos = first(c) + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen = (pos >= c * tc) & (pos < length)
+            s = jnp.where(seen, s * sm_scale, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            e = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            fade = jnp.exp(m - m_new)
+            acc = acc * fade + jnp.dot(e.astype(k.dtype), k[:, :v_dim],
+                                       preferred_element_type=jnp.float32)
+            return m_new, l * fade + e.sum(axis=-1, keepdims=True), acc
+
+        _, l, acc = lax.fori_loop(
+            0, pl.cdiv(length, tc), body,
+            (jnp.full((hq, 1), _NEG_INF, jnp.float32),
+             jnp.zeros((hq, 1), jnp.float32),
+             jnp.zeros((hq, v_dim), jnp.float32)))
+        o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, hq, v_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b,),
+            in_specs=[pl.BlockSpec((None, hq, w), lambda row, *_: (row, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, hq, v_dim),
+                                   lambda row, *_: (row, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((nbuf, tc, w), store.dtype),
+                            pltpu.SemaphoreType.DMA((nbuf,)),
+                            pltpu.SMEM((4,), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(jnp.reshape(li, (1,)).astype(jnp.int32),
+      jnp.clip(lengths, 0, t).astype(jnp.int32), q, store)
 
 
 def dense_decode_attention(q, k_store, v_store, li, lengths, *,
@@ -922,7 +1042,10 @@ def dense_decode_attention(q, k_store, v_store, li, lengths, *,
     whole row (the absorbed latent query, the rotary query, zeros where the
     row is padding); a head's value is the row's first ``v_dim`` columns,
     so the result is [B, Hq, v_dim]. ``sm_scale`` is the model's (the
-    query's width is not its scale here); ``n_kv_heads`` is 1."""
+    query's width is not its scale here); ``n_kv_heads`` is 1. Its kernel
+    is :func:`_latent_decode_pallas`, which shares no logic with the
+    dense one: a row of 640 read as K and V at once wants larger chunks
+    and more copies in flight than separate ``[chunk, Hkv, 128]`` tiles."""
     b, hq, hd = q.shape
     latent = v_store is None
     if hq % n_kv_heads:
@@ -935,9 +1058,10 @@ def dense_decode_attention(q, k_store, v_store, li, lengths, *,
             raise ValueError(
                 f"dense_decode_attention(implementation='pallas'): {why}")
     if implementation == "pallas" and latent:
-        return _dense_decode_pallas(q, k_store, None, li, lengths,
-                                    chunk=_LATENT_CHUNK, interpret=interpret,
-                                    v_dim=v_dim, sm_scale=sm_scale)
+        return _latent_decode_pallas(
+            q, k_store, li, lengths, v_dim=hd if v_dim is None else v_dim,
+            sm_scale=hd ** -0.5 if sm_scale is None else sm_scale,
+            interpret=interpret)
     if implementation == "pallas":
         return _dense_decode_pallas(q, k_store, v_store, li, lengths,
                                     interpret=interpret)

@@ -20,6 +20,7 @@ from benchmarks.drivers.latent_moe import install_weights
 from benchmarks.harness import registry
 from benchmarks.reference import kanana_f32 as ref
 from kubeflow_tpu.models import decode, transformer
+from kubeflow_tpu.ops import attention
 from kubeflow_tpu.ops.attention import dense_decode_attention
 from kubeflow_tpu.serving.continuous import ContinuousDecoder
 from kubeflow_tpu.serving.kv_allocator import kv_bytes_per_token
@@ -238,6 +239,41 @@ def test_the_latent_kernel_reads_as_the_xla_path_does():
     assert float(jnp.abs(a[0].astype(jnp.float32)).max()) == 0.0
     assert float(jnp.abs(a.astype(jnp.float32)
                          - b.astype(jnp.float32)).max()) < 0.02
+
+
+# The latent kernel at Kanana-2-30B-A3B's row: 512 latent columns, 64
+# rotary, 64 zeros (the width a TPU lays a 576 row out at), 32 heads, the
+# model's scale. A store of two chunks and 276 positions, so that its last
+# chunk starts early; each case's row sits beside a free slot and a live
+# row of another length (three rows, a row of length 0 between live ones).
+CHUNK = attention._LATENT_CHUNK
+LATENT_T = 2 * CHUNK + 276
+LATENT_LENGTHS = {"empty": 0, "one": 1, "chunk-1": CHUNK - 1, "chunk": CHUNK,
+                  "chunk+1": CHUNK + 1, "inside-the-last-chunk": 2 * CHUNK + 76,
+                  "the-whole-store": LATENT_T}
+
+
+@pytest.mark.parametrize("case", list(LATENT_LENGTHS))
+def test_the_latent_kernel_at_kanana_widths(case):
+    key = jax.random.PRNGKey(7)
+    col = (jnp.arange(640) < 576).astype(jnp.bfloat16)
+    lengths = jnp.asarray([LATENT_LENGTHS[case], 0, 2 * CHUNK - 3])
+    store = (jax.random.normal(key, (2, 3, LATENT_T, 640)).astype(
+        jnp.bfloat16) * col)
+    q = (jax.random.normal(jax.random.fold_in(key, 1), (3, 32, 640)).astype(
+        jnp.bfloat16) * col)
+    scale = 192 ** -0.5
+    got = dense_decode_attention(q, store, None, 1, lengths, n_kv_heads=1,
+                                 v_dim=512, sm_scale=scale,
+                                 implementation="pallas", interpret=True)
+    want = attention._dense_decode_xla(q[:, None], store, None, 1, lengths,
+                                       scale, 512)[:, 0]
+    assert got.shape == (3, 32, 512)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+    live = np.asarray(lengths) > 0
+    assert not got[~live].any()
+    assert np.abs(got[live]).max() > 0.05
 
 
 def _layer_mlp(params):

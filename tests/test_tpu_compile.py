@@ -258,7 +258,9 @@ def test_the_latent_decode_step_runs_its_experts_in_one_kernel_on_v5e(
     whole = "bf16[" + ",".join(map(str, stack.shape)) + "]"
     layouts = calls[0][calls[0].index("operand_layout_constraints="):]
     assert layouts[:layouts.index("}}")].count(whole) == 2, layouts[:400]
-    assert "ragged" not in text
+    # No ragged-dot op (a function name in the frames of the op metadata,
+    # `_ragged_attention`, is not one).
+    assert not re.search(r"ragged[-_]dot", text)
     layer_experts = 3 * 2 * stack.shape[1] * stack.shape[2] * stack.shape[3]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < layer_experts, (temp, layer_experts)
@@ -281,6 +283,67 @@ def test_the_grouped_expert_kernel_compiles_at_kanana_widths_on_v5e(
                        compiled.as_text())
     assert len(calls) == 1, calls
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_latent_decode_kernel_compiles_at_kanana_widths_on_v5e(
+        arg, no_compile_cache, monkeypatch):
+    """`moe-decode`'s call of the latent read: 64 rows of 32 heads over a
+    row of 640, the `[6, 64, 8192, 640]` store handed whole to ONE
+    `latent_decode_attention` call; nothing of its size is made."""
+    from kubeflow_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "not_tpu", lambda: None)
+    store = arg((6, 64, 8192, 640), jnp.bfloat16)
+    compiled = jax.jit(lambda q, s, li, n: attention.dense_decode_attention(
+        q, s, None, li, n, n_kv_heads=1, v_dim=512, sm_scale=192 ** -0.5)
+    ).lower(arg((64, 32, 640), jnp.bfloat16), store, arg((), jnp.int32),
+            arg((64,), jnp.int32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if re.search(r"%latent_decode_attention[.\d]* = .* custom-call\(",
+                          line)]
+    assert len(calls) == 1, calls
+    layouts = calls[0][calls[0].index("operand_layout_constraints="):]
+    assert layouts[:layouts.index("}}")].count("bf16[6,64,8192,640]") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# The dense kernel at `chat-steady`'s decode step (Mistral-7B: 32 rows of
+# 768, 32 query heads on 8, 6 layers) and `reason-decode`'s (Ouro-2.6B: 4
+# rows of 1,280, 16 on 16, 192 cache layers): (q, store) shapes, and the
+# first 16 hex digits of the sha256 of its custom call's lowered line as
+# the tree before the latent read had a kernel of its own lowered it.
+DENSE_CALLS = {
+    "mistral-7b": ((32, 32, 128), (6, 32, 768, 8, 128), "2919426a802c90f0"),
+    "ouro-2.6b": ((4, 16, 128), (192, 4, 1280, 16, 128), "2fe50819e83b4901"),
+}
+
+
+@pytest.fixture()
+def no_source_lines():
+    """Locations without Python frames: the hash is of the kernel, not of
+    the lines it sits on."""
+    before = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    yield
+    jax.config.update("jax_traceback_in_locations_limit", before)
+
+
+@pytest.mark.parametrize("model", sorted(DENSE_CALLS))
+def test_the_dense_decode_kernel_lowers_as_it_did_on_v5e(arg, no_source_lines,
+                                                         model):
+    import hashlib
+
+    from kubeflow_tpu.ops import attention
+
+    q_shape, store_shape, want = DENSE_CALLS[model]
+    store = arg(store_shape, jnp.bfloat16)
+    text = jax.jit(lambda q, k, v, li, n: attention._dense_decode_pallas(
+        q, k, v, li, n)).lower(
+            arg(q_shape, jnp.bfloat16), store, store, arg((), jnp.int32),
+            arg((q_shape[0],), jnp.int32)).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and "dense_decode_attention" in calls[0]
+    assert hashlib.sha256(calls[0].encode()).hexdigest()[:16] == want
 
 
 # An admission of one 256-token prompt, as `decode.prefill` runs it: the
